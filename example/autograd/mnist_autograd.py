@@ -30,9 +30,6 @@ def make_data(rng, n=512, feat=32, classes=4):
 
 
 def main():
-    import jax
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     rng = np.random.default_rng(0)
     X, Y = make_data(rng)
     feat, hidden, classes = X.shape[1], 64, 4

@@ -67,6 +67,7 @@ def main():
     parser.add_argument("--synthetic", action="store_true")
     args = parser.parse_args()
     logging.basicConfig(level=logging.INFO)
+    mx.engine.setup_compile_cache()
 
     net = models.get_symbol(args.network, num_classes=10)
     devs = (mx.current_context() if args.gpus is None

@@ -48,8 +48,6 @@ def main():
     args = ap.parse_args()
 
     import jax
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     from mxnet_tpu import models
     from mxnet_tpu.train_step import TrainStep
 

@@ -143,10 +143,7 @@ def main():
     ap.add_argument("--min-map", type=float, default=None,
                     help="assert final mAP >= this")
     args = ap.parse_args()
-
-    import jax
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    mx.engine.setup_compile_cache()
 
     rng = np.random.default_rng(0)
     if args.data_train:

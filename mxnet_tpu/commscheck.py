@@ -93,7 +93,7 @@ from .tracecheck import (Finding, COMM_LINTS, _is_suppressed, unsuppressed,
 # ONE HLO-metadata parser set across the analyzer trilogy: byte/shape
 # helpers and the op_name/source provenance regexes all live in memcheck
 from .memcheck import (_parse_bytes, _shape_bytes, _fmt_bytes, _unescape,
-                       _OPNAME_RE, _SOURCE_RE)
+                       _OPNAME_RE, instr_provenance, parse_stack_frames)
 
 __all__ = [
     "CollectiveEntry", "CommsReport", "parse_collectives", "analyze",
@@ -396,6 +396,7 @@ def parse_collectives(hlo_text, mesh=None, loop_trips=1):
     evidence)."""
     axis_groups = _mesh_axis_groups(mesh) if mesh is not None else {}
     lines = hlo_text.splitlines()  # multi-MB text: split once, scan thrice
+    frames = parse_stack_frames(hlo_text)
     # entry-computation parameter instruction names -> op_name label
     entry_params = {}
     in_entry = False
@@ -462,8 +463,7 @@ def parse_collectives(hlo_text, mesh=None, loop_trips=1):
             axes = None
         op = _OPNAME_RE.search(line)
         op_path = _unescape(op.group(1)) if op else None
-        src = _SOURCE_RE.search(line)
-        prov = ("%s:%s" % (src.group(1), src.group(2))) if src else None
+        prov = instr_provenance(line, frames)
         in_loop = bool(op_path and "/while/" in op_path)
         # direct operands that are entry parameters: the operand list runs
         # from the opcode's "(" to its matching close — collectives take

@@ -86,12 +86,19 @@ class Context(object):
             # context ids beyond physical devices are legal for CPU in the
             # reference (SURVEY.md section 4 multi-device trick); clamp by modulo.
             return devs[self.device_id % len(devs)]
-        # tpu / gpu alias -> whatever accelerator platform is default
+        # tpu / gpu alias -> this process's accelerator devices. No CPU
+        # stand-in and no id wrap-around: a run that names a chip it does
+        # not have must fail, not train somewhere else
         devs = _accelerator_devices()
         if not devs:
-            devs = jax.local_devices()
-        if self.device_id >= len(devs):
-            return devs[self.device_id % len(devs)]
+            raise MXNetError(
+                "%s: no accelerator device is visible to this process "
+                "(jax platform %r) — use mx.cpu() to run on the host"
+                % (self, jax.default_backend()))
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                "%s: device id out of range, this process has %d %s "
+                "device(s)" % (self, len(devs), devs[0].platform))
         return devs[self.device_id]
 
     @property
@@ -109,10 +116,9 @@ def _local_platform_devices(name):
 
 
 def _accelerator_devices():
-    """This process's non-cpu devices, else its cpu devices."""
+    """This process's non-cpu devices (empty on a CPU-only host)."""
     import jax
-    devs = [d for d in jax.local_devices() if d.platform != "cpu"]
-    return devs if devs else _local_platform_devices("cpu")
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
 
 
 def cpu(device_id=0):
@@ -135,19 +141,17 @@ def cpu_pinned(device_id=0):
 
 
 def num_devices():
-    """Number of accelerator devices visible (parity: mx.context device count)."""
+    """Number of accelerator devices visible, 0 on a CPU-only host
+    (parity: mx.context device count)."""
     return len(_accelerator_devices())
 
 
 def current_context():
     """The thread-local default context (default: first accelerator, else cpu)."""
     if not hasattr(Context._default_ctx, "value"):
-        import jax
-        try:
-            accel = [d for d in jax.local_devices() if d.platform != "cpu"]
-        except Exception:
-            accel = []
-        Context._default_ctx.value = Context("tpu", 0) if accel else Context("cpu", 0)
+        Context._default_ctx.value = (Context("tpu", 0)
+                                      if _accelerator_devices()
+                                      else Context("cpu", 0))
     return Context._default_ctx.value
 
 

@@ -276,6 +276,26 @@ def set_flopcheck(mode):
     return prev
 
 
+#: where compiled programs persist when the environment names no place:
+#: a fixed path in the checkout (the path is part of the cache key's
+#: lookup, so a directory that moves between runs never hits)
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def setup_compile_cache():
+    """Place JAX's persistent compilation cache; call before the first
+    compile. ``JAX_COMPILATION_CACHE_DIR`` wins and is left to JAX, which
+    reads it itself; unset, the cache is :data:`COMPILE_CACHE_DIR`. Every
+    ``jit`` and every AOT ``lower().compile()`` (the serving engines) goes
+    through it. Returns the directory in use."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
 def maybe_sync(arr):
     """Called after each imperative op; blocks in naive mode."""
     if _naive and arr is not None:

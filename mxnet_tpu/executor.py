@@ -57,7 +57,8 @@ def _build_graph_runner(symbol, placement=None, node_constraint=None):
     # epilogues; peephole over the DAG like nnvm's DetectInplaceAddTo
     # (ref: src/executor/inplace_addto_detect_pass.cc pattern).
     # OPT-IN: measured 2x slower than letting XLA fuse on v5e
-    # (docs/perf.md r4) — "1" enables on TPU, "interpret" for CPU tests.
+    # (docs/perf.md r4) — "1" compiles the kernel for the chip (and fails
+    # where there is none), "interpret" runs the Pallas interpreter.
     fuse_mode = os.environ.get("MXTPU_FUSE_CONV_BN", "0")
     fused_convs = {}        # id(conv node) -> conv node
     bn_stats_src = {}       # id(bn node) -> id(conv node)
@@ -76,13 +77,10 @@ def _build_graph_runner(symbol, placement=None, node_constraint=None):
                 bn_stats_src[id(node)] = id(src)
 
     def run(arg_vals, aux_vals, key, is_train):
-        if fused_convs and is_train:
+        use_fusion = bool(fused_convs) and is_train
+        if use_fusion:
             from .ops import pallas_fused as _pf
-            interp = (fuse_mode == "interpret"
-                      or jax.default_backend() != "tpu")
-            use_fusion = fuse_mode == "interpret" or not interp
-        else:
-            use_fusion = False
+            interp = fuse_mode == "interpret"
         env = {}
         stats_env = {}
         aux_updates = {}

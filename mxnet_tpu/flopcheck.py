@@ -108,7 +108,8 @@ from .tracecheck import (Finding, ROOFLINE_LINTS, _is_suppressed,
 # helpers, the computation-header regex and the op_name/source
 # provenance regexes all live in memcheck
 from .memcheck import (_parse_bytes, _shape_bytes, _fmt_bytes, _unescape,
-                       _COMP_RE, _OPNAME_RE, _SOURCE_RE, _VIEW_OPCODES)
+                       _COMP_RE, _OPNAME_RE, _VIEW_OPCODES,
+                       instr_provenance, parse_stack_frames)
 # the collective inventory + wire-time model live in commscheck; the
 # tuple-capable type pattern is shared so fusion results parse
 from .commscheck import (COLLECTIVE_KINDS, CommsReport, _TYPE_PAT,
@@ -281,6 +282,7 @@ def _parse_computations(hlo_text):
     An instr dict carries instruction/type/opcode/rest plus op path and
     source provenance pulled from its metadata."""
     comps, entry_name, cur = {}, None, None
+    frames = parse_stack_frames(hlo_text)
     for line in hlo_text.splitlines():
         cm = _COMP_RE.match(line)
         if cm:
@@ -298,15 +300,13 @@ def _parse_computations(hlo_text):
         if not im:
             continue
         op = _OPNAME_RE.search(line)
-        src = _SOURCE_RE.search(line)
         comps[cur].append({
             "instr": im.group("instr"),
             "type": im.group("type"),
             "opcode": im.group("opcode"),
             "rest": im.group("rest"),
             "op_path": _unescape(op.group(1)) if op else None,
-            "provenance": ("%s:%s" % (src.group(1), src.group(2))
-                           if src else None),
+            "provenance": instr_provenance(line, frames),
         })
     return comps, entry_name
 

@@ -30,22 +30,14 @@ def _init_distributed():
     # (whose jits stay process-local); MXTPU_DIST_GLOO=0 opts out.
     if os.environ.get("MXTPU_DIST_GLOO", "1") != "0" \
             and os.environ.get("JAX_PLATFORMS", "").strip() in ("cpu", ""):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # jaxlib without Gloo: ring transport still works
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     kwargs = dict(
         coordinator_address=coord,
         num_processes=int(os.environ.get("MXTPU_NPROC", "1")),
         process_id=int(os.environ.get("MXTPU_RANK", "0")))
     timeout = os.environ.get("MXTPU_INIT_TIMEOUT")
     if timeout:
-        try:
-            jax.distributed.initialize(
-                initialization_timeout=int(float(timeout)), **kwargs)
-            return True
-        except TypeError:
-            pass  # older jaxlib without the kwarg: fall through
+        kwargs["initialization_timeout"] = int(float(timeout))
     jax.distributed.initialize(**kwargs)
     return True
 

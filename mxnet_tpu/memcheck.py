@@ -17,7 +17,7 @@ can be ``ShapeDtypeStruct``s, no buffer is ever allocated — and derives a
 :class:`MemoryReport` from ``compiled.memory_analysis()`` plus the
 scheduled-HLO view: peak HBM, argument/output/temp/alias bytes, and a
 breakdown attributing the largest buffers to op paths and source provenance
-(the same ``op_name``/``source_file`` metadata tracecheck's collective audit
+(the same ``op_name``/``stack_frame_id`` metadata tracecheck's collective audit
 reads).
 
 Memory lint catalog (docs/static_analysis.md "Memory lints"):
@@ -169,7 +169,12 @@ _INSTR_RE = re.compile(
 _COMP_RE = re.compile(r"^(?P<entry>ENTRY\s+)?%(?P<name>[\w.\-]+)\s*\(.*\{\s*$")
 # op_name may contain escaped quotes: op_name="state[\'p\']"
 _OPNAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
-_SOURCE_RE = re.compile(r'source_file="([^"]+)"\s+source_line=(\d+)')
+# instruction metadata names a row of the module header's StackFrames
+# table; FileLocations / FileNames resolve the row to file:line
+_FRAME_ID_RE = re.compile(r"stack_frame_id=(\d+)")
+_FRAME_ROW_RE = re.compile(r"^(\d+)\s+(.+)$")
+_FRAME_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+_FIRST_COMP_RE = re.compile(r"^(?:%|ENTRY )", re.M)
 # input_output_alias={ {0}: (0, {}, may-alias), {1}: (1, {}, may-alias) }
 _ALIAS_MAP_RE = re.compile(r"input_output_alias=\{(?P<body>.*?)\}\s*,?\s*"
                            r"entry_computation_layout", re.S)
@@ -197,6 +202,46 @@ def _unescape(s):
     return s.replace("\\'", "'").replace('\\"', '"')
 
 
+def parse_stack_frames(hlo_text):
+    """``{stack_frame_id: "file:line"}`` from the header tables of a
+    compiled module's text. The frame an instruction names is the
+    innermost user frame of the op that produced it — what the analyzers
+    report as a finding's provenance."""
+    tables, cur = {}, None
+    # the tables sit above the first computation: split only that much of
+    # a multi-MB module text
+    first_comp = _FIRST_COMP_RE.search(hlo_text)
+    header = hlo_text[:first_comp.start()] if first_comp else hlo_text
+    for line in header.splitlines():
+        line = line.strip()
+        if line in _FRAME_TABLES:
+            cur = tables.setdefault(line, {})
+        elif cur is not None:
+            m = _FRAME_ROW_RE.match(line)
+            if m:
+                cur[int(m.group(1))] = m.group(2)
+
+    def field(row, key):
+        m = re.search(r"\b%s=(\d+)" % key, row or "")
+        return int(m.group(1)) if m else None
+
+    frames = {}
+    for fid, row in tables.get("StackFrames", {}).items():
+        loc = tables.get("FileLocations", {}).get(
+            field(row, "file_location_id"))
+        fname = tables.get("FileNames", {}).get(field(loc, "file_name_id"))
+        if fname is not None:
+            frames[fid] = "%s:%s" % (fname.strip('"'), field(loc, "line"))
+    return frames
+
+
+def instr_provenance(line, frames):
+    """``file:line`` of one instruction line (None when it carries no
+    frame), against :func:`parse_stack_frames`' table."""
+    m = _FRAME_ID_RE.search(line)
+    return frames.get(int(m.group(1))) if m else None
+
+
 def parse_hlo_buffers(hlo_text):
     """Walk the scheduled HLO text of a compiled program and return
     ``(buffers, entry_params, aliased_params)``:
@@ -213,6 +258,7 @@ def parse_hlo_buffers(hlo_text):
       output (successful donation), from the ``input_output_alias`` header.
     """
     buffers, entry_params, aliased = [], {}, set()
+    frames = parse_stack_frames(hlo_text)
     m = _ALIAS_MAP_RE.search(hlo_text)
     if m:
         for e in _ALIAS_ENTRY_RE.finditer(m.group("body")):
@@ -241,14 +287,12 @@ def parse_hlo_buffers(hlo_text):
         if opcode == "parameter" and not in_entry:
             continue  # sub-computation params alias their call operands
         op = _OPNAME_RE.search(line)
-        src = _SOURCE_RE.search(line)
         buffers.append({
             "bytes": nbytes,
             "opcode": opcode,
             "instruction": im.group("instr"),
             "op_path": _unescape(op.group(1)) if op else None,
-            "provenance": ("%s:%s" % (src.group(1), src.group(2))
-                           if src else None),
+            "provenance": instr_provenance(line, frames),
         })
     buffers.sort(key=lambda b: b["bytes"], reverse=True)
     return buffers, entry_params, aliased

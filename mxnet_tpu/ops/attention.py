@@ -117,9 +117,8 @@ def _attend(q, k, v, causal, block, seq_par):
                 _ring.ulysses_attention, axis_name="seq",
                 attn_fn=functools.partial(_ring.blockwise_attention,
                                           block_size=block, causal=causal))
-        from ..parallel.mesh import shard_map_compat
-        return shard_map_compat(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                                out_specs=spec)(q, k, v)
+        return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec)(q, k, v)
     return _ring.blockwise_attention(q, k, v, block_size=block,
                                      causal=causal)
 

@@ -24,6 +24,7 @@ matmul operand reads.
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -63,7 +64,14 @@ def _matmul_stats_raw(x, w, interpret=False):
     tm = _tile_m(m)
     tn = n if n <= 256 else 256
     if tm is None or n % tn or n % 128:
-        # shape outside the kernel's envelope: plain XLA fallback
+        # shape outside the kernel's envelope: plain XLA, and said once per
+        # shape (trace time; the warnings filter dedups) so a run under
+        # the knob cannot pass for fused where it is not
+        warnings.warn(
+            "MXTPU_FUSE_CONV_BN: the conv1x1+BN-stats kernel does not cover "
+            "a (%d, %d) x (%d, %d) product (rows need a multiple-of-16 "
+            "divisor <= 1024, channels a multiple of 128); this layer runs "
+            "plain XLA" % (m, k, k, n))
         yacc = jnp.dot(x, w, preferred_element_type=acc_dt)
         return (yacc.astype(x.dtype), jnp.sum(yacc, axis=0),
                 jnp.sum(yacc * yacc, axis=0))

@@ -186,33 +186,6 @@ def shard_batch(tree, mesh, axis=AXIS_DATA):
     return jax.tree_util.tree_map(put, tree)
 
 
-def shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                     check_vma=None, **kw):
-    """``jax.shard_map`` across jax versions: new jax exposes it at the top
-    level with a ``check_vma`` kwarg; this build (0.4.x) only has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``. One shim so
-    the ring/Ulysses/pipeline code runs on both instead of failing on the
-    rename."""
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def axis_size(axis_name):
-    """Static size of a mapped mesh axis from inside a shard_map body —
-    ``jax.lax.axis_size`` where it exists (newer jax), else ``psum(1)``,
-    which folds to a concrete int at trace time on 0.4.x."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return int(jax.lax.psum(1, axis_name))
-
-
 def data_axis_size(mesh, axis=AXIS_DATA):
     """Number of shards along the mesh's data axis (1 when absent) — the
     divisor every global batch dimension must honor."""

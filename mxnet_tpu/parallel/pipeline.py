@@ -35,8 +35,7 @@ def pipeline_spmd(stage_fn, stacked_params, microbatches, axis_name="pipe"):
 
     Returns (M, ...) outputs of the LAST stage, identical on every device.
     """
-    from .mesh import axis_size
-    S = axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     M = microbatches.shape[0]
     local_params = jax.tree_util.tree_map(lambda p: p[0], stacked_params)
@@ -113,8 +112,7 @@ def pipeline_apply(stage_fn, stacked_params, batch, mesh, axis_name="pipe",
     bspec = P() if batch_axis is None else P(None, batch_axis)
 
     pspec = jax.tree_util.tree_map(lambda _: P(axis_name), stacked_params)
-    from .mesh import shard_map_compat
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(pipeline_spmd, stage_fn, axis_name=axis_name),
         mesh=mesh,
         in_specs=(pspec, bspec),

@@ -86,8 +86,7 @@ def ring_attention(q, k, v, axis_name="seq", causal=False):
     local q accumulates blockwise-softmax statistics. Communication rides ICI
     neighbor links — bandwidth-optimal for long context.
     """
-    from .mesh import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, h, sq, d = q.shape
     scale = 1.0 / (d ** 0.5)
@@ -131,8 +130,7 @@ def ulysses_attention(q, k, v, axis_name="seq", attn_fn=None):
     """Ulysses-style sequence parallelism: all-to-all converts sequence
     sharding into head sharding, full-sequence attention runs locally per
     head group, then the layout is restored."""
-    from .mesh import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def a2a(x, split_axis, concat_axis):
         return jax.lax.all_to_all(x, axis_name, split_axis=split_axis,

@@ -39,7 +39,17 @@ def _load_native():
             try:
                 subprocess.run(["make", "-C", src], check=True,
                                capture_output=True)
-            except Exception:
+            except (OSError, subprocess.CalledProcessError) as e:
+                # said ONCE (_NATIVE latches): the Pillow pipeline that
+                # takes over is several times slower per core, so a build
+                # that broke must not pass for a slow disk
+                import warnings
+                warnings.warn(
+                    "could not build %s (`make -C %s`: %s); falling back "
+                    "to the Pillow pipeline.\n%s"
+                    % (so, src, e,
+                       (getattr(e, "stderr", b"") or b"").decode(
+                           "utf-8", "replace")[-2000:]))
                 _NATIVE = False
                 return None
     if not os.path.exists(so):

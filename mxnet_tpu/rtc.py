@@ -16,7 +16,7 @@ Example::
         o_ref[...] = x_ref[...] * 2.0
 
     k = mx.rtc.PallasKernel(scale_kernel, out_like=0)
-    y = k(mx.nd.ones((8, 128)))
+    y = k(mx.nd.ones((8, 128)))          # on a CPU host: interpret=True
 """
 from __future__ import annotations
 
@@ -41,21 +41,19 @@ class PallasKernel(object):
         Pallas grid; default single program instance.
     in_specs / out_specs : optional pl.BlockSpec lists.
     interpret : bool
-        Run in interpret mode (CPU debugging).
+        Run in the Pallas interpreter (CPU debugging). Never chosen for
+        you: without it the kernel compiles for the attached chip, and a
+        host with no chip gets the compiler's error, not the interpreter.
     """
 
     def __init__(self, kernel, out_like=0, grid=None, in_specs=None,
-                 out_specs=None, interpret=None):
+                 out_specs=None, interpret=False):
         self.kernel = kernel
         self.out_like = out_like
         self.grid = grid
         self.in_specs = in_specs
         self.out_specs = out_specs
-        if interpret is None:
-            # interpret automatically off-TPU so kernels are debuggable
-            # on the CPU mesh
-            interpret = jax.default_backend() not in ("tpu",)
-        self.interpret = interpret
+        self.interpret = bool(interpret)
         self._jitted = None
 
     def _out_shape(self, arrays):

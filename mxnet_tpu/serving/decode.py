@@ -701,6 +701,12 @@ class DecodeLoop(object):
         return self._dev([np.int32(i)])[0]
 
     # ------------------------------------------------------------------
+    @property
+    def devices(self):
+        """The devices holding this loop's parameters, by id."""
+        from .engine import _leaf_devices
+        return _leaf_devices(self._params)
+
     def weight_bytes(self):
         """Resident HBM bytes of the (possibly quantized) parameter
         set(s) — target plus draft; GLOBAL across shards (a fully

@@ -54,9 +54,12 @@ def default_buckets():
 
 def _model_mesh(contexts, who="ServingEngine"):
     """Resolve ``contexts=`` (int N, or a list of Context/jax.Device) to a
-    one-axis 'model' mesh — or None for the single-chip path. The mesh is
-    the unit one REPLICA serves from: a fleet runs N of these side by side
-    (docs/serving.md "Model-parallel replicas")."""
+    one-axis 'model' mesh — or None for the default device (no contexts,
+    or the int 1). A one-element list is a one-device mesh, so the replica
+    lives on THAT device: N one-chip replicas behind a FleetRouter each
+    name their own chip. The mesh is the unit one REPLICA serves from: a
+    fleet runs N of these side by side (docs/serving.md "Model-parallel
+    replicas")."""
     if not contexts:
         return None
     import jax
@@ -68,13 +71,22 @@ def _model_mesh(contexts, who="ServingEngine"):
         return _mesh.model_parallel_mesh(contexts, jax.local_devices())
     devs = [c.to_device() if isinstance(c, Context) else c
             for c in contexts]
-    if len(devs) <= 1:
-        return None
     if len(set(devs)) != len(devs):
         raise MXNetError(
             "%s: contexts resolve to duplicate devices %r — each model "
             "shard needs its own chip" % (who, devs))
     return _mesh.make_mesh({_mesh.AXIS_MODEL: len(devs)}, devs)
+
+
+def _leaf_devices(tree):
+    """Devices the array leaves of ``tree`` live on, sorted by id — what
+    ``ServingEngine.devices`` / ``DecodeLoop.devices`` report (read off
+    the arrays, not off the request that placed them)."""
+    import jax
+    devs = set()
+    for leaf in jax.tree_util.tree_leaves(tree):
+        devs |= leaf.devices()
+    return sorted(devs, key=lambda d: d.id)
 
 
 def _audit_load_comms(obj, who):
@@ -194,8 +206,9 @@ class ServingEngine(object):
         #: (``contexts=``): params shard over 'model' per the
         #: parallel.placement first-divisible-dim rule, batch inputs stay
         #: replicated at the edges, and every bucket program compiles
-        #: partitioned — bitwise-identical to the single-chip engine
-        #: (the rule never splits a contraction dim)
+        #: partitioned — equal to the single-chip engine up to the
+        #: backend's per-shape kernel choice (the rule never splits a
+        #: contraction dim)
         self._mesh = _model_mesh(contexts, who="ServingEngine")
         self._symbol = _strip_loss_heads(load_symbol(symbol_json_or_file))
         if output_names:
@@ -259,8 +272,8 @@ class ServingEngine(object):
         def place(arr, sharded):
             """Model-mesh placement: params shard per the placement rule
             (first divisible dim = the OUTPUT dim of an (out, in) weight,
-            so contraction dims never split and the partitioned forward
-            stays bitwise with single-chip); aux stats replicate."""
+            so contraction dims never split and no sum changes its order
+            against single-chip); aux stats replicate."""
             if self._mesh is None:
                 return arr
             from ..parallel import placement as _pl
@@ -334,13 +347,14 @@ class ServingEngine(object):
             # FULL contractions (operand replicated, weight sharded on its
             # output dim — the placement first-divisible-dim rule), then
             # all-gathers the slice. That is Megatron column-parallel +
-            # gather, and it is what makes the sharded engine BITWISE
-            # identical to the single-chip one: no reduction ever spans
-            # shards, so float summation order never changes. Letting
+            # gather, and it keeps the sharded engine equal to the
+            # single-chip one to the last ulp or two: no reduction ever
+            # spans shards, so float summation order never changes (what
+            # remains is the backend picking kernels by shard shape). Letting
             # activations stay sharded between ops is faster on paper but
             # lets GSPMD split a later contraction (or a softmax row
-            # reduction) into partial sums — a 1-ulp drift the parity
-            # acceptance test catches immediately.
+            # reduction) into partial sums, and that drift grows with the
+            # contraction instead of staying at rounding.
             _repl = jax.sharding.NamedSharding(
                 self._mesh, jax.sharding.PartitionSpec())
 
@@ -450,6 +464,11 @@ class ServingEngine(object):
         """Number of chips one replica of this engine spans (1 =
         single-chip)."""
         return 1 if self._mesh is None else int(self._mesh.devices.size)
+
+    @property
+    def devices(self):
+        """The devices holding this engine's parameters, by id."""
+        return _leaf_devices(self._params)
 
     def bucket_for(self, n):
         """Smallest compiled bucket covering ``n`` examples."""
@@ -663,10 +682,14 @@ class ServingEngine(object):
                 raise MXNetError(
                     "executable file %s was exported for a different "
                     "bucket/shape configuration" % (path,))
+            # load over the devices this engine occupies — the default is
+            # every local device, which a one-chip program cannot take
+            devs = (self.devices if self._mesh is None
+                    else list(self._mesh.devices.flat))
             for b in self.buckets:
                 blob, in_tree, out_tree = payload["buckets"][b]
                 self._compiled[b] = _se.deserialize_and_load(
-                    blob, in_tree, out_tree)
+                    blob, in_tree, out_tree, execution_devices=devs)
             return True
         except Exception as e:
             logging.warning(

@@ -134,7 +134,8 @@ _SCAN_COLLECTIVE_PRIMS = frozenset({
 #: means a host round-trip on every execution (the scan body runs them K
 #: times per dispatch)
 _HOST_SYNC_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
     "infeed", "outfeed", "outside_call", "host_callback_call",
 })
 
@@ -285,8 +286,7 @@ def enabled():
 # ---------------------------------------------------------------------------
 
 def _sub_jaxprs(eqn):
-    import jax
-    core = jax.core
+    from jax.extend import core
     for v in eqn.params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for item in vals:
@@ -635,12 +635,14 @@ def _lint_host_sync(closed, hlo_text, name):
             findings.append(Finding("host-sync", name, msg, op_path=path,
                                     provenance=_provenance(eqn)))
     if not findings and hlo_text:
-        for pat in _HLO_HOST_SYNC:
-            if pat in hlo_text:
-                findings.append(Finding(
-                    "host-sync", name,
-                    "lowered StableHLO contains host-transfer construct %r"
-                    % pat, op_path="stablehlo"))
+        # one finding per program: a callback's custom-call target can
+        # match several patterns (``xla_ffi_python_cpu_callback``)
+        pat = next((p for p in _HLO_HOST_SYNC if p in hlo_text), None)
+        if pat is not None:
+            findings.append(Finding(
+                "host-sync", name,
+                "lowered StableHLO contains host-transfer construct %r"
+                % pat, op_path="stablehlo"))
     return findings
 
 

@@ -69,6 +69,25 @@ def _sds(shape, mesh=None, spec=None, dtype=np.float32):
 
 _FAKE_HLO = """HloModule t, is_scheduled=true, entry_computation_layout={(f32[8]{0})->f32[8]{0}}
 
+FileNames
+1 "a.py"
+
+FunctionNames
+1 "f"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=3 end_line=3 column=1 end_column=9}
+2 {file_name_id=1 function_name_id=1 line=7 end_line=7 column=1 end_column=9}
+3 {file_name_id=1 function_name_id=1 line=9 end_line=9 column=1 end_column=9}
+4 {file_name_id=1 function_name_id=1 line=11 end_line=11 column=1 end_column=9}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+2 {file_location_id=2 parent_frame_id=1}
+3 {file_location_id=3 parent_frame_id=1}
+4 {file_location_id=4 parent_frame_id=1}
+
+
 %wide.body (p: f32[8]) -> f32[8] {
   %p.1 = f32[8]{0} parameter(0)
 }
@@ -76,10 +95,10 @@ _FAKE_HLO = """HloModule t, is_scheduled=true, entry_computation_layout={(f32[8]
 ENTRY %main.1 (Arg_0.1: f32[8], Arg_1.2: f32[16,4]) -> f32[8] {
   %Arg_0.1 = f32[8]{0} parameter(0), metadata={op_name="state['w']"}
   %Arg_1.2 = f32[16,4]{1,0} parameter(1), metadata={op_name="batch"}
-  %all-reduce.1 = f32[8]{0} all-reduce(f32[8]{0} %mul.1), channel_id=1, replica_groups=[1,8]<=[8], use_global_device_ids=true, to_apply=%add, metadata={op_name="jit(f)/jit(main)/while/body/psum" source_file="a.py" source_line=3}
-  %all-gather.1 = f32[64,4]{1,0} all-gather(f32[16,4]{1,0} %Arg_1.2), channel_id=2, replica_groups=[2,4]<=[4,2]T(1,0), dimensions={0}, metadata={op_name="jit(f)/jit(main)/gather" source_file="a.py" source_line=7}
-  %all-to-all.1 = (f32[2,4]{1,0}, f32[2,4]{1,0}) all-to-all(f32[2,4]{1,0} %s.1, f32[2,4]{1,0} %s.2), channel_id=3, replica_groups={{0,1},{2,3},{4,5},{6,7}}, metadata={op_name="jit(f)/jit(main)/a2a" source_file="a.py" source_line=9}
-  %collective-permute-start.1 = f32[4,4]{1,0} collective-permute-start(f32[4,4]{1,0} %q.1), channel_id=4, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}, metadata={op_name="jit(f)/jit(main)/while/body/ppermute" source_file="a.py" source_line=11}
+  %all-reduce.1 = f32[8]{0} all-reduce(f32[8]{0} %mul.1), channel_id=1, replica_groups=[1,8]<=[8], use_global_device_ids=true, to_apply=%add, metadata={op_name="jit(f)/jit(main)/while/body/psum" stack_frame_id=1}
+  %all-gather.1 = f32[64,4]{1,0} all-gather(f32[16,4]{1,0} %Arg_1.2), channel_id=2, replica_groups=[2,4]<=[4,2]T(1,0), dimensions={0}, metadata={op_name="jit(f)/jit(main)/gather" stack_frame_id=2}
+  %all-to-all.1 = (f32[2,4]{1,0}, f32[2,4]{1,0}) all-to-all(f32[2,4]{1,0} %s.1, f32[2,4]{1,0} %s.2), channel_id=3, replica_groups={{0,1},{2,3},{4,5},{6,7}}, metadata={op_name="jit(f)/jit(main)/a2a" stack_frame_id=3}
+  %collective-permute-start.1 = f32[4,4]{1,0} collective-permute-start(f32[4,4]{1,0} %q.1), channel_id=4, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}, metadata={op_name="jit(f)/jit(main)/while/body/ppermute" stack_frame_id=4}
   %collective-permute-done.1 = f32[4,4]{1,0} collective-permute-done(f32[4,4]{1,0} %collective-permute-start.1)
 }
 """
@@ -112,9 +131,22 @@ def test_parser_kinds_groups_and_loop_detection():
 
 _ASYNC_HLO = """HloModule t, is_scheduled=true, entry_computation_layout={(f32[8,4]{1,0})->f32[32,4]{1,0}}
 
+FileNames
+1 "a.py"
+
+FunctionNames
+1 "f"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=4 end_line=4 column=1 end_column=9}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+
+
 ENTRY %main.1 (p0: f32[8,4]) -> f32[32,4] {
   %p0 = f32[8,4]{1,0} parameter(0)
-  %all-gather-start.1 = (f32[8,4]{1,0}, f32[32,4]{1,0}) all-gather-start(f32[8,4]{1,0} %p0.copy), channel_id=1, replica_groups={{0,1,2,3}}, dimensions={0}, metadata={op_name="jit(f)/ag" source_file="a.py" source_line=4}
+  %all-gather-start.1 = (f32[8,4]{1,0}, f32[32,4]{1,0}) all-gather-start(f32[8,4]{1,0} %p0.copy), channel_id=1, replica_groups={{0,1,2,3}}, dimensions={0}, metadata={op_name="jit(f)/ag" stack_frame_id=1}
   %all-gather-done.1 = f32[32,4]{1,0} all-gather-done((f32[8,4]{1,0}, f32[32,4]{1,0}) %all-gather-start.1)
 }
 """
@@ -267,10 +299,9 @@ def test_ring_attention_signature_ppermute_only():
     the 'seq' axis); an all-gather would mean the ring degenerated into
     every chip holding the full sequence."""
     from mxnet_tpu.parallel import ring as pring
-    from mxnet_tpu.parallel.mesh import shard_map_compat
     n = min(4, len(jax.devices()))
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:n]), ("seq",))
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(pring.ring_attention, axis_name="seq",
                           causal=True),
         mesh=mesh, in_specs=(_seq_spec(),) * 3, out_specs=_seq_spec(),
@@ -289,10 +320,9 @@ def test_ulysses_signature_all_to_all_only():
     input all-to-alls (q, k, v) + 1 output all-to-all per attention, and
     nothing else — no all-gather, no ppermute."""
     from mxnet_tpu.parallel import ring as pring
-    from mxnet_tpu.parallel.mesh import shard_map_compat
     n = min(4, len(jax.devices()))
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:n]), ("seq",))
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(pring.ulysses_attention, axis_name="seq"),
         mesh=mesh, in_specs=(_seq_spec(),) * 3, out_specs=_seq_spec(),
         check_vma=False)
@@ -386,7 +416,7 @@ def test_zoo_single_device_program_has_empty_inventory():
 def _gather_in_scan_program(n=4):
     """The regression the drift gate exists for: an all_gather inside
     the scan body."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:n]), ("data",))
 
     def bad(xs):
@@ -397,7 +427,7 @@ def _gather_in_scan_program(n=4):
         return out
 
     sm = shard_map(bad, mesh=mesh, in_specs=P(None, "data"), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     xs = _sds((3, 8 * n), mesh, P(None, "data"))
     return jax.jit(sm), (xs,), mesh
 

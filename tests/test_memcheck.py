@@ -87,6 +87,19 @@ def test_hlo_buffer_parse_shapes():
     use (and sub-byte types), and skips view ops."""
     txt = """HloModule t, is_scheduled=true, input_output_alias={ {0}: (1, {}, may-alias) }, entry_computation_layout={(f32[4]{0})->f32[4]{0}}
 
+FileNames
+1 "x.py"
+
+FunctionNames
+1 "f"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=7 end_line=7 column=1 end_column=9}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+
+
 %fused_computation (p: f32[8,8]) -> f32[8,8] {
   %inner.1 = f32[8,8]{1,0} multiply(f32[8,8]{1,0} %p, f32[8,8]{1,0} %p)
 }
@@ -94,7 +107,7 @@ def test_hlo_buffer_parse_shapes():
 ENTRY %main.1 (Arg_0.1: f32[4]) -> f32[4] {
   %Arg_0.1 = f32[4]{0} parameter(0), metadata={op_name="state[\\'p\\']"}
   %Arg_1.2 = bf16[2,3]{1,0} parameter(1), metadata={op_name="batch"}
-  %big.1 = f32[128,2]{1,0} broadcast(f32[4]{0} %Arg_0.1), metadata={op_name="jit(f)/bcast" source_file="x.py" source_line=7}
+  %big.1 = f32[128,2]{1,0} broadcast(f32[4]{0} %Arg_0.1), metadata={op_name="jit(f)/bcast" stack_frame_id=1}
   %gte.1 = f32[4]{0} get-tuple-element(%big.1), index=0
   %pred.1 = pred[16]{0} compare(f32[4]{0} %Arg_0.1, f32[4]{0} %Arg_0.1)
 }

@@ -56,7 +56,7 @@ def _seq_mesh(n):
 
 def test_ring_attention_matches_full():
     """Ring attention over a 4-device 'seq' axis == full attention."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     n = 4
     mesh = _seq_mesh(n)
@@ -72,7 +72,7 @@ def test_ring_attention_matches_full():
 
 
 def test_ring_attention_causal():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     n = 4
     mesh = _seq_mesh(n)
@@ -88,7 +88,7 @@ def test_ring_attention_causal():
 
 
 def test_ulysses_attention_matches_full():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     n = 2
     mesh = _seq_mesh(n)
@@ -106,7 +106,7 @@ def test_ulysses_attention_matches_full():
 def test_make_mesh_and_grad_sync():
     mesh = make_mesh({"data": 4, "model": 2})
     assert mesh.shape == {"data": 4, "model": 2}
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     dp = data_parallel_mesh(4)
 

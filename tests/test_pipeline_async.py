@@ -544,7 +544,7 @@ def test_imperative_invoke_in_place_shape_mismatch_fails():
     from mxnet_tpu import c_api
     code, h_in = c_api.MXNDArrayCreate([3], 1, 0)
     assert code == 0
-    code, h_out = c_api.MXNDArrayCreate([2, 3], 2, 0)
+    code, h_out = c_api.MXNDArrayCreate([2, 3], 1, 0)
     assert code == 0
     before = c_api._get(h_out).asnumpy().copy()
     code, err = c_api.MXImperativeInvokeInPlace("square", [h_in], {},

@@ -12,7 +12,7 @@ def test_pallas_kernel_basic():
     def scale_kernel(x_ref, o_ref):
         o_ref[...] = x_ref[...] * 2.0
 
-    k = mx.rtc.PallasKernel(scale_kernel, out_like=0)
+    k = mx.rtc.PallasKernel(scale_kernel, out_like=0, interpret=True)
     y = k(nd.ones((8, 128)))
     assert (y.asnumpy() == 2.0).all()
 
@@ -21,7 +21,7 @@ def test_pallas_kernel_two_inputs():
     def addmul_kernel(a_ref, b_ref, o_ref):
         o_ref[...] = a_ref[...] * b_ref[...] + a_ref[...]
 
-    k = mx.rtc.PallasKernel(addmul_kernel, out_like=0)
+    k = mx.rtc.PallasKernel(addmul_kernel, out_like=0, interpret=True)
     a = np.random.rand(8, 128).astype(np.float32)
     b = np.random.rand(8, 128).astype(np.float32)
     y = k(nd.array(a), nd.array(b))

@@ -112,7 +112,7 @@ def test_dtype_lint_f64_literal():
     """An f64 literal leaking into the step program (only reachable with
     x64 enabled — exactly the config drift the lint is for) is reported
     with the producing op and provenance."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         def f64_math(x):
             return x * np.float64(2.0)
@@ -603,7 +603,7 @@ def test_collective_lint_flags_explicit_allgather_in_scan():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     P = jax.sharding.PartitionSpec
     mesh = _dp_mesh()
 
@@ -615,7 +615,7 @@ def test_collective_lint_flags_explicit_allgather_in_scan():
         return out
 
     sm = shard_map(bad, mesh=mesh, in_specs=P(None, "data"), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     xs = jax.device_put(np.ones((4, 8), np.float32),
                         jax.sharding.NamedSharding(mesh, P(None, "data")))
     findings = [f for f in tc.check_program(jax.jit(sm), (xs,),
@@ -633,7 +633,7 @@ def test_collective_lint_allows_psum_in_scan():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     P = jax.sharding.PartitionSpec
     mesh = _dp_mesh()
 
@@ -644,7 +644,7 @@ def test_collective_lint_allows_psum_in_scan():
         return out
 
     sm = shard_map(good, mesh=mesh, in_specs=P(None, "data"), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     xs = jax.device_put(np.ones((4, 8), np.float32),
                         jax.sharding.NamedSharding(mesh, P(None, "data")))
     assert [f for f in tc.check_program(jax.jit(sm), (xs,), name="psum-scan")
@@ -658,7 +658,7 @@ def test_collective_lint_suppressible():
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         P = jax.sharding.PartitionSpec
         mesh = _dp_mesh()
 
@@ -668,7 +668,7 @@ def test_collective_lint_suppressible():
             return jax.lax.scan(body, jnp.float32(0), xs)[0]
 
         sm = shard_map(bad, mesh=mesh, in_specs=P(None, "data"),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         xs = jax.device_put(np.ones((4, 8), np.float32),
                             jax.sharding.NamedSharding(mesh, P(None, "data")))
         fs = [f for f in tc.check_program(jax.jit(sm), (xs,),

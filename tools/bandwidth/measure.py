@@ -25,7 +25,6 @@ def main():
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     devs = jax.devices()
@@ -43,7 +42,7 @@ def main():
 
     if n > 1:
         mesh = Mesh(np.array(devs), ("data",))
-        f = shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
+        f = jax.shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
                       in_specs=P("data"), out_specs=P())
 
         xs = jax.device_put(x, jax.sharding.NamedSharding(mesh, P("data")))

@@ -88,9 +88,6 @@ def main():
     ap.add_argument("--workdir", default=None)
     args = ap.parse_args()
 
-    import jax
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     import mxnet_tpu as mx
     from mxnet_tpu import models
 

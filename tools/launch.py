@@ -8,8 +8,8 @@ rendezvous (coordinator address + process_id + num_processes), and gradient
 sync rides psum over ICI/DCN instead of ps-lite push/pull.
 
 Launchers:
-  local — spawn N worker processes on this host (the reference's local
-          tracker; useful with a CPU mesh for testing dist_sync semantics)
+  local — spawn N worker processes on this host, on the CPU when N > 1
+          (the reference's local tracker; for testing dist_sync semantics)
   ssh   — spawn one worker per host in --host-file via ssh
 
 Each worker gets MXTPU_COORD / MXTPU_RANK / MXTPU_NPROC env vars; call
@@ -22,14 +22,34 @@ import subprocess
 import sys
 
 
+def local_platform(n):
+    """The JAX_PLATFORMS value ``n`` workers on THIS host run under. A chip
+    belongs to one process at a time, and one process drives every chip of
+    a host (``context=[mx.tpu(i) ...]``), so several local workers run on
+    the CPU; asking for an accelerator platform for them is refused rather
+    than left to hang on the chip the first worker took. One worker keeps
+    whatever the caller set."""
+    asked = os.environ.get("JAX_PLATFORMS", "").strip()
+    if n <= 1:
+        return asked
+    if asked not in ("", "cpu"):
+        raise SystemExit(
+            "launch.py: %d local workers under JAX_PLATFORMS=%s would all "
+            "claim the same device, and a chip belongs to one process. Run "
+            "local workers with JAX_PLATFORMS=cpu (or unset), drive a "
+            "host's chips from ONE process, or use --launcher ssh with one "
+            "worker per host." % (n, asked))
+    return "cpu"
+
+
 def launch_local(n, command, coord_port=12421):
+    platform = local_platform(n)
     procs = []
     for rank in range(n):
         env = dict(os.environ)
         env.update(MXTPU_COORD="localhost:%d" % coord_port,
                    MXTPU_RANK=str(rank), MXTPU_NPROC=str(n),
-                   # workers on one host must split visible devices or run cpu
-                   JAX_PLATFORMS=env.get("JAX_PLATFORMS", ""))
+                   JAX_PLATFORMS=platform)
         procs.append(subprocess.Popen(command, shell=True, env=env))
     code = 0
     for p in procs:
