@@ -1,0 +1,9 @@
+"""Decode: device idle per step while the loop thread was in
+``decode_dispatch`` (argument handling and enqueue of the step program): the
+trace's idle gaps intersected with the loop's own leaf spans
+(``harness/stepgaps.py``)."""
+from benchmark.harness import stepgaps
+
+
+def read(ctx):
+    return stepgaps.gap_ms(ctx, ("decode_dispatch",))

@@ -12,11 +12,19 @@ trace, with correlation IDs (``dispatch=`` / ``req=``) threaded through
 span args so one dispatch or one serving request reads as one timeline
 (docs/observability.md).
 
-Cost contract: with tracing AND the flight recorder off, :func:`span`
-is one module-global flag check returning a shared no-op context manager —
-no allocation, no clock read. ``MXTPU_TRACE=1`` arms it;
-``MXTPU_TRACE_PATH`` names the output file (default
-``mxtpu_trace.json``, written at interpreter exit and by :func:`save`).
+Cost contract, as it is: :func:`span` is one module-global flag check
+returning a shared no-op context manager (no allocation, no clock read)
+ONLY with tracing AND the flight recorder both off. The recorder is on by
+default (``MXTPU_FLIGHT_RECORDER``, :mod:`.flight`) and attaches itself as
+this module's sink at import, so in a default process every span is LIVE:
+a ``_Span``, two clock reads, one event dict and the ring's append (with
+tracing on, also a lock and a list append here). That is 1-3 us a span on
+a quiet core; CHANGES.md (PR 27) has the decode step's cost measured on
+the chip in all three states. A site whose ARGUMENTS are costly to build
+asks :func:`active` first; ``span()`` cannot spare what its caller already
+evaluated. ``MXTPU_TRACE=1`` arms the trace file; ``MXTPU_TRACE_PATH``
+names it (default ``mxtpu_trace.json``, written at interpreter exit and by
+:func:`save`).
 
 Event model (Chrome trace-event format, the subset Perfetto renders):
 
@@ -37,8 +45,9 @@ import time
 from ..base import env_bool, env_int, env_str
 
 __all__ = [
-    "span", "instant", "complete", "async_complete", "enabled", "start",
-    "stop", "save", "events", "clear", "trace_path", "set_sink",
+    "span", "instant", "complete", "async_complete", "enabled", "active",
+    "start", "stop", "save", "events", "clear", "trace_path", "set_sink",
+    "expand_laps", "NOOP",
 ]
 
 #: hard bound on buffered events — a runaway span site degrades to a
@@ -62,6 +71,18 @@ _named_tids = set()     # tids that already emitted thread_name metadata
 #: perf_counter_ns at module import — all ts are relative to this, so
 #: spans from every thread share one monotonic clock
 _EPOCH_NS = time.perf_counter_ns()
+
+#: this process's id, read once: ``os.getpid()`` is a system call on every
+#: event otherwise (several microseconds each on a sandboxed host)
+_PID = os.getpid()
+
+
+def _refresh_pid():
+    global _PID
+    _PID = os.getpid()
+
+
+os.register_at_fork(after_in_child=_refresh_pid)
 
 #: module-level fast-path flag: True when the tracer OR the flight
 #: recorder needs span records. span()/instant() check ONLY this.
@@ -91,6 +112,13 @@ def enabled():
     """True when spans are being recorded for the TRACE FILE (the flight
     recorder may keep span() live even when this is False)."""
     return _TRACING
+
+
+def active():
+    """True when :func:`span` returns a live span: tracing on, or the
+    flight recorder attached (its default). A site asks this before it
+    builds span arguments that cost something (a list per call)."""
+    return _ACTIVE
 
 
 def trace_path():
@@ -166,21 +194,50 @@ class _NoopSpan(object):
     def __exit__(self, *a):
         return False
 
+    def set(self, **args):
+        pass
+
+    def lap(self, name, **args):
+        pass
+
 
 _NOOP = _NoopSpan()
+#: the shared no-op span, for a site that hands "the live span, if any"
+#: down to the code it wraps
+NOOP = _NOOP
 
 
 class _Span(object):
-    __slots__ = ("name", "args", "_t0")
+    __slots__ = ("name", "args", "_t0", "_laps")
 
     def __init__(self, name, args):
         self.name = name
         self.args = args
         self._t0 = None
+        self._laps = None
 
     def __enter__(self):
         self._t0 = time.perf_counter_ns()
         return self
+
+    def set(self, **args):
+        """Add arguments known only at the span's end (what the region
+        turned out to process); they land in the event beside the ones
+        the span was opened with."""
+        self.args.update(args)
+
+    def lap(self, name, **args):
+        """End a phase of this span: ``name`` lasted from the end of the
+        lap before (or the span's start) until now. Laps are a span's
+        leaf phases at one clock read and one tuple each: contiguous by
+        construction, kept INSIDE the span's event (``args["laps"]``:
+        ``[name, offset_us, dur_us]``, plus the lap's own args if any),
+        so that a hot loop's phases do not multiply the events every
+        reader walks; :func:`expand_laps` (and so the written trace file)
+        turns them into child spans."""
+        if self._laps is None:
+            self._laps = []
+        self._laps.append((name, time.perf_counter_ns(), args))
 
     def __exit__(self, exc_type, exc, tb):
         t0 = self._t0
@@ -189,10 +246,22 @@ class _Span(object):
         if exc_type is not None:
             args = dict(args)
             args["error"] = exc_type.__name__
+        if self._laps is not None:
+            # on the grid of ts and dur, so that laps nest in their span
+            laps, start = [], (t0 - _EPOCH_NS) // 1000
+            at = start
+            for name, t, largs in self._laps:
+                end = (t - _EPOCH_NS) // 1000
+                lap = [name, at - start, end - at]
+                laps.append(lap + [largs] if largs else lap)
+                at = end
+            args["laps"] = laps
+        # both ends rounded on the epoch's grid, so that a span that ends
+        # inside another in nanoseconds also does in whole microseconds
+        ts = (t0 - _EPOCH_NS) // 1000
         _record({"ph": "X", "name": self.name, "cat": "host",
-                 "ts": (t0 - _EPOCH_NS) // 1000,
-                 "dur": max(0, (t1 - t0) // 1000),
-                 "pid": os.getpid(), "tid": threading.get_ident(),
+                 "ts": ts, "dur": (t1 - _EPOCH_NS) // 1000 - ts,
+                 "pid": _PID, "tid": threading.get_ident(),
                  "args": args})
         return False
 
@@ -201,9 +270,12 @@ def span(name, **args):
     """Context manager timing one host region as a Chrome complete event.
 
     ``args`` are the correlation payload (``dispatch=i``, ``req=rid``, …)
-    and land in the event's ``args`` dict. When neither tracing nor the
-    flight recorder is armed this returns a shared no-op instance —
-    near-zero cost at every instrumented site."""
+    and land in the event's ``args`` dict; ``with span(...) as sp`` and
+    ``sp.set(**more)`` adds what is known only at the end, ``sp.lap(name)``
+    ends a phase inside the span. When neither
+    tracing nor the flight recorder is armed this returns a shared no-op
+    instance (whose ``set`` and ``lap`` do nothing); in a default process the
+    recorder is armed and the span is live (see the module docstring)."""
     if not _ACTIVE:
         return _NOOP
     return _Span(name, args)
@@ -220,7 +292,7 @@ def complete(name, dur_s, **args):
     now = _now_us()
     dur = max(0, int(dur_s * 1e6))
     _record({"ph": "X", "name": name, "cat": "host", "ts": now - dur,
-             "dur": dur, "pid": os.getpid(),
+             "dur": dur, "pid": _PID,
              "tid": threading.get_ident(), "args": args})
 
 
@@ -235,7 +307,7 @@ def async_complete(name, dur_s, id, **args):
         return
     now = _now_us()
     dur = max(0, int(dur_s * 1e6))
-    pid = os.getpid()
+    pid = _PID
     tid = threading.get_ident()
     _record({"ph": "b", "name": name, "cat": "async", "id": id,
              "ts": now - dur, "pid": pid, "tid": tid, "args": args})
@@ -248,8 +320,31 @@ def instant(name, **args):
     if not _ACTIVE:
         return
     _record({"ph": "i", "name": name, "cat": "host", "s": "t",
-             "ts": _now_us(), "pid": os.getpid(),
+             "ts": _now_us(), "pid": _PID,
              "tid": threading.get_ident(), "args": args})
+
+
+def expand_laps(evs):
+    """``evs`` with every span's laps (:meth:`_Span.lap`) written out as
+    child complete events, each after its parent: same thread, the lap's
+    own args, and the parent's scalar args (its correlation ids: a
+    ``step``, a ``dispatch``). What :func:`save` writes, so that Perfetto
+    shows the phases nested in their span."""
+    out = []
+    for ev in evs:
+        out.append(ev)
+        laps = ev.get("args", {}).get("laps") if ev.get("ph") == "X" else None
+        if not laps:
+            continue
+        inherit = {k: v for k, v in ev["args"].items()
+                   if isinstance(v, (int, float, str))}
+        for lap in laps:
+            out.append({"ph": "X", "name": lap[0], "cat": ev["cat"],
+                        "ts": ev["ts"] + lap[1], "dur": lap[2],
+                        "pid": ev["pid"], "tid": ev["tid"],
+                        "args": dict(inherit, **(lap[3] if len(lap) > 3
+                                                 else {}))})
+    return out
 
 
 def save(path=None):
@@ -260,7 +355,7 @@ def save(path=None):
     with _lock:
         evs = list(_events)
         dropped = _dropped
-    doc = {"traceEvents": evs, "displayTimeUnit": "ms",
+    doc = {"traceEvents": expand_laps(evs), "displayTimeUnit": "ms",
            "otherData": {"producer": "mxnet_tpu.obs",
                          "dropped_events": dropped}}
     atomic_write_bytes(path, json.dumps(doc).encode("utf-8"))

@@ -21,11 +21,15 @@ sides of the Perfetto view (docs/observability.md).
 from __future__ import annotations
 
 import os
+import time
 
 import jax
 
 from .base import MXNetError
 from .obs import trace as _obs_trace
+
+#: the name of the event that aligns the device trace with the host trace
+CLOCK_SYNC = "mxtpu_clock_sync"
 
 _state = {"running": False, "dir": "profile_output", "mode": "symbolic"}
 
@@ -52,11 +56,24 @@ def profiler_set_state(state="stop"):
         _autostart_pending = False  # an explicit start supersedes it
         jax.profiler.start_trace(_state["dir"])
         _state["running"] = True
+        _clock_sync()
     elif state == "stop" and _state["running"]:
         jax.profiler.stop_trace()
         _state["running"] = False
     elif state not in ("run", "stop"):
         raise MXNetError("profiler state must be 'run' or 'stop'")
+
+
+def _clock_sync():
+    """One instant on both clocks: a ``mxtpu_clock_sync`` annotation in
+    the device trace's host plane and, opened inside it, an instant of
+    the same name in the host trace that carries its own
+    ``perf_counter_ns``. The device trace's clock and the host trace's
+    (microseconds since ``obs.trace``'s epoch) then differ by the
+    difference of the two events' starts (docs/observability.md)."""
+    with jax.profiler.TraceAnnotation(CLOCK_SYNC):
+        _obs_trace.instant(CLOCK_SYNC,
+                           perf_counter_ns=time.perf_counter_ns())
 
 
 def maybe_autostart():

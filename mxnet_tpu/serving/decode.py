@@ -134,39 +134,50 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
         nslots = tokens.shape[0]
         rows = ck.shape[3]
         wpos = jnp.minimum(pos, jnp.int32(rows - 1))
-        x = edge(params["tok_embed_weight"][tokens]
-                 + params["pos_embed_weight"][wpos])
+        with jax.named_scope("embed"):
+            x = edge(params["tok_embed_weight"][tokens]
+                     + params["pos_embed_weight"][wpos])
         embed = x.shape[1]
         d = embed // num_heads
         scale = jnp.float32(1.0 / float(np.sqrt(d)))
         sidx = jnp.arange(nslots)
         tmask = jnp.arange(rows)[None, None, :] <= pos[:, None, None]
         neg = jnp.float32(-1e30)
+        # the scope names are what an operator searches a device trace
+        # for: the same in every layer, so they sum by kind
         for i in range(num_layers):
             pre = "layer%d" % i
-            a = _ln(x, params[pre + "_ln1_gamma"], params[pre + "_ln1_beta"])
-            qkv = a @ params[pre + "_attn_qkv_weight"].T \
-                + params[pre + "_attn_qkv_bias"]
-            qkv = qkv.reshape(nslots, 3, num_heads, d)
-            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]     # (slots, H, D)
-            ck = ck.at[i, sidx, :, wpos, :].set(k)
-            cv = cv.at[i, sidx, :, wpos, :].set(v)
-            s = jnp.einsum("shd,shtd->sht", q, ck[i]) * scale
-            s = jnp.where(tmask, s, neg)
-            w = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("sht,shtd->shd", w, cv[i]).reshape(nslots, embed)
-            o = o @ params[pre + "_attn_out_weight"].T \
-                + params[pre + "_attn_out_bias"]
-            x = edge(x + o)
-            f = _ln(x, params[pre + "_ln2_gamma"], params[pre + "_ln2_beta"])
-            f = jnp.maximum(
-                f @ params[pre + "_ffn_fc1_weight"].T
-                + params[pre + "_ffn_fc1_bias"], jnp.float32(0.0))
-            f = f @ params[pre + "_ffn_fc2_weight"].T \
-                + params[pre + "_ffn_fc2_bias"]
-            x = edge(x + f)
-        x = _ln(x, params["final_ln_gamma"], params["final_ln_beta"])
-        logits = x @ params["lm_head_weight"].T + params["lm_head_bias"]
+            with jax.named_scope("layer/attn"):
+                a = _ln(x, params[pre + "_ln1_gamma"],
+                        params[pre + "_ln1_beta"])
+                qkv = a @ params[pre + "_attn_qkv_weight"].T \
+                    + params[pre + "_attn_qkv_bias"]
+                qkv = qkv.reshape(nslots, 3, num_heads, d)
+                q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]     # (slots, H, D)
+            with jax.named_scope("cache_write"):
+                ck = ck.at[i, sidx, :, wpos, :].set(k)
+                cv = cv.at[i, sidx, :, wpos, :].set(v)
+            with jax.named_scope("layer/attn"):
+                s = jnp.einsum("shd,shtd->sht", q, ck[i]) * scale
+                s = jnp.where(tmask, s, neg)
+                w = jax.nn.softmax(s, axis=-1)
+                o = jnp.einsum("sht,shtd->shd", w, cv[i]).reshape(nslots,
+                                                                  embed)
+                o = o @ params[pre + "_attn_out_weight"].T \
+                    + params[pre + "_attn_out_bias"]
+                x = edge(x + o)
+            with jax.named_scope("layer/mlp"):
+                f = _ln(x, params[pre + "_ln2_gamma"],
+                        params[pre + "_ln2_beta"])
+                f = jnp.maximum(
+                    f @ params[pre + "_ffn_fc1_weight"].T
+                    + params[pre + "_ffn_fc1_bias"], jnp.float32(0.0))
+                f = f @ params[pre + "_ffn_fc2_weight"].T \
+                    + params[pre + "_ffn_fc2_bias"]
+                x = edge(x + f)
+        with jax.named_scope("head"):
+            x = _ln(x, params["final_ln_gamma"], params["final_ln_beta"])
+            logits = x @ params["lm_head_weight"].T + params["lm_head_bias"]
         return ck, cv, logits
 
     return token_pass
@@ -180,12 +191,14 @@ def _build_decode_fn(num_layers, num_heads, mesh=None):
 
     def decode_fn(state, params, tokens, pos, temp, top_k, top_p,
                   fresh_seed, reseed):
+        import jax
         import jax.numpy as jnp
         seeds = jnp.where(reseed, fresh_seed, state["seed"])
         p = dequant_tree(params)
         ck, cv, logits = token_pass(state["k"], state["v"], p, tokens, pos)
-        u = position_uniforms(seeds, pos)
-        nxt = sample_rows(logits, u, temp, top_k, top_p)
+        with jax.named_scope("sample"):
+            u = position_uniforms(seeds, pos)
+            nxt = sample_rows(logits, u, temp, top_k, top_p)
         return {"k": ck, "v": cv, "seed": seeds}, nxt
 
     return decode_fn
@@ -201,6 +214,7 @@ def _build_verify_fn(num_layers, num_heads, window, mesh=None):
 
     def verify_fn(state, params, tokens_w, pos0, temp, top_k, top_p,
                   fresh_seed, reseed):
+        import jax
         import jax.numpy as jnp
         seeds = jnp.where(reseed, fresh_seed, state["seed"])
         p = dequant_tree(params)
@@ -209,8 +223,9 @@ def _build_verify_fn(num_layers, num_heads, window, mesh=None):
         for j in range(window):
             pos_j = pos0 + jnp.int32(j)
             ck, cv, logits = token_pass(ck, cv, p, tokens_w[:, j], pos_j)
-            u = position_uniforms(seeds, pos_j)
-            outs.append(sample_rows(logits, u, temp, top_k, top_p))
+            with jax.named_scope("sample"):
+                u = position_uniforms(seeds, pos_j)
+                outs.append(sample_rows(logits, u, temp, top_k, top_p))
         return ({"k": ck, "v": cv, "seed": seeds},
                 jnp.stack(outs, axis=1))
 
@@ -255,7 +270,7 @@ class GenerateFuture(Settleable):
     so open-loop clients can drive ``generate`` exactly like ``infer``."""
 
     __slots__ = ("prompt", "max_new", "_loop", "rid", "temperature",
-                 "top_k", "top_p", "seed", "prefix_len")
+                 "top_k", "top_p", "seed", "prefix_len", "token_times")
 
     def __init__(self, loop, prompt, max_new, temperature=0.0, top_k=0,
                  top_p=1.0, seed=None, prefix_len=0, on_done=None):
@@ -275,6 +290,12 @@ class GenerateFuture(Settleable):
         #: requests); pass ``seed=`` for replayable sampling
         self.seed = int(self.rid if seed is None else seed) & 0x7FFFFFFF
         self.prefix_len = int(prefix_len)
+        #: ``time.perf_counter()`` of the step that emitted each token, in
+        #: order (one clock read a step; the tokens of one speculative
+        #: round share theirs). ``token_times[0]`` less the time of
+        #: submission is the time to first token, the differences are the
+        #: token gaps; the loop thread appends, so read it once done
+        self.token_times = []
 
     @property
     def tokens(self):
@@ -518,6 +539,8 @@ class DecodeLoop(object):
         self._closed = False
         self.dead = None
         self._steps = 0   # decode-step ordinal for the host trace
+        self._cpu_ns = None   # loop thread's CPU clock at the last traced
+        #                       step's end (None: the next has no cpu_us)
         self._wake = threading.Event()
         self._thread = threading.Thread(target=self._run,
                                         name="mxtpu-serve-decode",
@@ -860,13 +883,16 @@ class DecodeLoop(object):
             self.health.record_shed(shed, exc)
 
     def _admit(self):
+        """Seat queued requests in free slots; returns how many joined."""
+        joined = 0
         for i in range(self.slots):
             if self._slots[i] is not None:
                 continue
             try:
                 fut = self._join_q.get_nowait()
             except queue.Empty:
-                return
+                break
+            joined += 1
             slot = _Slot(fut)
             self._slots[i] = slot
             if self.prefix_enabled and fut.prefix_len > 0:
@@ -885,6 +911,7 @@ class DecodeLoop(object):
                     slot.producing = (key, fut.prefix_len)
             _obs.instant("decode_join", req=fut.rid, slot=i)
             self.health.record_join()
+        return joined
 
     def _implant_slot(self, i, entry):
         s = self._dev_scalar(i)
@@ -923,7 +950,12 @@ class DecodeLoop(object):
         from .. import faults as _faults
         try:
             while not self._closed:
-                self._admit()
+                t_admit = time.perf_counter()
+                joined = self._admit()
+                if joined:      # a span only where it seated someone
+                    _obs.complete("decode_admit",
+                                  time.perf_counter() - t_admit,
+                                  step=self._steps + 1, joined=joined)
                 if all(s is None for s in self._slots):
                     self._wake.wait(timeout=0.05)
                     self._wake.clear()
@@ -946,13 +978,29 @@ class DecodeLoop(object):
 
     def _step(self):
         self._steps += 1
+        body = self._step_spec if self.spec_k else self._step_inner
+        if not _obs.active():
+            self._cpu_ns = None
+            body(_obs.NOOP)     # no span is live: nothing is built for one
+            return
+        occ = [s for s in self._slots if s is not None]
         with _obs.span("decode_step", step=self._steps,
-                       reqs=[s.fut.rid for s in self._slots
-                             if s is not None]):
-            if self.spec_k:
-                self._step_spec()
-            else:
-                self._step_inner()
+                       reqs=[s.fut.rid for s in occ]) as sp:
+            pos = [s.pos for s in occ]
+            had = [len(s.emitted) for s in occ]
+            body(sp)
+            # a retired slot keeps its last pos and emitted: aligned with
+            # reqs, where each request started, the positions it committed
+            # and the tokens it was handed in this step
+            sp.set(pos=pos, n=[s.pos - p for s, p in zip(occ, pos)],
+                   emit=[len(s.emitted) - h for s, h in zip(occ, had)])
+            # the thread's CPU clock is a system call (20-40 us each in a
+            # process with JAX's threads; chip run, PR 27): read once a
+            # step, and for the trace file only, not for the recorder
+            cpu = time.thread_time_ns() if _obs.enabled() else None
+            if cpu is not None and self._cpu_ns is not None:
+                sp.set(cpu_us=(cpu - self._cpu_ns) // 1000)
+            self._cpu_ns = cpu
 
     def _gather_sampling(self):
         """Host-side per-slot dispatch arrays (and consume reseed marks)."""
@@ -978,17 +1026,30 @@ class DecodeLoop(object):
                 slot.reseed = False
         return arrs
 
-    def _step_inner(self):
+    def _step_inner(self, sp):
+        """One step. ``sp`` is the step's span (or the no-op): each phase
+        of the host round trip ends in a lap of it (docs/observability.md
+        "Span catalogue"): ``decode_gather``, ``decode_h2d``,
+        ``decode_dispatch``, ``decode_readback``, ``decode_commit``."""
         from .. import faults as _faults
         a = self._gather_sampling()
+        sp.lap("decode_gather")
         _faults.fire("serve.sample")
-        new_state, toks = self._step_c(
-            self._state, self._params,
-            *self._dev([a["tokens"], a["pos"], a["temp"], a["top_k"],
-                        a["top_p"], a["fresh"], a["reseed"]]))
-        self._state = new_state
+        dev = self._dev([a["tokens"], a["pos"], a["temp"], a["top_k"],
+                         a["top_p"], a["fresh"], a["reseed"]])
+        sp.lap("decode_h2d")
+        self._state, toks = self._step_c(self._state, self._params, *dev)
+        # the argument buffers are released here, while the device works,
+        # and the token buffer with the readback: left to this function's
+        # end, eight releases fall into the device's idle time after the
+        # commit, in no phase (chip run, PR 27)
+        del dev
+        sp.lap("decode_dispatch")
         host_toks = np.asarray(toks)   # the one per-step readback
-        self.health.record_decode_step()
+        del toks
+        sp.lap("decode_readback")
+        now = time.perf_counter()
+        emitted = prompt = 0
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
@@ -996,9 +1057,12 @@ class DecodeLoop(object):
             if slot.pending:
                 # prompt still feeding: next input is teacher-forced
                 slot.next_token = slot.pending.pop(0)
+                prompt += 1
             else:
                 tok = int(host_toks[i])
                 slot.emitted.append(tok)
+                slot.fut.token_times.append(now)
+                emitted += 1
                 slot.next_token = tok
                 if (len(slot.emitted) >= slot.fut.max_new
                         or (self.eos_id is not None and tok == self.eos_id)):
@@ -1008,8 +1072,10 @@ class DecodeLoop(object):
                 self._retire(i)
                 continue
             self._maybe_harvest(i)
+        self.health.record_decode_step(emitted, prompt)
+        sp.lap("decode_commit")
 
-    def _step_spec(self):
+    def _step_spec(self, sp):
         """One draft-K-then-verify round: K+1 cheap draft passes chain
         the proposals (teacher-forced wherever the prompt already knows
         the token, so the draft cache stays position-synced), then ONE
@@ -1019,6 +1085,7 @@ class DecodeLoop(object):
         inputs (docs/serving.md "Speculative decoding")."""
         from .. import faults as _faults
         window = self.spec_k + 1
+        draft, verify = {"pass": "draft"}, {"pass": "verify"}
         a = self._gather_sampling()
         w = np.zeros((self.slots, window), np.int32)
         w[:, 0] = a["tokens"]
@@ -1028,16 +1095,19 @@ class DecodeLoop(object):
         _faults.fire("serve.sample")
         no_reseed = np.zeros(self.slots, np.bool_)
         for j in range(window):
-            d_state, d_toks = self._draft_c(
-                self._draft_state, self._draft_params,
-                *self._dev([w[:, j].copy(),
-                            (a["pos"] + j).astype(np.int32), a["temp"],
-                            a["top_k"], a["top_p"], a["fresh"],
-                            a["reseed"] if j == 0 else no_reseed]))
-            self._draft_state = d_state
+            sp.lap("decode_gather")
+            dev = self._dev([w[:, j].copy(),
+                             (a["pos"] + j).astype(np.int32), a["temp"],
+                             a["top_k"], a["top_p"], a["fresh"],
+                             a["reseed"] if j == 0 else no_reseed])
+            sp.lap("decode_h2d")
+            self._draft_state, d_toks = self._draft_c(
+                self._draft_state, self._draft_params, *dev)
+            sp.lap("decode_dispatch", **draft)
             if j + 1 >= window:
                 break
             d_host = np.asarray(d_toks)
+            sp.lap("decode_readback", **draft)
             for i, slot in enumerate(self._slots):
                 if slot is None:
                     continue
@@ -1047,14 +1117,18 @@ class DecodeLoop(object):
                     w[i, j + 1] = d_host[i]       # draft proposal
                     dfill[i, j + 1] = True
         _faults.fire("serve.spec_verify")
-        new_state, samples = self._verify_c(
-            self._state, self._params,
-            *self._dev([w, a["pos"], a["temp"], a["top_k"], a["top_p"],
-                        a["fresh"], a["reseed"]]))
-        self._state = new_state
+        dev = self._dev([w, a["pos"], a["temp"], a["top_k"], a["top_p"],
+                         a["fresh"], a["reseed"]])
+        sp.lap("decode_h2d")
+        self._state, samples = self._verify_c(self._state, self._params,
+                                              *dev)
+        del dev, d_toks     # as in _step_inner: released inside a phase
+        sp.lap("decode_dispatch", **verify)
         s = np.asarray(samples)        # (slots, window) int32
-        self.health.record_decode_step()
-        accepted = judged = 0
+        del samples
+        sp.lap("decode_readback", **verify)
+        now = time.perf_counter()
+        accepted = judged = emitted = prompt = 0
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
@@ -1062,9 +1136,12 @@ class DecodeLoop(object):
                 slot.pos += 1
                 if slot.pending:
                     nxt = slot.pending.pop(0)
+                    prompt += 1
                 else:
                     tok = int(s[i, j])
                     slot.emitted.append(tok)
+                    slot.fut.token_times.append(now)
+                    emitted += 1
                     nxt = tok
                     if (len(slot.emitted) >= slot.fut.max_new
                             or (self.eos_id is not None
@@ -1094,6 +1171,8 @@ class DecodeLoop(object):
         # retire/length break left unverified would deflate the acceptance
         # rate a perfect draft earns (drafted == accepted by construction)
         self.health.record_spec_round(judged, accepted)
+        self.health.record_decode_step(emitted, prompt)
+        sp.lap("decode_commit")
 
     def _retire(self, i):
         slot = self._slots[i]

@@ -27,6 +27,9 @@ class ServingHealth(object):
         self.shed = 0              # in-flight requests failed by a dying loop
         self.errors = 0            # dispatch errors propagated to callers
         self.decode_steps = 0      # continuous-batching decode iterations
+        self.tokens_emitted = 0    # tokens decode steps handed to requests
+        self.prompt_positions = 0  # cache positions committed that emitted
+        #                            none (a prompt being fed)
         self.joined = 0            # sequences that entered a decode slot
         self.retired = 0           # sequences that left a decode slot
         self.requeued = 0          # requests moved off a dead/draining
@@ -70,8 +73,16 @@ class ServingHealth(object):
     def record_error(self, err=None):
         self._bump("errors", err=err)
 
-    def record_decode_step(self):
-        self._bump("decode_steps")
+    def record_decode_step(self, emitted=0, prompt=0):
+        """One decode step (or speculative round) that handed ``emitted``
+        tokens to its requests and processed ``prompt`` positions that
+        emitted none: the three counts move under one lock."""
+        with self._lock:
+            self.decode_steps += 1
+            self.tokens_emitted += int(emitted)
+            self.prompt_positions += int(prompt)
+        if self._parent is not None:
+            self._parent.record_decode_step(emitted, prompt)
 
     def record_join(self):
         self._bump("joined")
@@ -103,7 +114,10 @@ class ServingHealth(object):
                 "examples": self.examples, "padded": self.padded,
                 "expired": self.expired, "dropped": self.dropped,
                 "shed": self.shed, "errors": self.errors,
-                "decode_steps": self.decode_steps, "joined": self.joined,
+                "decode_steps": self.decode_steps,
+                "tokens_emitted": self.tokens_emitted,
+                "prompt_positions": self.prompt_positions,
+                "joined": self.joined,
                 "retired": self.retired, "requeued": self.requeued,
                 "prefix_hits": self.prefix_hits,
                 "prefix_prefills": self.prefix_prefills,
@@ -118,6 +132,7 @@ class ServingHealth(object):
             self.requests = self.batches = self.examples = 0
             self.padded = self.expired = self.dropped = 0
             self.shed = self.errors = self.decode_steps = 0
+            self.tokens_emitted = self.prompt_positions = 0
             self.joined = self.retired = self.requeued = 0
             self.prefix_hits = self.prefix_prefills = 0
             self.spec_rounds = self.spec_drafted = self.spec_accepted = 0

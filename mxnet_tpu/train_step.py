@@ -643,11 +643,23 @@ class TrainStep(object):
                 outs, aux_up = run(arg_vals, aux, key, True)
                 return outs, aux_up
 
-            (outs, aux_up), vjp_fn = jax.vjp(f, params)
-            cots = [jnp.ones_like(o) for o in outs]
-            cots_aux = jax.tree_util.tree_map(jnp.zeros_like, aux_up)
-            (grads,) = vjp_fn((cots, cots_aux))
+            # forward / backward / update: the names an operator searches
+            # the device trace of the step (and of the K-step scan) for
+            with jax.named_scope("forward"):
+                (outs, aux_up), vjp_fn = jax.vjp(f, params)
+            with jax.named_scope("backward"):
+                cots = [jnp.ones_like(o) for o in outs]
+                cots_aux = jax.tree_util.tree_map(jnp.zeros_like, aux_up)
+                (grads,) = vjp_fn((cots, cots_aux))
+            with jax.named_scope("update"):
+                new_state, ok, gnorm = update(state, grads, outs, aux_up,
+                                              key, lr_base, poison)
+            if guard:
+                return new_state, outs, (ok, gnorm)
+            return new_state, outs
 
+        def update(state, grads, outs, aux_up, key, lr_base, poison):
+            params, aux, opt = state["params"], state["aux"], state["opt"]
             t = state["step"].astype(jnp.float32) + jnp.float32(1.0)
             gs = {n: grads[n].astype(params[n].dtype) * rescale
                   for n in updated}
@@ -717,10 +729,7 @@ class TrainStep(object):
             step_inc = ok.astype(jnp.int32) if guard else 1
             new_state = {"params": new_params, "aux": new_aux,
                          "opt": new_opt, "step": state["step"] + step_inc}
-            new_state = self._pin_state_sharding(new_state)
-            if guard:
-                return new_state, outs, (ok, gnorm)
-            return new_state, outs
+            return self._pin_state_sharding(new_state), ok, gnorm
 
         return step_fn
 
