@@ -295,11 +295,36 @@ def lm_params(vocab, embed, heads, layers, max_len, seed=0):
             if n not in ("data", "softmax_label")}
 
 
+def cache_relayouts(compiled, name, cache_bytes):
+    """What ``flopcheck`` finds in the step program's own executable
+    against a cache that must pass through a step untouched but for one
+    row a slot: its ``layout-copy`` findings, and every kernel of its
+    inventory that only moves data (copy, transpose, a fusion of nothing
+    else) and moves as much as one cache holds. The lint alone is not
+    enough: it fires on a kernel's SHARE of the program's traffic (25%),
+    and each of the four cache copies the v5e trace showed before PR 28
+    was 3% of this program's (PERF.md, PR 28)."""
+    from mxnet_tpu import flopcheck
+    rep = flopcheck.analyze_compiled(compiled, name)
+    found = [f.format() for f in flopcheck.lint_report(rep)
+             if f.lint == "layout-copy" and not f.suppressed]
+    moved = [k for k in rep.kernels if k.is_layout]
+    found += ["%s %s moves %d bytes, the cache holds %d"
+              % (k.opcode, k.instruction, k.bytes * k.multiplier, cache_bytes)
+              for k in moved if k.bytes * k.multiplier >= cache_bytes]
+    facts = {"kernels": len(rep.kernels), "layout_kernels": len(moved),
+             "layout_bytes_max": max(
+                 [k.bytes * k.multiplier for k in moved] or [0]),
+             "cache_bytes": cache_bytes}
+    return found, facts
+
+
 def decode_leg(meter, context, layers, embed, heads, vocab,
                max_len, slots=8, requests=12, prompt_range=(32, 128),
                max_new=32, margin=DECODE_MARGIN):
     """``DecodeLoop`` with more requests than slots (join/leave), checked
-    against a full forward of the plain symbol over every prompt."""
+    against a full forward of the plain symbol over every prompt; its
+    compiled step program must not re-lay the KV cache out."""
     import mxnet_tpu as mx
     from mxnet_tpu import models, serving
 
@@ -312,10 +337,16 @@ def decode_leg(meter, context, layers, embed, heads, vocab,
 
     loop = serving.DecodeLoop(params, layers, heads, max_len, slots=slots)
     try:
+        relaid, step_facts = cache_relayouts(
+            loop._step_c, loop.name + "/step",
+            int(loop._state["k"].nbytes))
         futures = [loop.generate(p, max_new) for p in prompts]
         outs = [f.result(timeout=900.0) for f in futures]
     finally:
         loop.close()
+    if relaid:
+        raise AssertionError("the step program re-lays the KV cache out: "
+                             "%s" % "; ".join(relaid))
     health = loop.health.report()
     if not (health["joined"] == health["retired"] == requests):
         raise AssertionError("join/retire mismatch: %r" % (health,))
@@ -368,7 +399,7 @@ def decode_leg(meter, context, layers, embed, heads, vocab,
              "decode_steps": health["decode_steps"],
              "first_token_checked": asserted,
              "first_token_agrees": int(agree), "margins": margins,
-             "margin_tolerance": margin,
+             "margin_tolerance": margin, "step_program": step_facts,
              "loop_devices": [str(d) for d in loop.devices]}
     facts = _report("decode", facts, meter, snap)
     return facts

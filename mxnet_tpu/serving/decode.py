@@ -96,7 +96,7 @@ def _ln(x, gamma, beta):
 
 def _build_token_pass(num_layers, num_heads, mesh=None):
     """ONE position per slot through every layer, reading and writing the
-    (layers, slots, heads, rows, head_dim) KV cache. Matches
+    (layers, slots, rows, heads * head_dim) KV cache. Matches
     models/transformer.py op-for-op (pre-LN blocks, qkv packing, 1/sqrt(d)
     scaling) so greedy decode agrees with the full forward.
 
@@ -105,18 +105,36 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
     a position computes the IDENTICAL op sequence through either, which is
     what makes speculative output token-identical to target-only decode.
 
-    The write/embed position is clamped to the last cache row: a
-    speculative cache carries one extra TRASH row (``rows = max_len + 1``)
-    that window positions past ``max_len`` land in and no valid query ever
-    attends (the causal mask covers rows ``<= pos`` and live positions are
-    ``< max_len``); on a plain ``rows = max_len`` cache the clamp is an
-    index identity, preserving the pre-sampling program bit-for-bit.
+    THE HEADS ARE FOLDED INTO THE MINOR DIMENSION because the chip tiles
+    an array's two minor dimensions into (8 sublanes, 128 lanes): ``heads
+    * head_dim`` (the model's width, a multiple of 128) fills the lanes
+    and ``rows`` the sublanes, so the donated buffer holds no padding and
+    the step program computes in the layout the runtime stores. With
+    ``head_dim`` = 64 alone in the minor dimension the compiler re-laid
+    both caches out on the way into and out of every step: four
+    cache-sized copies, 56% of the step (PERF.md, PR 28). A position's
+    write is one contiguous row per slot. The minor dimension is never
+    reshaped into (heads, head_dim), which would bring the padding back:
+    the per-head contractions are a float32 multiply over all lanes and a
+    sum of each head's lanes (``heads_sum``), and the mix spreads a head's
+    weight over its lanes (``heads_spread``) before a float32 multiply and
+    a sum over rows. Both go through a 0/1 matrix at ``HIGHEST``
+    precision, where a product with 1 is exact: the same float32 products
+    and sums as ever, with no bfloat16 rounding anywhere.
+
+    The write/embed position is clamped to the last cache row. Rows past
+    ``max_len`` are TRASH rows: a speculative window's positions past
+    ``max_len`` land there and no valid query ever attends them (the
+    causal mask covers rows ``<= pos`` and live positions are
+    ``< max_len``); for live positions the clamp is an index identity.
 
     With a model ``mesh`` the residual stream is pinned REPLICATED at
     every block boundary while the KV cache and the attention math stay
-    sharded over heads — per-head contractions never cross shards, so the
-    sharded loop emits the same tokens as the single-chip one
-    (docs/serving.md "Model-parallel replicas")."""
+    sharded over heads: the lanes split into one GROUP of whole heads per
+    shard and ``heads_sum``/``heads_spread`` work group by group, so
+    per-head contractions never cross shards and the sharded loop emits
+    the same tokens as the single-chip one (docs/serving.md
+    "Model-parallel replicas")."""
     import jax.numpy as jnp
     import jax
 
@@ -130,9 +148,12 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
         def edge(x):
             return x
 
+    groups = 1 if mesh is None else int(mesh.devices.size)
+    highest = jax.lax.Precision.HIGHEST
+
     def token_pass(ck, cv, params, tokens, pos):
         nslots = tokens.shape[0]
-        rows = ck.shape[3]
+        rows = ck.shape[2]
         wpos = jnp.minimum(pos, jnp.int32(rows - 1))
         with jax.named_scope("embed"):
             x = edge(params["tok_embed_weight"][tokens]
@@ -141,8 +162,23 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
         d = embed // num_heads
         scale = jnp.float32(1.0 / float(np.sqrt(d)))
         sidx = jnp.arange(nslots)
-        tmask = jnp.arange(rows)[None, None, :] <= pos[:, None, None]
+        tmask = (jnp.arange(rows)[None, :] <= pos[:, None])[:, :, None]
         neg = jnp.float32(-1e30)
+        # lane e of a group belongs to the group's head e // d
+        lanes, gheads = embed // groups, num_heads // groups
+        seg = (jnp.arange(lanes)[:, None] // d
+               == jnp.arange(gheads)[None, :]).astype(jnp.float32)
+
+        def heads_sum(p):       # (slots, rows, embed) -> (slots, rows, heads)
+            p = p.reshape(nslots, rows, groups, lanes)
+            return jnp.einsum("stge,eh->stgh", p, seg, precision=highest
+                              ).reshape(nslots, rows, num_heads)
+
+        def heads_spread(w):    # (slots, rows, heads) -> (slots, rows, embed)
+            w = w.reshape(nslots, rows, groups, gheads)
+            return jnp.einsum("stgh,eh->stge", w, seg, precision=highest
+                              ).reshape(nslots, rows, embed)
+
         # the scope names are what an operator searches a device trace
         # for: the same in every layer, so they sum by kind
         for i in range(num_layers):
@@ -152,17 +188,16 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
                         params[pre + "_ln1_beta"])
                 qkv = a @ params[pre + "_attn_qkv_weight"].T \
                     + params[pre + "_attn_qkv_bias"]
-                qkv = qkv.reshape(nslots, 3, num_heads, d)
-                q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]     # (slots, H, D)
+                qkv = qkv.reshape(nslots, 3, embed)
+                q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]   # (slots, H * D)
             with jax.named_scope("cache_write"):
-                ck = ck.at[i, sidx, :, wpos, :].set(k)
-                cv = cv.at[i, sidx, :, wpos, :].set(v)
+                ck = ck.at[i, sidx, wpos].set(k)
+                cv = cv.at[i, sidx, wpos].set(v)
             with jax.named_scope("layer/attn"):
-                s = jnp.einsum("shd,shtd->sht", q, ck[i]) * scale
+                s = heads_sum(q[:, None, :] * ck[i]) * scale
                 s = jnp.where(tmask, s, neg)
-                w = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("sht,shtd->shd", w, cv[i]).reshape(nslots,
-                                                                  embed)
+                w = jax.nn.softmax(s, axis=1)
+                o = jnp.sum(heads_spread(w) * cv[i], axis=1)
                 o = o @ params[pre + "_attn_out_weight"].T \
                     + params[pre + "_attn_out_bias"]
                 x = edge(x + o)
@@ -233,7 +268,8 @@ def _build_verify_fn(num_layers, num_heads, window, mesh=None):
 
 
 def _build_extract_fn(mesh=None):
-    """Prefix harvest: copy one slot's full KV slab out of the cache
+    """Prefix harvest: copy one slot's full KV slab, (layers, rows, heads *
+    head_dim) like the cache less its slot axis, out of the cache
     (non-donating — the cache keeps serving). Garbage rows past the
     prefix length ride along; every consumer rewrites them before any
     query can attend them."""
@@ -244,7 +280,7 @@ def _build_extract_fn(mesh=None):
             import jax
             from ..parallel.mesh import AXIS_MODEL
             sh = jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec(None, AXIS_MODEL))
+                mesh, jax.sharding.PartitionSpec(None, None, AXIS_MODEL))
             pk = jax.lax.with_sharding_constraint(pk, sh)
             pv = jax.lax.with_sharding_constraint(pv, sh)
         return {"k": pk, "v": pv}
@@ -371,7 +407,8 @@ class DecodeLoop(object):
         self.eos_id = eos_id
         self.health = health or ServingHealth(parent=SERVING_HEALTH)
         #: model-axis mesh when the loop spans more than one chip: the KV
-        #: cache (the dominant buffer) shards over HEADS, params shard per
+        #: cache (the dominant buffer) shards over HEADS (its minor
+        #: dimension, a group of whole heads per chip), params shard per
         #: the placement rule, the residual stream stays replicated at
         #: block edges (docs/serving.md "Model-parallel replicas")
         self._mesh = _model_mesh(contexts, who="DecodeLoop")
@@ -406,7 +443,6 @@ class DecodeLoop(object):
                 "table (%d rows) — positions past it would be silently "
                 "clamped" % (self.max_len, pos_rows))
         self.vocab_size = int(vocab)
-        head_dim = embed // self.num_heads
 
         self._resolve_knobs(host_params, quantize, prefix_cache, spec_k,
                             draft_params)
@@ -463,16 +499,16 @@ class DecodeLoop(object):
 
         # --- device state: KV cache(s) + per-slot seeds ---------------
         # speculative windows run past a retiring sequence's last row;
-        # one extra TRASH row absorbs those writes (see _build_token_pass)
-        self._rows = self.max_len + (1 if self.spec_k else 0)
-        self._state = self._init_state(self.num_layers, self.num_heads,
-                                       head_dim)
+        # one extra TRASH row absorbs those writes (see _build_token_pass).
+        # Rows lie on the chip's 8 sublanes: allocated in whole eights, so
+        # the chip pads nothing; the surplus rows are trash rows too
+        self._rows = -(-(self.max_len + (1 if self.spec_k else 0)) // 8) * 8
+        self._state = self._init_state(self.num_layers, int(embed))
         self._draft_state = None
         if self.spec_k:
             self._draft_state = self._init_state(
-                self.draft_num_layers, self.draft_num_heads,
-                int(dhost["tok_embed_weight"].shape[1])
-                // self.draft_num_heads)
+                self.draft_num_layers,
+                int(dhost["tok_embed_weight"].shape[1]))
 
         # --- AOT-compile + register every program ---------------------
         self.name = _tc.unique_name(name or "serving-decode")
@@ -634,10 +670,17 @@ class DecodeLoop(object):
                              prefer_first=True)
         return put(leaf, spec)
 
-    def _init_state(self, layers, heads, head_dim):
+    def _init_state(self, layers, width):
+        """The donated device state: K and V caches of shape (layers,
+        slots, rows, heads * head_dim) float32, and the slots' seeds. The
+        model's ``width`` is the minor dimension (128 lanes at a time) and
+        rows the second minor (a multiple of the 8 sublanes): the chip's
+        tiles hold no padding and the step program computes in this
+        layout as stored (see :func:`_build_token_pass`). A model mesh
+        shards the minor dimension, a group of whole heads per chip."""
         import jax
         import jax.numpy as jnp
-        cache_shape = (layers, self.slots, heads, self._rows, head_dim)
+        cache_shape = (layers, self.slots, self._rows, width)
         state = {"k": jnp.zeros(cache_shape, np.float32),
                  "v": jnp.zeros(cache_shape, np.float32),
                  "seed": jnp.zeros((self.slots,), np.uint32)}
@@ -645,7 +688,7 @@ class DecodeLoop(object):
             from ..parallel.mesh import AXIS_MODEL
             cache_sh = jax.sharding.NamedSharding(
                 self._mesh,
-                jax.sharding.PartitionSpec(None, None, AXIS_MODEL))
+                jax.sharding.PartitionSpec(None, None, None, AXIS_MODEL))
             repl = jax.sharding.NamedSharding(
                 self._mesh, jax.sharding.PartitionSpec())
             state = {"k": jax.device_put(state["k"], cache_sh),
@@ -659,7 +702,8 @@ class DecodeLoop(object):
         if self._mesh is not None:
             from ..parallel.mesh import AXIS_MODEL
             sh = jax.sharding.NamedSharding(
-                self._mesh, jax.sharding.PartitionSpec(None, AXIS_MODEL))
+                self._mesh,
+                jax.sharding.PartitionSpec(None, None, AXIS_MODEL))
             slab_s = jax.ShapeDtypeStruct(slab_shape, np.float32,
                                           sharding=sh)
         else:

@@ -64,6 +64,38 @@ def test_decode_leg_tiny(meter):
         margin=0.01)
     assert facts["first_token_checked"] >= 1
     assert facts["decode_steps"] > 0
+    # (layers, slots, rows, heads * head_dim) float32, no cache-sized copy
+    assert facts["step_program"]["cache_bytes"] == 2 * 2 * 48 * 32 * 4
+    assert facts["step_program"]["layout_bytes_max"] < 2 * 2 * 48 * 32 * 4
+
+
+@pytest.mark.parametrize("relaid", [True, False])
+def test_cache_relayouts_sees_the_copy_the_lint_lets_through(relaid):
+    """The decode leg's check on the step program: a kernel that only
+    moves data and moves a whole cache is found in flopcheck's inventory
+    even where its share of the program's traffic (6% here, 3% for each
+    of the four copies on the v5e before PR 28) is under the
+    ``layout-copy`` lint's 25%; a program that updates the cache in place
+    is clean."""
+    import jax.numpy as jnp
+    cache = jax.ShapeDtypeStruct((64, 256, 128), np.float32)
+    weights = jax.ShapeDtypeStruct((16, 64, 256, 128), np.float32)
+
+    def step(c, w):
+        c = jnp.transpose(c, (0, 2, 1)) if relaid else c.at[3, 5].set(1.0)
+        return c, (w * 2.0).sum()
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        cache, weights).compile()
+    found, facts = chip_smoke.cache_relayouts(compiled, "step", 64 * 256
+                                              * 128 * 4)
+    if relaid:
+        assert found and all("moves 8388608 bytes" in f for f in found)
+        assert not any("layout-copy" in f for f in found)   # lint silent
+        assert facts["layout_bytes_max"] == 8388608
+    else:
+        assert found == [] and facts["layout_bytes_max"] < 8388608
+    assert facts["cache_bytes"] == 8388608 and facts["kernels"] >= 2
 
 
 def test_smoke_script_refuses_cpu():

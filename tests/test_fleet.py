@@ -576,7 +576,9 @@ def test_sharded_decode_greedy_token_parity():
         assert t1 == t2
         shard_shapes = {tuple(s.data.shape)
                         for s in l2._state["k"].addressable_shards}
-        assert shard_shapes == {(2, 2, 2, 24, 4)}   # heads 4 -> 2 per dev
+        # (layers, slots, rows, heads * head_dim): 4 heads of 4 lanes, a
+        # group of 2 heads per device
+        assert shard_shapes == {(2, 2, 24, 8)}
         bad = [f for f in l2.check(memory=True, comms=True)
                if not f.suppressed]
         assert bad == [], [f.format() for f in bad]
