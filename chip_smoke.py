@@ -14,6 +14,11 @@ from a seed, and checks what comes out:
   ``models.transformer`` with more requests than slots, against a full
   forward of the plain symbol.
 
+* latent — ``serving.DecodeLoop(arch=DeepseekV3Arch)`` at Kimi-K2's
+  published widths and depth 2 (the dense layer and one expert layer that
+  holds 12 of 384 experts), bfloat16: the step program's inventory against
+  the latent cache, and the routing counters against the steps.
+
 On a host with four chips the train leg also runs data-parallel over all of
 them. Times are printed as set-up facts; this script measures no rate. It
 refuses to run without a TPU (no CPU stand-in), catches no leg's exception,
@@ -52,6 +57,22 @@ DEPLOY_ATOL = 1e-5
 #: the v5e: all 12 first tokens agree, down to a margin of 0.033, and 9 of
 #: the 12 prompts clear 0.1 (chip run, PR 21)
 DECODE_MARGIN = 0.1
+#: Kimi-K2-Instruct's published widths (``config.json``), cut in depth, in
+#: experts held and in vocabulary as ``benchmark/configs/kimi-k2-ep32.json``
+#: cuts them, and to depth 2 here: the dense layer and one expert layer
+KIMI_K2_DEPTH2 = {
+    "hidden_size": 7168, "num_attention_heads": 64, "q_lora_rank": 1536,
+    "kv_lora_rank": 512, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+    "v_head_dim": 128, "intermediate_size": 18432,
+    "moe_intermediate_size": 2048, "num_experts_per_tok": 8,
+    "n_shared_experts": 1, "n_routed_experts": 12, "router_width": 384,
+    "first_k_dense_replace": 1, "num_hidden_layers": 2, "vocab_size": 20480,
+    "rms_norm_eps": 1e-6, "rope_theta": 50000,
+    "routed_scaling_factor": 2.827, "scoring_func": "sigmoid",
+    "rope_scaling": {"beta_fast": 1, "beta_slow": 1, "factor": 32,
+                     "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 4096,
+                     "type": "yarn"}}
 
 
 class CompileMeter(object):
@@ -405,6 +426,73 @@ def decode_leg(meter, context, layers, embed, heads, vocab,
     return facts
 
 
+def latent_decode_leg(meter, config, max_len, slots=8, requests=12,
+                      prompt_range=(8, 24), max_new=8):
+    """``DecodeLoop`` over the DeepSeek-V3 block (latent attention, a share
+    of a routed-expert layer) in bfloat16, more requests than slots: its
+    compiled step program must not re-lay the latent cache out, and its
+    routing counters must account for every position it processed."""
+    import jax.numpy as jnp
+    from mxnet_tpu import serving
+
+    snap = meter.snapshot()
+    arch = serving.DeepseekV3Arch(config)
+    # one seeded block, repeated to every leaf's size: a smoke needs
+    # weights that are not degenerate, not 3e9 fresh normals
+    rs = np.random.RandomState(3)
+    block = rs.standard_normal(1 << 22).astype(np.float32)
+    params = {}
+    for i, (name, shape) in enumerate(sorted(arch.param_shapes().items())):
+        x = np.resize(np.roll(block, 7919 * i), shape)
+        x = 1.0 + 0.1 * x if name.endswith("_gamma") else x * (
+            1.0 if name == "tok_embed_weight" else 0.02)
+        params[name] = x.astype(jnp.bfloat16)
+    vocab = arch.vocab_size
+    lo, hi = prompt_range
+    prompts = [[int(t) for t in rs.randint(0, vocab, rs.randint(lo, hi + 1))]
+               for _ in range(requests)]
+    loop = serving.DecodeLoop(params, max_len=max_len, slots=slots, arch=arch,
+                              quantize="bf16", prefix_cache=False, spec_k=0)
+    try:
+        relaid, step_facts = cache_relayouts(
+            loop._step_c, loop.name + "/step",
+            int(loop._state["latent"].nbytes))
+        futures = [loop.generate(p, max_new) for p in prompts]
+        outs = [f.result(timeout=900.0) for f in futures]
+        health = loop.health.report()
+    finally:
+        loop.close()
+    if relaid:
+        raise AssertionError("the step program re-lays the latent cache "
+                             "out: %s" % "; ".join(relaid))
+    if not (health["joined"] == health["retired"] == requests) \
+            or health["errors"] or health["shed"] or loop.dead is not None:
+        raise AssertionError("decode loop unhealthy: %r dead=%r"
+                             % (health, loop.dead))
+    for out in outs:
+        if len(out) != max_new or not all(0 <= t < vocab for t in out):
+            raise AssertionError("bad generation: %r" % (out,))
+    positions = sum(len(p) for p in prompts) + requests * (max_new - 1)
+    routed = len(arch.moe_layers) * arch.num_experts_per_tok * positions
+    if health["moe_pairs_routed"] != routed \
+            or not 0 <= health["moe_pairs_here"] <= routed:
+        raise AssertionError("routing counters: %d routed, %d here, %d "
+                             "positions" % (health["moe_pairs_routed"],
+                                            health["moe_pairs_here"],
+                                            positions))
+    facts = {"shape": {k: config[k] for k in (
+                 "hidden_size", "num_attention_heads", "kv_lora_rank",
+                 "num_hidden_layers", "n_routed_experts", "router_width",
+                 "vocab_size")},
+             "max_len": max_len, "slots": slots, "requests": requests,
+             "weight_bytes": loop.weight_bytes(),
+             "decode_steps": health["decode_steps"],
+             "moe_pairs_routed": health["moe_pairs_routed"],
+             "moe_pairs_here": health["moe_pairs_here"],
+             "step_program": step_facts}
+    return _report("latent", facts, meter, snap)
+
+
 def main():
     import jax
     found = device_facts()
@@ -437,6 +525,7 @@ def main():
     del mod, eng
     decode_leg(meter, mx.tpu(0), layers=12, embed=768, heads=12,
                vocab=50304, max_len=1024)
+    latent_decode_leg(meter, KIMI_K2_DEPTH2, max_len=1024)
     print(json.dumps({"ok": True, "device": found}), flush=True)
 
 

@@ -10,9 +10,12 @@ Three layers over the standalone :class:`~mxnet_tpu.predictor.Predictor`:
   concurrent ``infer()`` calls into the smallest covering bucket, with
   max-latency / max-batch / deadline / back-pressure knobs
   (``MXTPU_SERVE_*``).
-* :class:`DecodeLoop` — slot-based continuous batching for the
-  transformer LM: the KV cache is donated device state stepped by one
-  compiled decode body; sequences join and leave mid-stream. The
+* :class:`DecodeLoop` — slot-based continuous batching for a language
+  model: the slots' state (K and V rows, or whatever arrays the model's
+  :class:`Architecture` names: :class:`OptArch` is the default,
+  :class:`DeepseekV3Arch` keeps latent rows and holds a share of a routed
+  expert layer) is donated device state stepped by one compiled decode
+  body; sequences join and leave mid-stream. The
   production decode path layers four separately-benchable legs on top,
   each behind a knob (docs/serving.md):
 
@@ -40,13 +43,16 @@ from .health import ServingHealth, SERVING_HEALTH
 from .engine import ServingEngine, default_buckets
 from .batcher import (Batcher, ServingError, ServingDeadlineError,
                       ServingOverloadedError, ServingClosedError)
-from .decode import DecodeLoop, GenerateFuture
+from .arch import Architecture
+from .decode import DecodeLoop, GenerateFuture, OptArch
+from .deepseek_v3 import DeepseekV3Arch
 from .fleet import FleetRouter, FleetRequest, CLASSES as FLEET_CLASSES
 from .quantize import (QUANT_MODES, check_quality, quality_report,
                        quantize_tree, tree_bytes)
 
 __all__ = [
     "ServingEngine", "Batcher", "DecodeLoop", "GenerateFuture",
+    "Architecture", "OptArch", "DeepseekV3Arch",
     "FleetRouter", "FleetRequest", "FLEET_CLASSES",
     "ServingHealth", "SERVING_HEALTH", "default_buckets",
     "ServingError", "ServingDeadlineError", "ServingOverloadedError",

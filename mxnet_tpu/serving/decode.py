@@ -79,10 +79,11 @@ import numpy as np
 
 from ..base import MXNetError, env_int, env_str
 from ..obs import trace as _obs
+from .arch import Architecture
 from .batcher import REQUEST_IDS, ServingClosedError, Settleable
 from .health import ServingHealth, SERVING_HEALTH
-from .quantize import (dequant_tree, is_quantized_leaf, quantize_array,
-                       quantize_tree, resolve_mode, tree_bytes)
+from .quantize import (is_quantized_leaf, quantize_array, quantize_tree,
+                       resolve_mode, tree_bytes)
 from .sampling import position_uniforms, sample_rows, validate_sampling
 
 
@@ -218,83 +219,150 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
     return token_pass
 
 
-def _build_decode_fn(num_layers, num_heads, mesh=None):
+class OptArch(Architecture):
+    """The default architecture: the pre-LN, ReLU, learned-position block
+    of ``models/transformer.py`` (OPT) through :func:`_build_token_pass`,
+    its slot state a K and a V array of ``heads * head_dim`` float32 a
+    row. ``who`` names the parameter set in its errors (the draft model of
+    a speculative loop is validated as ``draft_params``)."""
+
+    name = "opt"
+
+    def __init__(self, num_layers, num_heads, who="params"):
+        self.num_layers = int(num_layers)
+        self.num_heads = int(num_heads)
+        self.who = who
+
+    def validate(self, host_params, max_len, mesh, quant_mode):
+        draft = self.who != "params"
+        for need in ("tok_embed_weight", "pos_embed_weight",
+                     "final_ln_gamma", "lm_head_weight") \
+                + (() if draft else ("lm_head_bias",)):
+            if need not in host_params:
+                raise MXNetError(
+                    "DecodeLoop: %s missing %r%s" % (
+                        self.who, need, "" if draft else
+                        " — expected the models/transformer.py parameter "
+                        "naming"))
+        vocab, embed = host_params["tok_embed_weight"].shape
+        heads = "draft_num_heads" if draft else "num_heads"
+        if embed % self.num_heads:
+            raise MXNetError("DecodeLoop: %sembed %d %% %s %d != 0"
+                             % ("draft " if draft else "", embed, heads,
+                                self.num_heads))
+        if mesh is not None and self.num_heads % int(mesh.devices.size):
+            raise MXNetError(
+                "DecodeLoop: %s %d %% %d model shards != 0 — the KV cache "
+                "shards over heads" % (heads, self.num_heads,
+                                       int(mesh.devices.size)))
+        # jit-mode gather CLAMPS out-of-range indices: a position past the
+        # embedding table would silently reuse its last row (wrong tokens,
+        # zero errors) — fail loudly at construction instead
+        pos_rows = int(host_params["pos_embed_weight"].shape[0])
+        if max_len > pos_rows:
+            raise MXNetError(
+                "DecodeLoop: max_len %d exceeds the %spositional embedding "
+                "table (%d rows) — positions past it would be silently "
+                "clamped" % (max_len, "DRAFT " if draft else "", pos_rows))
+        return int(vocab)
+
+    def slot_state(self, host_params, quant_mode):
+        width = int(host_params["tok_embed_weight"].shape[1])
+        return {"k": (width, np.float32), "v": (width, np.float32)}
+
+    def slot_partition(self):
+        from ..parallel.mesh import AXIS_MODEL
+        return (None, None, None, AXIS_MODEL)
+
+    def build_token_pass(self, mesh=None):
+        inner = _build_token_pass(self.num_layers, self.num_heads, mesh=mesh)
+
+        def token_pass(state, params, tokens, pos):
+            ck, cv, logits = inner(state["k"], state["v"], params, tokens,
+                                   pos)
+            return {"k": ck, "v": cv}, logits
+
+        return token_pass
+
+
+def _model_state(state):
+    """The donated state less the slots' seeds: what the architecture's
+    token pass reads and writes."""
+    return {k: v for k, v in state.items() if k != "seed"}
+
+
+def _build_decode_fn(arch, mesh=None):
     """The single-token decode body: one position per slot, sampled
     in-graph. Returns ``(state, next_tokens)`` — the host reads back one
-    (slots,) int32 vector, never the logits."""
-    token_pass = _build_token_pass(num_layers, num_heads, mesh=mesh)
+    (slots,) int32 vector, never the logits. ``live`` is the eighth
+    per-slot array of an architecture that asks for it."""
+    token_pass = arch.build_token_pass(mesh=mesh)
 
     def decode_fn(state, params, tokens, pos, temp, top_k, top_p,
-                  fresh_seed, reseed):
+                  fresh_seed, reseed, *live):
         import jax
         import jax.numpy as jnp
         seeds = jnp.where(reseed, fresh_seed, state["seed"])
-        p = dequant_tree(params)
-        ck, cv, logits = token_pass(state["k"], state["v"], p, tokens, pos)
+        p = arch.load(params)
+        new, logits = token_pass(_model_state(state), p, tokens, pos, *live)
         with jax.named_scope("sample"):
             u = position_uniforms(seeds, pos)
             nxt = sample_rows(logits, u, temp, top_k, top_p)
-        return {"k": ck, "v": cv, "seed": seeds}, nxt
+        return dict(new, seed=seeds), nxt
 
     return decode_fn
 
 
-def _build_verify_fn(num_layers, num_heads, window, mesh=None):
+def _build_verify_fn(arch, window, mesh=None):
     """The speculative verify body: ``window`` positions per slot through
     the SAME per-position pass as the single-token body, unrolled (the
-    cache threads through, so position j attends the rows j' < j wrote),
-    each position sampled with its own (seed, position) uniform. One
-    dispatch scores and samples the whole window."""
-    token_pass = _build_token_pass(num_layers, num_heads, mesh=mesh)
+    slots' state threads through, so position j attends the rows j' < j
+    wrote), each position sampled with its own (seed, position) uniform.
+    One dispatch scores and samples the whole window."""
+    token_pass = arch.build_token_pass(mesh=mesh)
 
     def verify_fn(state, params, tokens_w, pos0, temp, top_k, top_p,
-                  fresh_seed, reseed):
+                  fresh_seed, reseed, *live):
         import jax
         import jax.numpy as jnp
         seeds = jnp.where(reseed, fresh_seed, state["seed"])
-        p = dequant_tree(params)
-        ck, cv = state["k"], state["v"]
+        p = arch.load(params)
+        model = _model_state(state)
         outs = []
         for j in range(window):
             pos_j = pos0 + jnp.int32(j)
-            ck, cv, logits = token_pass(ck, cv, p, tokens_w[:, j], pos_j)
+            model, logits = token_pass(model, p, tokens_w[:, j], pos_j,
+                                       *live)
             with jax.named_scope("sample"):
                 u = position_uniforms(seeds, pos_j)
                 outs.append(sample_rows(logits, u, temp, top_k, top_p))
-        return ({"k": ck, "v": cv, "seed": seeds},
-                jnp.stack(outs, axis=1))
+        return dict(model, seed=seeds), jnp.stack(outs, axis=1)
 
     return verify_fn
 
 
-def _build_extract_fn(mesh=None):
-    """Prefix harvest: copy one slot's full KV slab, (layers, rows, heads *
-    head_dim) like the cache less its slot axis, out of the cache
-    (non-donating — the cache keeps serving). Garbage rows past the
-    prefix length ride along; every consumer rewrites them before any
-    query can attend them."""
+def _build_extract_fn(names, slab_sharding=None):
+    """Prefix harvest: copy one slot's full slab, every slot-state array
+    of ``names`` less its slot axis, out of the state (non-donating — the
+    state keeps serving). Garbage rows past the prefix length ride along;
+    every consumer rewrites them before any query can attend them."""
     def extract_fn(state, slot):
-        pk = state["k"][:, slot]
-        pv = state["v"][:, slot]
-        if mesh is not None:
+        slab = {k: state[k][:, slot] for k in names}
+        if slab_sharding is not None:
             import jax
-            from ..parallel.mesh import AXIS_MODEL
-            sh = jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec(None, None, AXIS_MODEL))
-            pk = jax.lax.with_sharding_constraint(pk, sh)
-            pv = jax.lax.with_sharding_constraint(pv, sh)
-        return {"k": pk, "v": pv}
+            slab = {k: jax.lax.with_sharding_constraint(v, slab_sharding)
+                    for k, v in slab.items()}
+        return slab
 
     return extract_fn
 
 
 def _build_implant_fn():
-    """Prefix reuse: write a cached KV slab into one slot (the state is
-    donated — in-place on device); seeds pass through untouched."""
-    def implant_fn(state, slot, pk, pv):
-        return {"k": state["k"].at[:, slot].set(pk),
-                "v": state["v"].at[:, slot].set(pv),
-                "seed": state["seed"]}
+    """Prefix reuse: write a cached slab into one slot (the state is
+    donated — in-place on device); seeds and counters pass through."""
+    def implant_fn(state, slot, slab):
+        return dict(state, **{k: state[k].at[:, slot].set(v)
+                              for k, v in slab.items()})
 
     return implant_fn
 
@@ -375,10 +443,30 @@ class _Slot(object):
         self.producing = None             # (key, L): harvest prefix at L
 
 
+#: steps between two readings of an architecture's device counters in a
+#: TRACED run (each reading is a ``loop_counters`` span; never once a step)
+COUNTER_SPAN_STEPS = 32
+
+
+def _host_leaf(v, quant_mode):
+    """One parameter leaf as a host array: float32, except that under
+    ``quantize="bf16"`` a leaf that already IS bfloat16 stays as it is and
+    WHERE it is, host or device (a float32 copy of a large model is twice
+    its size, and a device array would come to the host only to go back)."""
+    if not isinstance(v, np.ndarray):    # an NDArray keeps its array there
+        v = getattr(v, "data", v)
+    if quant_mode == "bf16" and str(getattr(v, "dtype", "")) == "bfloat16":
+        return v
+    return np.asarray(v, np.float32)
+
+
 class DecodeLoop(object):
-    """Slot-scheduled continuous decoding over a transformer-LM parameter
-    set (``models/transformer.py`` naming: ``tok_embed_weight``,
-    ``layer{i}_...``, ``final_ln_*``, ``lm_head_*``).
+    """Slot-scheduled continuous decoding over a language model's
+    parameter set. The model is an :class:`~mxnet_tpu.serving.arch.
+    Architecture` (``arch=``); the default is :class:`OptArch` over
+    ``num_layers``/``num_heads`` (``models/transformer.py`` naming:
+    ``tok_embed_weight``, ``layer{i}_...``, ``final_ln_*``,
+    ``lm_head_*``). With ``arch=`` the two may be ``None``.
 
     ``generate(prompt, max_new_tokens, temperature=..., top_k=...,
     top_p=..., seed=..., prefix_len=...)`` returns a
@@ -387,21 +475,35 @@ class DecodeLoop(object):
 
     Decode knobs resolve arg > ``MXTPU_SERVE_*`` env > tuning DB >
     default (docs/autotune.md): ``spec_k`` (0 = off; needs
-    ``draft_params``), ``prefix_cache`` (default on), ``quantize``
+    ``draft_params``, and ``draft_arch`` where the draft is no
+    :class:`OptArch`), ``prefix_cache`` (default on), ``quantize``
     (default ``"none"``).
     """
 
-    def __init__(self, params, num_layers, num_heads, max_len, slots=4,
-                 eos_id=None, health=None, name=None, contexts=None,
+    def __init__(self, params, num_layers=None, num_heads=None, max_len=None,
+                 slots=4, eos_id=None, health=None, name=None, contexts=None,
                  quantize=None, prefix_cache=None, spec_k=None,
                  draft_params=None, draft_num_layers=None,
-                 draft_num_heads=None):
+                 draft_num_heads=None, arch=None, draft_arch=None):
         import jax
         import jax.numpy as jnp
         from .. import tracecheck as _tc
         from .engine import _model_mesh
-        self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
+        if max_len is None:
+            raise MXNetError("DecodeLoop: max_len is required")
+        if arch is None:
+            if num_layers is None or num_heads is None:
+                raise MXNetError("DecodeLoop: num_layers and num_heads are "
+                                 "required without arch=")
+            arch = OptArch(num_layers, num_heads)
+        for given, own, what in ((num_layers, arch.num_layers, "num_layers"),
+                                 (num_heads, arch.num_heads, "num_heads")):
+            if given is not None and int(given) != own:
+                raise MXNetError("DecodeLoop: %s=%d disagrees with arch=%s "
+                                 "(%d)" % (what, given, arch.name, own))
+        self._arch = arch
+        self.num_layers = int(arch.num_layers)
+        self.num_heads = int(arch.num_heads)
         self.max_len = int(max_len)
         self.slots = int(slots)
         self.eos_id = eos_id
@@ -412,40 +514,16 @@ class DecodeLoop(object):
         #: the placement rule, the residual stream stays replicated at
         #: block edges (docs/serving.md "Model-parallel replicas")
         self._mesh = _model_mesh(contexts, who="DecodeLoop")
-        if self._mesh is not None:
-            nshard = int(self._mesh.devices.size)
-            if self.num_heads % nshard:
-                raise MXNetError(
-                    "DecodeLoop: num_heads %d %% %d model shards != 0 — "
-                    "the KV cache shards over heads" % (self.num_heads,
-                                                        nshard))
 
-        host_params = {}
-        for k, v in params.items():
-            host_params[k] = np.asarray(getattr(v, "data", v), np.float32)
-        for need in ("tok_embed_weight", "pos_embed_weight",
-                     "final_ln_gamma", "lm_head_weight", "lm_head_bias"):
-            if need not in host_params:
-                raise MXNetError(
-                    "DecodeLoop: params missing %r — expected the "
-                    "models/transformer.py parameter naming" % need)
-        vocab, embed = host_params["tok_embed_weight"].shape
-        if embed % self.num_heads:
-            raise MXNetError("DecodeLoop: embed %d %% num_heads %d != 0"
-                             % (embed, self.num_heads))
-        # jit-mode gather CLAMPS out-of-range indices: a position past the
-        # embedding table would silently reuse its last row (wrong tokens,
-        # zero errors) — fail loudly at construction instead
-        pos_rows = int(host_params["pos_embed_weight"].shape[0])
-        if self.max_len > pos_rows:
-            raise MXNetError(
-                "DecodeLoop: max_len %d exceeds the positional embedding "
-                "table (%d rows) — positions past it would be silently "
-                "clamped" % (self.max_len, pos_rows))
-        self.vocab_size = int(vocab)
+        self.quant_mode = resolve_mode(
+            quantize if quantize is not None
+            else env_str("MXTPU_SERVE_QUANT", "none"))
+        host_params = {k: _host_leaf(v, self.quant_mode)
+                       for k, v in params.items()}
+        self.vocab_size = int(arch.validate(host_params, self.max_len,
+                                            self._mesh, self.quant_mode))
 
-        self._resolve_knobs(host_params, quantize, prefix_cache, spec_k,
-                            draft_params)
+        self._resolve_knobs(host_params, prefix_cache, spec_k, draft_params)
         self.prefix_max = env_int("MXTPU_SERVE_PREFIX_MAX", 8)
 
         self._params = {
@@ -453,62 +531,59 @@ class DecodeLoop(object):
             for k, v in quantize_tree(host_params, self.quant_mode).items()}
 
         # --- draft model (speculative decoding only) ------------------
-        self._draft_params = None
+        self._draft_params = self._draft_arch = None
         self.draft_num_layers = self.draft_num_heads = 0
         if self.spec_k:
-            dhost = {k: np.asarray(getattr(v, "data", v), np.float32)
+            dhost = {k: _host_leaf(v, self.quant_mode)
                      for k, v in draft_params.items()}
-            if draft_num_layers is None:
-                ids = [int(k[5:k.index("_", 5)]) for k in dhost
-                       if k.startswith("layer")]
-                draft_num_layers = max(ids) + 1 if ids else 0
-            self.draft_num_layers = int(draft_num_layers)
-            self.draft_num_heads = int(draft_num_heads or self.num_heads)
-            if self.draft_num_layers <= 0:
-                raise MXNetError(
-                    "DecodeLoop: draft_params has no layer{i}_* entries")
-            for need in ("tok_embed_weight", "pos_embed_weight",
-                         "final_ln_gamma", "lm_head_weight"):
-                if need not in dhost:
+            if draft_arch is None:
+                if not isinstance(arch, OptArch):
                     raise MXNetError(
-                        "DecodeLoop: draft_params missing %r" % need)
-            dvocab, dembed = dhost["tok_embed_weight"].shape
-            if int(dvocab) != self.vocab_size:
+                        "DecodeLoop: spec_k over arch=%s needs draft_arch= "
+                        "(the draft's own architecture)" % arch.name)
+                if draft_num_layers is None:
+                    ids = [int(k[5:k.index("_", 5)]) for k in dhost
+                           if k.startswith("layer")]
+                    draft_num_layers = max(ids) + 1 if ids else 0
+                if int(draft_num_layers) <= 0:
+                    raise MXNetError(
+                        "DecodeLoop: draft_params has no layer{i}_* entries")
+                draft_arch = OptArch(draft_num_layers,
+                                     draft_num_heads or self.num_heads,
+                                     who="draft_params")
+            self._draft_arch = draft_arch
+            self.draft_num_layers = int(draft_arch.num_layers)
+            self.draft_num_heads = int(draft_arch.num_heads)
+            dvocab = int(draft_arch.validate(dhost, self.max_len, self._mesh,
+                                             self.quant_mode))
+            if dvocab != self.vocab_size:
                 raise MXNetError(
                     "DecodeLoop: draft vocab %d != target vocab %d — "
                     "draft proposals must be target token ids"
                     % (dvocab, self.vocab_size))
-            if dembed % self.draft_num_heads:
-                raise MXNetError(
-                    "DecodeLoop: draft embed %d %% draft_num_heads %d "
-                    "!= 0" % (dembed, self.draft_num_heads))
-            if self._mesh is not None \
-                    and self.draft_num_heads % int(self._mesh.devices.size):
-                raise MXNetError(
-                    "DecodeLoop: draft_num_heads %d %% %d model shards "
-                    "!= 0" % (self.draft_num_heads,
-                              int(self._mesh.devices.size)))
-            if self.max_len > int(dhost["pos_embed_weight"].shape[0]):
-                raise MXNetError(
-                    "DecodeLoop: max_len %d exceeds the DRAFT positional "
-                    "embedding table (%d rows)"
-                    % (self.max_len, dhost["pos_embed_weight"].shape[0]))
             self._draft_params = {
                 k: self._place_leaf(v)
                 for k, v in quantize_tree(dhost, self.quant_mode).items()}
 
-        # --- device state: KV cache(s) + per-slot seeds ---------------
+        # --- device state: the slots' state + per-slot seeds ----------
         # speculative windows run past a retiring sequence's last row;
         # one extra TRASH row absorbs those writes (see _build_token_pass).
-        # Rows lie on the chip's 8 sublanes: allocated in whole eights, so
-        # the chip pads nothing; the surplus rows are trash rows too
-        self._rows = -(-(self.max_len + (1 if self.spec_k else 0)) // 8) * 8
-        self._state = self._init_state(self.num_layers, int(embed))
+        # Rows lie on the chip's sublanes, 8 of four bytes: allocated in
+        # whole tiles, so the chip pads nothing; the surplus rows are trash
+        # rows too
+        self._state = self._init_state(arch, host_params)
         self._draft_state = None
         if self.spec_k:
-            self._draft_state = self._init_state(
-                self.draft_num_layers,
-                int(dhost["tok_embed_weight"].shape[1]))
+            self._draft_state = self._init_state(draft_arch, dhost)
+        #: held by whoever dispatches on (and so donates) the state, and by
+        #: whoever reads an array of it from another thread
+        self._state_lock = threading.Lock()
+        self._counter_lock = threading.Lock()
+        self._counter_names = tuple(arch.counters())
+        self._counter_base = {}    # the counters' totals at the last report
+        self._span_sent = False    # this trace has the program's scopes
+        if self._counter_names:
+            self.health.add_source(self._report_counters)
 
         # --- AOT-compile + register every program ---------------------
         self.name = _tc.unique_name(name or "serving-decode")
@@ -526,6 +601,7 @@ class DecodeLoop(object):
             return compiled
 
         samp = self._sampling_structs(jax)
+        live_s = (self._vec_struct(jax, (self.slots,), np.bool_),)
         state_s = self._tree_structs(jax, self._state)
         params_s = self._tree_structs(jax, self._params)
         if self.spec_k:
@@ -535,29 +611,29 @@ class DecodeLoop(object):
             tokw_s = self._vec_struct(jax, (self.slots, window), np.int32)
             self._verify_c = compile_one(
                 "verify[slots=%d,win=%d]" % (self.slots, window),
-                _build_verify_fn(self.num_layers, self.num_heads, window,
-                                 mesh=self._mesh),
-                (state_s, params_s, tokw_s) + samp[1:], (0,))
+                _build_verify_fn(arch, window, mesh=self._mesh),
+                (state_s, params_s, tokw_s) + samp[1:]
+                + live_s * arch.wants_live, (0,))
             self._jfn = self._jfns[-1]   # the main decode body
             self._draft_c = compile_one(
                 "draft[slots=%d,len=%d]" % (self.slots, self.max_len),
-                _build_decode_fn(self.draft_num_layers,
-                                 self.draft_num_heads, mesh=self._mesh),
-                (dstate_s, dparams_s) + samp, (0,))
+                _build_decode_fn(draft_arch, mesh=self._mesh),
+                (dstate_s, dparams_s) + samp
+                + live_s * draft_arch.wants_live, (0,))
         else:
             self._step_c = compile_one(
                 "step[slots=%d,len=%d]" % (self.slots, self.max_len),
-                _build_decode_fn(self.num_layers, self.num_heads,
-                                 mesh=self._mesh),
-                (state_s, params_s) + samp, (0,))
+                _build_decode_fn(arch, mesh=self._mesh),
+                (state_s, params_s) + samp + live_s * arch.wants_live,
+                (0,))
             self._jfn = self._jfns[-1]   # the main decode body
         if self.prefix_enabled:
             slot_s = self._vec_struct(jax, (), np.int32)
-            self._prefix_programs(compile_one, jax, "target", state_s,
-                                  slot_s)
+            self._prefix_programs(compile_one, jax, "target", arch,
+                                  state_s, slot_s)
             if self.spec_k:
-                self._prefix_programs(compile_one, jax, "draft", dstate_s,
-                                      slot_s)
+                self._prefix_programs(compile_one, jax, "draft", draft_arch,
+                                      dstate_s, slot_s)
 
         # MXTPU_MEMCHECK / MXTPU_COMMSCHECK: audit the whole decode
         # program set at LOAD time — memory_report() covers every program
@@ -584,15 +660,12 @@ class DecodeLoop(object):
         self._thread.start()
 
     # ------------------------------------------------------------------
-    def _resolve_knobs(self, host_params, quantize, prefix_cache, spec_k,
+    def _resolve_knobs(self, host_params, prefix_cache, spec_k,
                        draft_params):
-        """arg > MXTPU_SERVE_* env > tuning DB > default. A DB-resolved
+        """arg > MXTPU_SERVE_* env > tuning DB > default (``quantize`` is
+        resolved before the parameters are copied). A DB-resolved
         ``spec_k`` without a draft model falls back with a warning (a
         stale DB row must not break a deploy); an arg/env one raises."""
-        self.quant_mode = resolve_mode(
-            quantize if quantize is not None
-            else env_str("MXTPU_SERVE_QUANT", "none"))
-
         db = {}
         if spec_k is None and not env_str("MXTPU_SERVE_SPEC_K") \
                 or prefix_cache is None \
@@ -670,49 +743,59 @@ class DecodeLoop(object):
                              prefer_first=True)
         return put(leaf, spec)
 
-    def _init_state(self, layers, width):
-        """The donated device state: K and V caches of shape (layers,
-        slots, rows, heads * head_dim) float32, and the slots' seeds. The
-        model's ``width`` is the minor dimension (128 lanes at a time) and
-        rows the second minor (a multiple of the 8 sublanes): the chip's
-        tiles hold no padding and the step program computes in this
-        layout as stored (see :func:`_build_token_pass`). A model mesh
-        shards the minor dimension, a group of whole heads per chip."""
+    def _init_state(self, arch, host_params):
+        """The donated device state of one model: the arrays the
+        architecture's ``slot_state`` names, each ``(layers, slots, rows,
+        width)`` (for :class:`OptArch` a K and a V cache of ``heads *
+        head_dim`` float32), its counters, and the slots' seeds. The
+        ``width`` is the minor dimension (128 lanes at a time) and rows
+        the second minor, a multiple of the sublanes a tile of the
+        narrowest dtype holds (8 of four bytes, 16 of two): the chip's
+        tiles hold no padding in the rows, and the step program computes
+        in this layout as stored (see :func:`_build_token_pass`). A model
+        mesh shards a slot-state array as the architecture says (OptArch:
+        the minor dimension, a group of whole heads per chip)."""
         import jax
         import jax.numpy as jnp
-        cache_shape = (layers, self.slots, self._rows, width)
-        state = {"k": jnp.zeros(cache_shape, np.float32),
-                 "v": jnp.zeros(cache_shape, np.float32),
-                 "seed": jnp.zeros((self.slots,), np.uint32)}
+        spec = arch.slot_state(host_params, self.quant_mode)
+        tile = 8 * 4 // min(np.dtype(d).itemsize for _, d in spec.values())
+        rows = -(-(self.max_len + (1 if self.spec_k else 0)) // tile) * tile
+        if arch is self._arch:
+            self._rows = rows
+        state = {k: jnp.zeros((arch.num_layers, self.slots, rows, width),
+                              dtype) for k, (width, dtype) in spec.items()}
+        state.update({k: jnp.zeros(shape, np.int32)
+                      for k, shape in arch.counters().items()})
+        state["seed"] = jnp.zeros((self.slots,), np.uint32)
         if self._mesh is not None:
-            from ..parallel.mesh import AXIS_MODEL
-            cache_sh = jax.sharding.NamedSharding(
-                self._mesh,
-                jax.sharding.PartitionSpec(None, None, None, AXIS_MODEL))
-            repl = jax.sharding.NamedSharding(
-                self._mesh, jax.sharding.PartitionSpec())
-            state = {"k": jax.device_put(state["k"], cache_sh),
-                     "v": jax.device_put(state["v"], cache_sh),
-                     "seed": jax.device_put(state["seed"], repl)}
+            P = jax.sharding.PartitionSpec
+            slot_sh = jax.sharding.NamedSharding(
+                self._mesh, P(*arch.slot_partition()))
+            repl = jax.sharding.NamedSharding(self._mesh, P())
+            state = {k: jax.device_put(v, slot_sh if k in spec else repl)
+                     for k, v in state.items()}
         return state
 
-    def _prefix_programs(self, compile_one, jax, which, state_s, slot_s):
-        shape = tuple(state_s["k"].shape)
-        slab_shape = (shape[0],) + shape[2:]
+    def _prefix_programs(self, compile_one, jax, which, arch, state_s,
+                         slot_s):
+        names = sorted(set(state_s) - set(arch.counters()) - {"seed"})
+        sh = None
         if self._mesh is not None:
-            from ..parallel.mesh import AXIS_MODEL
+            part = arch.slot_partition()
             sh = jax.sharding.NamedSharding(
                 self._mesh,
-                jax.sharding.PartitionSpec(None, None, AXIS_MODEL))
-            slab_s = jax.ShapeDtypeStruct(slab_shape, np.float32,
-                                          sharding=sh)
-        else:
-            slab_s = jax.ShapeDtypeStruct(slab_shape, np.float32)
+                jax.sharding.PartitionSpec(*(part[:1] + part[2:])))
+        slab_s = {}
+        for k in names:
+            shape = tuple(state_s[k].shape)
+            slab_s[k] = jax.ShapeDtypeStruct(
+                (shape[0],) + shape[2:], state_s[k].dtype,
+                **({} if sh is None else {"sharding": sh}))
         get_c = compile_one("prefix_get[%s]" % which,
-                            _build_extract_fn(mesh=self._mesh),
+                            _build_extract_fn(names, sh),
                             (state_s, slot_s), ())
         put_c = compile_one("prefix_put[%s]" % which, _build_implant_fn(),
-                            (state_s, slot_s, slab_s, slab_s), (0,))
+                            (state_s, slot_s, slab_s), (0,))
         if which == "target":
             self._extract_c, self._implant_c = get_c, put_c
         else:
@@ -814,8 +897,7 @@ class DecodeLoop(object):
                 % ", ".join(missing[:8]))
         new = {}
         for n, resident in self._params.items():
-            arr = np.asarray(getattr(params[n], "data", params[n]),
-                             np.float32)
+            arr = _host_leaf(params[n], self.quant_mode)
             rq = resident["q"] if is_quantized_leaf(resident) else resident
             if tuple(arr.shape) != tuple(rq.shape):
                 raise MXNetError(
@@ -832,9 +914,10 @@ class DecodeLoop(object):
                                         resident["s"].sharding)}
             else:
                 sh = getattr(resident, "sharding", None)
-                new[n] = jax.device_put(np.asarray(stored, rq.dtype), sh) \
-                    if sh is not None else jax.numpy.asarray(
-                        np.asarray(stored, rq.dtype))
+                if not isinstance(stored, jax.Array):   # else: as it is
+                    stored = np.asarray(stored, rq.dtype)
+                new[n] = jax.device_put(stored, sh) \
+                    if sh is not None else jax.numpy.asarray(stored)
         # land transfers BEFORE the rebind so the decode thread never
         # blocks on (or races) an in-flight H2D mid-step
         for v in new.values():
@@ -907,6 +990,9 @@ class DecodeLoop(object):
         if self._thread.is_alive():
             self._thread.join(timeout=5.0)
         self._shed(ServingClosedError("decode loop closed"))
+        if self._counter_names:     # the last counts, then let go of us
+            self._report_counters()
+            self.health.remove_source(self._report_counters)
 
     # ------------------------------------------------------------------
     def _shed(self, exc):
@@ -960,11 +1046,11 @@ class DecodeLoop(object):
     def _implant_slot(self, i, entry):
         s = self._dev_scalar(i)
         t = entry["target"]
-        self._state = self._implant_c(self._state, s, t["k"], t["v"])
+        with self._state_lock:
+            self._state = self._implant_c(self._state, s, t)
         if self.spec_k and entry["draft"] is not None:
-            d = entry["draft"]
             self._draft_state = self._implant_draft_c(
-                self._draft_state, s, d["k"], d["v"])
+                self._draft_state, s, entry["draft"])
 
     def _maybe_harvest(self, i):
         """Prefix-cache producer path: once this slot has teacher-forced
@@ -1025,6 +1111,7 @@ class DecodeLoop(object):
         body = self._step_spec if self.spec_k else self._step_inner
         if not _obs.active():
             self._cpu_ns = None
+            self._span_sent = False
             body(_obs.NOOP)     # no span is live: nothing is built for one
             return
         occ = [s for s in self._slots if s is not None]
@@ -1045,6 +1132,20 @@ class DecodeLoop(object):
             if cpu is not None and self._cpu_ns is not None:
                 sp.set(cpu_us=(cpu - self._cpu_ns) // 1000)
             self._cpu_ns = cpu
+        if cpu is None:
+            self._span_sent = False
+            return
+        # for the trace file only: once a trace the step program's table
+        # of instruction -> scope, and every COUNTER_SPAN_STEPS steps the
+        # architecture's device counters (never once a step)
+        if not self._span_sent:
+            self._span_sent = True
+            self._program_span()
+        if self._counter_names and self._steps % COUNTER_SPAN_STEPS == 0:
+            t0 = time.perf_counter()
+            counts = {k: v.tolist() for k, v in self.counter_totals().items()}
+            _obs.complete("loop_counters", time.perf_counter() - t0,
+                          step=self._steps, **counts)
 
     def _gather_sampling(self):
         """Host-side per-slot dispatch arrays (and consume reseed marks)."""
@@ -1055,10 +1156,12 @@ class DecodeLoop(object):
                 "top_k": np.zeros(n, np.int32),
                 "top_p": np.ones(n, np.float32),
                 "fresh": np.zeros(n, np.uint32),
-                "reseed": np.zeros(n, np.bool_)}
+                "reseed": np.zeros(n, np.bool_),
+                "live": np.zeros(n, np.bool_)}
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
+            arrs["live"][i] = True
             arrs["tokens"][i] = slot.next_token
             arrs["pos"][i] = slot.pos
             arrs["temp"][i] = slot.fut.temperature
@@ -1080,9 +1183,12 @@ class DecodeLoop(object):
         sp.lap("decode_gather")
         _faults.fire("serve.sample")
         dev = self._dev([a["tokens"], a["pos"], a["temp"], a["top_k"],
-                         a["top_p"], a["fresh"], a["reseed"]])
+                         a["top_p"], a["fresh"], a["reseed"]]
+                        + [a["live"]] * self._arch.wants_live)
         sp.lap("decode_h2d")
-        self._state, toks = self._step_c(self._state, self._params, *dev)
+        with self._state_lock:
+            self._state, toks = self._step_c(self._state, self._params,
+                                             *dev)
         # the argument buffers are released here, while the device works,
         # and the token buffer with the readback: left to this function's
         # end, eight releases fall into the device's idle time after the
@@ -1143,7 +1249,8 @@ class DecodeLoop(object):
             dev = self._dev([w[:, j].copy(),
                              (a["pos"] + j).astype(np.int32), a["temp"],
                              a["top_k"], a["top_p"], a["fresh"],
-                             a["reseed"] if j == 0 else no_reseed])
+                             a["reseed"] if j == 0 else no_reseed]
+                            + [a["live"]] * self._draft_arch.wants_live)
             sp.lap("decode_h2d")
             self._draft_state, d_toks = self._draft_c(
                 self._draft_state, self._draft_params, *dev)
@@ -1162,10 +1269,12 @@ class DecodeLoop(object):
                     dfill[i, j + 1] = True
         _faults.fire("serve.spec_verify")
         dev = self._dev([w, a["pos"], a["temp"], a["top_k"], a["top_p"],
-                         a["fresh"], a["reseed"]])
+                         a["fresh"], a["reseed"]]
+                        + [a["live"]] * self._arch.wants_live)
         sp.lap("decode_h2d")
-        self._state, samples = self._verify_c(self._state, self._params,
-                                              *dev)
+        with self._state_lock:
+            self._state, samples = self._verify_c(self._state, self._params,
+                                                  *dev)
         del dev, d_toks     # as in _step_inner: released inside a phase
         sp.lap("decode_dispatch", **verify)
         s = np.asarray(samples)        # (slots, window) int32
@@ -1225,6 +1334,72 @@ class DecodeLoop(object):
         _obs.instant("decode_retire", req=slot.fut.rid, slot=i,
                      emitted=len(slot.emitted))
         self.health.record_retire()
+
+    # ------------------------------------------------------------------
+    def counter_totals(self):
+        """Host copies of the architecture's device counters, cumulative
+        since the loop was built (``{}`` for an architecture without, or
+        once a dead loop's state is gone). Safe from any thread: the lock
+        keeps the step from donating the state while it is read, which
+        holds the loop up for a step at the most."""
+        if not self._counter_names:
+            return {}
+        try:
+            with self._state_lock:
+                return {k: np.asarray(self._state[k])
+                        for k in self._counter_names}
+        except Exception as e:     # the state died with the loop
+            logging.warning("%s: counters unreadable (%r)", self.name, e)
+            return {}
+
+    def _report_counters(self):
+        """:class:`ServingHealth`'s source: what the device counted since
+        the last report goes into the health counters, as the
+        architecture maps it."""
+        with self._counter_lock:
+            counts = self.counter_totals()
+            if counts:
+                self._arch.record_counters(self.health, counts,
+                                           self._counter_base)
+                self._counter_base = counts
+
+    def program_scopes(self):
+        """``{instruction name: scope}`` of the step program (the verify
+        program of a speculative loop): for every instruction of the
+        compiled executable outside its fusions, the ``jax.named_scope``
+        path it was traced under (``layer/moe/experts``), from the
+        executable's own metadata. A device trace names the operations it
+        timed by these instruction names."""
+        import re
+        from ..memcheck import _COMP_RE, _OPNAME_RE
+        name_re = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=")
+        comp = self._verify_c if self.spec_k else self._step_c
+        scopes, fused = {}, False
+        for line in comp.as_text().splitlines():
+            head = _COMP_RE.match(line)
+            if head:
+                fused = head.group("name").startswith("fused_")
+                continue
+            name = None if fused else name_re.match(line)
+            op = name and _OPNAME_RE.search(line)
+            if not op:
+                continue
+            path = [c for c in op.group(1).split("/")[:-1]
+                    if c and "(" not in c]
+            if path:
+                scopes[name.group(1)] = "/".join(path)
+        return scopes
+
+    def _program_span(self):
+        t0 = time.perf_counter()
+        try:
+            scopes = self.program_scopes()
+        except Exception as e:   # an executable that cannot surface HLO
+            logging.warning("%s: no scope table (%r)", self.name, e)
+            return
+        _obs.complete("loop_program", time.perf_counter() - t0,
+                      program="jit_verify_fn" if self.spec_k
+                      else "jit_decode_fn", scopes=scopes)
 
     # ------------------------------------------------------------------
     def memory_report(self, top=8):

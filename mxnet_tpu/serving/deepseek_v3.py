@@ -1,0 +1,445 @@
+"""The DeepSeek-V3 block (``modeling_deepseek.py``; ``model_type: kimi_k2``
+uses it unchanged) for :class:`~mxnet_tpu.serving.decode.DecodeLoop`:
+RMSNorm, rotary positions with YaRN, SwiGLU, latent attention (MLA) in its
+ABSORBED decode form over a latent cache, the sigmoid router with its
+selection bias, and a routed-expert layer that is told which experts it
+holds (docs/serving.md "Architectures").
+
+Parameter names (the repo's): ``tok_embed_weight``, ``final_norm_gamma``,
+``lm_head_weight`` and per layer ``layer{i}_`` + ``attn_norm_gamma``,
+``attn_q_a_weight`` (q_lora, hidden), ``attn_q_a_norm_gamma``,
+``attn_q_b_weight`` (heads * (nope + rope), q_lora), ``attn_kv_a_weight``
+(kv_lora + rope, hidden), ``attn_kv_a_norm_gamma``, ``attn_kv_b_weight``
+(heads * (nope + v), kv_lora), ``attn_out_weight`` (hidden, heads * v),
+``ffn_norm_gamma``; a dense layer ``ffn_{gate,up,down}_weight``; an expert
+layer ``router_weight`` (router_width, hidden), ``router_bias``,
+``shared_{gate,up,down}_weight`` and the HELD experts stacked:
+``experts_{gate,up}_weight`` (held, width, hidden), ``experts_down_weight``
+(held, hidden, width). Every matrix is (out, in).
+
+**The latent cache.** A position leaves ``kv_lora_rank + qk_rope_head_dim``
+values per layer (Kimi-K2: 576, against 2 * 64 * 128 for K and V): the
+normed latent ``c'`` and the rotated shared ``k_pe``, in one array
+``latent`` of ``(layers, slots, rows, width)``, the width minor so that a
+position's write is one contiguous row (PERF.md, PR 28). ``width`` is the
+576 rounded up to whole 128-lane tiles, 640, the surplus lanes zero: at
+576 the chip stores the array ROWS minor (1024 rows fill its lanes, 576
+does not), and the step program, which writes rows, converts the whole
+cache to width-minor on entry and back on exit and one layer's slab
+before every scores product (PERF.md, PR 29). No K and no V is
+ever built: ``q_nope`` goes through the head's K half of ``W_kvb`` into the
+latent space, the scores are one product of ``[q_lat, q_pe]`` with the
+rows, and the weighted sum of ``c'`` goes through the V half.
+
+**The share.** ``n_routed_experts`` of the config is how many experts this
+chip HOLDS: indices ``share_index * n ..`` of the ``router_width`` experts
+the router ranks. The layer routes over all of them, adds only its own
+experts' terms for the (token, choice) pairs that chose them, plus the
+shared expert, and THAT partial sum goes on. On one chip the layer runs
+without its exchange; nothing stands in for the absent chips. The held
+experts are computed densely (every live row through every held expert,
+the unchosen weighted 0): at decode batch sizes an expert's product is
+bound by reading its weights, which a step does once either way.
+
+**Precision.** The operands of every weight product and of the two cache
+products are the STORED dtype (bfloat16 under ``quantize="bf16"``: no
+float32 copy of a weight is ever made), accumulation float32; norms,
+softmax, the router (its product at ``HIGHEST``) and the residual stream
+float32.
+
+**Counters**, on the device in the donated state: ``moe_served`` (expert
+layer, held expert): (token, choice) pairs served here; ``moe_routed``
+(expert layer): pairs routed in all. Only live slots count.
+"""
+from __future__ import annotations
+
+import math
+
+import numpy as np
+
+from ..base import MXNetError
+from .arch import Architecture
+
+_KEYS = ("hidden_size", "num_attention_heads", "q_lora_rank", "kv_lora_rank",
+         "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+         "intermediate_size", "moe_intermediate_size", "num_experts_per_tok",
+         "n_shared_experts", "n_routed_experts", "first_k_dense_replace",
+         "num_hidden_layers", "vocab_size")
+
+
+def yarn_inv_freq(dim, theta, scaling=None):
+    """The ``dim / 2`` inverse frequencies of the rotary pairs (float64):
+    plain ``theta^(-2i/dim)`` without ``scaling``; with it (``type: yarn``)
+    the blend of those with the same over ``factor``, by a linear ramp
+    between the pairs that turn ``beta_fast`` and ``beta_slow`` times over
+    ``original_max_position_embeddings``."""
+    extra = 1.0 / float(theta) ** (np.arange(0, dim, 2, dtype=np.float64)
+                                   / dim)
+    if not scaling:
+        return extra
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def pair_of(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) \
+            / (2 * math.log(float(theta)))
+
+    low = max(math.floor(pair_of(float(scaling["beta_fast"]))), 0)
+    high = min(math.ceil(pair_of(float(scaling["beta_slow"]))), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return extra / float(scaling["factor"]) * ramp + extra * (1.0 - ramp)
+
+
+def yarn_mscale(factor, m):
+    return 1.0 if factor <= 1 else 0.1 * m * math.log(factor) + 1.0
+
+
+def rms_norm(x, gamma, eps):
+    """``x * rsqrt(mean(x^2) + eps) * gamma`` in float32."""
+    import jax
+    import jax.numpy as jnp
+    x = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(var + jnp.float32(eps)) \
+        * gamma.astype(jnp.float32)
+
+
+def rope(x, cos, sin):
+    """Rotate the interleaved pairs ``(x[2i], x[2i+1])`` of the minor
+    dimension by the angles whose cos and sin are given per pair."""
+    import jax.numpy as jnp
+    shape = x.shape
+    x = x.reshape(shape[:-1] + (shape[-1] // 2, 2))
+    a, b = x[..., 0], x[..., 1]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                     axis=-1).reshape(shape)
+
+
+def linear(x, w):
+    """``x @ w.T`` with the operands in the WEIGHT's stored dtype and
+    float32 accumulation."""
+    import jax.numpy as jnp
+    return jnp.einsum("se,fe->sf", x.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
+def swiglu(x, gate, up, down):
+    import jax
+    return linear(jax.nn.silu(linear(x, gate)) * linear(x, up), down)
+
+
+def route(f, weight, bias, top_k, scaling, normalise=True):
+    """``(indices, weights)`` ``(rows, top_k)`` of the experts each row
+    chooses among ALL the router's experts, in float32: chosen by
+    ``sigmoid(W f) + bias``, weighted by the sigmoid alone over the chosen
+    ones' sum (+1e-20), times ``scaling``."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    logits = jnp.einsum("se,xe->sx", f.astype(f32), weight.astype(f32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + bias.astype(f32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if normalise:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + f32(1e-20))
+    return idx, w * f32(scaling)
+
+
+def held_weights(idx, w, first, held):
+    """``(hit, dense)``: ``hit`` (rows, top_k, held) marks the (row,
+    choice) pairs that chose held expert ``first + j``; ``dense`` (rows,
+    held) is each row's weight for each held expert, 0 where unchosen."""
+    import jax.numpy as jnp
+    hit = (idx - first)[:, :, None] == jnp.arange(held)[None, None, :]
+    return hit, jnp.sum(jnp.where(hit, w[:, :, None], jnp.float32(0.0)),
+                        axis=1)
+
+
+def held_experts(f, dense, gate, up, down):
+    """The held experts' part of the layer's output: every row through
+    every held expert (stacked ``(held, ...)`` weights, each read once),
+    summed with ``dense`` (rows, held) in float32."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    fb = f.astype(gate.dtype)
+    g = jnp.einsum("se,xfe->xsf", fb, gate, preferred_element_type=f32)
+    u = jnp.einsum("se,xfe->xsf", fb, up, preferred_element_type=f32)
+    act = (jax.nn.silu(g) * u).astype(down.dtype)
+    y = jnp.einsum("xsf,xef->xse", act, down, preferred_element_type=f32)
+    return jnp.sum(y * dense.T[:, :, None], axis=0)
+
+
+def _pad_lanes(x, width):
+    """``x`` with zeros appended to its minor dimension up to ``width``."""
+    import jax.numpy as jnp
+    pad = width - x.shape[-1]
+    return x if not pad else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def mla_absorbed(q_nope, q_pe, rows, kv_b, tmask, scale, nope):
+    """Latent attention of one position per slot over its ``rows``
+    ``(slots, rows, width)`` (``kv_lora + rope`` values and zero lanes up
+    to whole tiles), the absorbed form: ``q_nope``
+    (slots, heads, nope) through the K half of ``kv_b`` (heads, nope + v,
+    kv_lora), scores of ``[q_lat, q_pe]`` with the rows, float32 softmax
+    under ``tmask`` (slots, rows), the mix of the rows' latent part through
+    the V half. Returns (slots, heads * v) float32."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    op = rows.dtype
+    lora = kv_b.shape[-1]
+    # heads lead both operands: XLA:CPU has no bfloat16 product for the
+    # "shd,hdc" order (the tier-1 tests run this path in bfloat16 too)
+    q_lat = jnp.einsum("hsd,hdc->shc", q_nope.astype(op).swapaxes(0, 1),
+                       kv_b[:, :nope], preferred_element_type=f32)
+    qc = _pad_lanes(jnp.concatenate([q_lat, q_pe], axis=-1),
+                    rows.shape[-1]).astype(op)
+    s = jnp.einsum("shc,stc->sht", qc, rows,
+                   preferred_element_type=f32) * f32(scale)
+    s = jnp.where(tmask[:, None, :], s, f32(-1e30))
+    w = jax.nn.softmax(s, axis=-1)
+    o_lat = jnp.einsum("sht,stc->shc", w.astype(op), rows[..., :lora],
+                       preferred_element_type=f32)
+    o = jnp.einsum("shc,hdc->shd", o_lat.astype(op), kv_b[:, nope:],
+                   preferred_element_type=f32)
+    return o.reshape(o.shape[0], -1)
+
+
+class DeepseekV3Arch(Architecture):
+    """The DeepSeek-V3 / Kimi-K2 block from its ``config.json`` keys, with
+    two of this repo's: ``router_width`` (the experts the router ranks;
+    default ``n_routed_experts``: nothing cut) and ``share_index`` (which
+    ``n_routed_experts`` of them are held here; default 0)."""
+
+    name = "deepseek_v3"
+    wants_live = True
+
+    def __init__(self, config):
+        missing = [k for k in _KEYS + ("rms_norm_eps", "rope_theta",
+                                       "routed_scaling_factor")
+                   if k not in config]
+        if missing:
+            raise MXNetError("DeepseekV3Arch: config lacks %s"
+                             % ", ".join(missing))
+        for k in _KEYS:
+            setattr(self, k, int(config[k]))
+        if config.get("scoring_func", "sigmoid") != "sigmoid" \
+                or int(config.get("n_group", 1)) != 1 \
+                or int(config.get("topk_group", 1)) != 1:
+            raise MXNetError(
+                "DeepseekV3Arch: only the sigmoid router without group "
+                "limits (n_group = topk_group = 1) is implemented")
+        self.num_layers = self.num_hidden_layers
+        self.num_heads = self.num_attention_heads
+        self.eps = float(config["rms_norm_eps"])
+        self.routed_scaling = float(config["routed_scaling_factor"])
+        self.norm_topk_prob = bool(config.get("norm_topk_prob", True))
+        self.router_width = int(config.get("router_width",
+                                           self.n_routed_experts))
+        self.share_index = int(config.get("share_index", 0))
+        self.first_expert = self.share_index * self.n_routed_experts
+        if self.first_expert + self.n_routed_experts > self.router_width:
+            raise MXNetError(
+                "DeepseekV3Arch: share %d of %d held experts lies outside "
+                "the router's %d" % (self.share_index, self.n_routed_experts,
+                                     self.router_width))
+        self.latent = self.kv_lora_rank + self.qk_rope_head_dim
+        #: the cache's minor dimension: whole 128-lane tiles
+        self.latent_width = -(-self.latent // 128) * 128
+        self.moe_layers = [i for i in range(self.num_layers)
+                           if i >= self.first_k_dense_replace]
+        sc = config.get("rope_scaling") or None
+        if sc is not None and sc.get("type", "yarn") != "yarn":
+            raise MXNetError("DeepseekV3Arch: rope_scaling type %r is not "
+                             "implemented (yarn is)" % sc.get("type"))
+        self.inv_freq = yarn_inv_freq(self.qk_rope_head_dim,
+                                      float(config["rope_theta"]), sc)
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        self.softmax_scale = qk ** -0.5
+        self.rope_scale = 1.0
+        if sc is not None:
+            f = float(sc["factor"])
+            all_dim = float(sc.get("mscale_all_dim", 0))
+            if all_dim:
+                self.softmax_scale *= yarn_mscale(f, all_dim) ** 2
+            self.rope_scale = yarn_mscale(f, float(sc.get("mscale", 1))) \
+                / yarn_mscale(f, all_dim)
+
+    # -- what the loop asks ----------------------------------------------------
+    def param_shapes(self):
+        e, h = self.hidden_size, self.num_heads
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        out = {"tok_embed_weight": (self.vocab_size, e),
+               "final_norm_gamma": (e,),
+               "lm_head_weight": (self.vocab_size, e)}
+        for i in range(self.num_layers):
+            pre = "layer%d_" % i
+            out.update({
+                pre + "attn_norm_gamma": (e,),
+                pre + "attn_q_a_weight": (self.q_lora_rank, e),
+                pre + "attn_q_a_norm_gamma": (self.q_lora_rank,),
+                pre + "attn_q_b_weight": (h * qk, self.q_lora_rank),
+                pre + "attn_kv_a_weight": (self.latent, e),
+                pre + "attn_kv_a_norm_gamma": (self.kv_lora_rank,),
+                pre + "attn_kv_b_weight": (
+                    h * (self.qk_nope_head_dim + self.v_head_dim),
+                    self.kv_lora_rank),
+                pre + "attn_out_weight": (e, h * self.v_head_dim),
+                pre + "ffn_norm_gamma": (e,)})
+            if i not in self.moe_layers:
+                f = self.intermediate_size
+                out.update({pre + "ffn_gate_weight": (f, e),
+                            pre + "ffn_up_weight": (f, e),
+                            pre + "ffn_down_weight": (e, f)})
+                continue
+            f, n = self.moe_intermediate_size, self.n_routed_experts
+            fs = f * self.n_shared_experts
+            out.update({pre + "router_weight": (self.router_width, e),
+                        pre + "router_bias": (self.router_width,),
+                        pre + "shared_gate_weight": (fs, e),
+                        pre + "shared_up_weight": (fs, e),
+                        pre + "shared_down_weight": (e, fs),
+                        pre + "experts_gate_weight": (n, f, e),
+                        pre + "experts_up_weight": (n, f, e),
+                        pre + "experts_down_weight": (n, e, f)})
+        return out
+
+    def validate(self, host_params, max_len, mesh, quant_mode):
+        if mesh is not None:
+            raise MXNetError(
+                "DecodeLoop: no model mesh over the %s architecture yet — "
+                "its expert layer has no 'expert' mesh axis and no "
+                "exchange (ROADMAP); serve it on one chip" % self.name)
+        if quant_mode == "int8":
+            raise MXNetError(
+                "DecodeLoop: quantize='int8' is not implemented for the %s "
+                "architecture (none and bf16 are)" % self.name)
+        for name, shape in self.param_shapes().items():
+            if name not in host_params:
+                raise MXNetError(
+                    "DecodeLoop: params missing %r — expected the "
+                    "serving/deepseek_v3.py parameter naming" % name)
+            got = tuple(np.shape(host_params[name]))
+            if got != tuple(shape):
+                raise MXNetError(
+                    "DecodeLoop: %r has shape %s, the %s config gives %s"
+                    % (name, got, self.name, tuple(shape)))
+        return self.vocab_size
+
+    def slot_state(self, host_params, quant_mode):
+        import jax.numpy as jnp
+        dtype = jnp.bfloat16 if quant_mode == "bf16" else np.float32
+        return {"latent": (self.latent_width, dtype)}
+
+    def counters(self):
+        n = len(self.moe_layers)
+        if not n:
+            return {}
+        return {"moe_served": (n, self.n_routed_experts),
+                "moe_routed": (n,)}
+
+    def load(self, params):
+        return params      # as stored: no float32 copy (int8 was refused)
+
+    def record_counters(self, health, counts, before):
+        def total(name):
+            return int(np.sum(counts[name], dtype=np.int64)) \
+                - int(np.sum(before.get(name, 0), dtype=np.int64))
+        health.record_moe(total("moe_routed"), total("moe_served"),
+                          int(np.max(counts["moe_served"])))
+
+    # -- one position per slot through every layer -----------------------------
+    def build_token_pass(self, mesh=None):
+        import jax
+        import jax.numpy as jnp
+        if mesh is not None:
+            self.slot_partition()
+        f32 = jnp.float32
+        nope, lora = self.qk_nope_head_dim, self.kv_lora_rank
+        heads, eps = self.num_heads, self.eps
+        inv_freq = np.asarray(self.inv_freq, np.float32)
+        moe_index = {i: m for m, i in enumerate(self.moe_layers)}
+
+        def token_pass(state, params, tokens, pos, live):
+            lat = state["latent"]
+            nslots, rows = tokens.shape[0], lat.shape[2]
+            wpos = jnp.minimum(pos, jnp.int32(rows - 1))
+            sidx = jnp.arange(nslots)
+            with jax.named_scope("embed"):
+                x = params["tok_embed_weight"][tokens].astype(f32)
+                angle = wpos.astype(f32)[:, None] * inv_freq[None, :]
+                cos = jnp.cos(angle) * f32(self.rope_scale)
+                sin = jnp.sin(angle) * f32(self.rope_scale)
+            tmask = jnp.arange(rows)[None, :] <= pos[:, None]
+            served = state.get("moe_served")
+            routed = state.get("moe_routed")
+            nlive = jnp.sum(live.astype(jnp.int32))
+            # the scope names are what a device trace is searched for: the
+            # same in every layer, so they sum by kind
+            for i in range(self.num_layers):
+                def p(name, pre="layer%d_" % i):
+                    return params[pre + name]
+                with jax.named_scope("layer/mla"):
+                    a = rms_norm(x, p("attn_norm_gamma"), eps)
+                    q = linear(rms_norm(linear(a, p("attn_q_a_weight")),
+                                        p("attn_q_a_norm_gamma"), eps),
+                               p("attn_q_b_weight"))
+                    q = q.reshape(nslots, heads, -1)
+                    q_pe = rope(q[..., nope:], cos[:, None], sin[:, None])
+                    kva = linear(a, p("attn_kv_a_weight"))
+                    row = _pad_lanes(jnp.concatenate(
+                        [rms_norm(kva[:, :lora], p("attn_kv_a_norm_gamma"),
+                                  eps),
+                         rope(kva[:, lora:], cos, sin)], axis=-1),
+                        lat.shape[-1])
+                with jax.named_scope("cache_write"):
+                    lat = lat.at[i, sidx, wpos].set(row.astype(lat.dtype))
+                with jax.named_scope("layer/mla"):
+                    o = mla_absorbed(
+                        q[..., :nope], q_pe, lat[i],
+                        p("attn_kv_b_weight").reshape(heads, -1, lora),
+                        tmask, self.softmax_scale, nope)
+                    x = x + linear(o, p("attn_out_weight"))
+                if i not in moe_index:
+                    with jax.named_scope("layer/mlp"):
+                        f = rms_norm(x, p("ffn_norm_gamma"), eps)
+                        x = x + swiglu(f, p("ffn_gate_weight"),
+                                       p("ffn_up_weight"),
+                                       p("ffn_down_weight"))
+                    continue
+                m = moe_index[i]
+                with jax.named_scope("layer/moe/router"):
+                    f = rms_norm(x, p("ffn_norm_gamma"), eps)
+                    idx, w = route(f, p("router_weight"), p("router_bias"),
+                                   self.num_experts_per_tok,
+                                   self.routed_scaling, self.norm_topk_prob)
+                    hit, dense = held_weights(idx, w, self.first_expert,
+                                              self.n_routed_experts)
+                    here = jnp.sum(hit & live[:, None, None], axis=(0, 1),
+                                   dtype=jnp.int32)
+                    served = served.at[m].add(here)
+                    routed = routed.at[m].add(
+                        nlive * jnp.int32(self.num_experts_per_tok))
+                with jax.named_scope("layer/moe/experts"):
+                    y = held_experts(f, dense, p("experts_gate_weight"),
+                                     p("experts_up_weight"),
+                                     p("experts_down_weight"))
+                with jax.named_scope("layer/moe/shared"):
+                    y = y + swiglu(f, p("shared_gate_weight"),
+                                   p("shared_up_weight"),
+                                   p("shared_down_weight"))
+                x = x + y
+            with jax.named_scope("head"):
+                logits = linear(rms_norm(x, params["final_norm_gamma"], eps),
+                                params["lm_head_weight"])
+            out = {"latent": lat}
+            if served is not None:
+                out.update(moe_served=served, moe_routed=routed)
+            return out, logits
+
+        return token_pass

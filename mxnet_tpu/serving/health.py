@@ -40,7 +40,23 @@ class ServingHealth(object):
         self.spec_rounds = 0       # draft-K-then-verify rounds dispatched
         self.spec_drafted = 0      # draft proposals the target ruled on
         self.spec_accepted = 0     # draft tokens the target verified
+        self.moe_pairs_routed = 0  # (token, choice) pairs expert layers
+        #                            routed, over all the router's experts
+        self.moe_pairs_here = 0    # of them, pairs that chose a HELD expert
+        self.moe_busiest_expert = 0   # the busiest held expert's pairs
         self.last_error = None
+        #: callables run at the top of report(): a decode loop whose
+        #: counters live on the device brings them up to date here
+        self._sources = []
+
+    def add_source(self, fn):
+        with self._lock:
+            self._sources.append(fn)
+
+    def remove_source(self, fn):
+        with self._lock:
+            if fn in self._sources:
+                self._sources.remove(fn)
 
     def _bump(self, field, n=1, err=None):
         with self._lock:
@@ -107,7 +123,23 @@ class ServingHealth(object):
         if self._parent is not None:
             self._parent.record_spec_round(drafted, accepted)
 
+    def record_moe(self, routed, here, busiest):
+        """Expert-layer routing since the last report: ``routed`` and
+        ``here`` are increments, ``busiest`` the count of the busiest held
+        expert so far (the largest is kept)."""
+        with self._lock:
+            self.moe_pairs_routed += int(routed)
+            self.moe_pairs_here += int(here)
+            self.moe_busiest_expert = max(self.moe_busiest_expert,
+                                          int(busiest))
+        if self._parent is not None:
+            self._parent.record_moe(routed, here, busiest)
+
     def report(self):
+        with self._lock:
+            sources = list(self._sources)
+        for fn in sources:
+            fn()
         with self._lock:
             return {
                 "requests": self.requests, "batches": self.batches,
@@ -124,6 +156,9 @@ class ServingHealth(object):
                 "spec_rounds": self.spec_rounds,
                 "spec_drafted": self.spec_drafted,
                 "spec_accepted": self.spec_accepted,
+                "moe_pairs_routed": self.moe_pairs_routed,
+                "moe_pairs_here": self.moe_pairs_here,
+                "moe_busiest_expert": self.moe_busiest_expert,
                 "last_error": self.last_error,
             }
 
@@ -136,6 +171,8 @@ class ServingHealth(object):
             self.joined = self.retired = self.requeued = 0
             self.prefix_hits = self.prefix_prefills = 0
             self.spec_rounds = self.spec_drafted = self.spec_accepted = 0
+            self.moe_pairs_routed = self.moe_pairs_here = 0
+            self.moe_busiest_expert = 0
             self.last_error = None
 
     def __repr__(self):

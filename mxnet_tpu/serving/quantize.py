@@ -56,6 +56,8 @@ def _eligible(arr, mode):
 def quantize_array(arr, mode):
     """Quantize one host array; returns the stored form (ndarray or
     ``{"q","s"}`` dict). Ineligible arrays pass through as f32."""
+    if mode == "bf16" and str(getattr(arr, "dtype", "")) == "bfloat16":
+        return arr      # stored as it came, host or device: no round trip
     a = np.asarray(arr)
     if mode == "none" or not _eligible(a, mode):
         return a

@@ -1,0 +1,259 @@
+"""The OPT loop's step program is the parent's (PERF.md, PR 29): the
+architecture protocol (``serving/arch.py``) moved the block behind
+``OptArch``, and the two OPT cells of the benchmark must not move. Below is
+a FROZEN copy of ``serving/decode.py``'s token pass, decode body and verify
+body as they stood at PR 28; the programs built from the loop's own
+builders lower to the same text, argument for argument, for float32,
+bfloat16 and int8 parameter trees, for the single-token body and the
+speculative window."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import chip_smoke
+from mxnet_tpu.serving import decode
+from mxnet_tpu.serving.quantize import dequant_tree, quantize_tree
+from mxnet_tpu.serving.sampling import position_uniforms, sample_rows
+
+LAYERS, HEADS, VOCAB, EMBED, SLOTS, ROWS = 2, 2, 48, 128, 3, 24
+
+
+# ---- frozen at PR 28 (commit 09dcbac): do not edit ------------------------
+def _ln(x, gamma, beta):
+    import jax
+    import jax.numpy as jnp
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+    return (x - mean) * jax.lax.rsqrt(var + jnp.float32(1e-5)) * gamma + beta
+
+
+def _build_token_pass(num_layers, num_heads, mesh=None):
+    """ONE position per slot through every layer, reading and writing the
+    (layers, slots, rows, heads * head_dim) KV cache. Matches
+    models/transformer.py op-for-op (pre-LN blocks, qkv packing, 1/sqrt(d)
+    scaling) so greedy decode agrees with the full forward.
+
+    This is the shared per-position pass: the single-token decode body
+    runs it once, the speculative verify body unrolls it over the window —
+    a position computes the IDENTICAL op sequence through either, which is
+    what makes speculative output token-identical to target-only decode.
+
+    THE HEADS ARE FOLDED INTO THE MINOR DIMENSION because the chip tiles
+    an array's two minor dimensions into (8 sublanes, 128 lanes): ``heads
+    * head_dim`` (the model's width, a multiple of 128) fills the lanes
+    and ``rows`` the sublanes, so the donated buffer holds no padding and
+    the step program computes in the layout the runtime stores. With
+    ``head_dim`` = 64 alone in the minor dimension the compiler re-laid
+    both caches out on the way into and out of every step: four
+    cache-sized copies, 56% of the step (PERF.md, PR 28). A position's
+    write is one contiguous row per slot. The minor dimension is never
+    reshaped into (heads, head_dim), which would bring the padding back:
+    the per-head contractions are a float32 multiply over all lanes and a
+    sum of each head's lanes (``heads_sum``), and the mix spreads a head's
+    weight over its lanes (``heads_spread``) before a float32 multiply and
+    a sum over rows. Both go through a 0/1 matrix at ``HIGHEST``
+    precision, where a product with 1 is exact: the same float32 products
+    and sums as ever, with no bfloat16 rounding anywhere.
+
+    The write/embed position is clamped to the last cache row. Rows past
+    ``max_len`` are TRASH rows: a speculative window's positions past
+    ``max_len`` land there and no valid query ever attends them (the
+    causal mask covers rows ``<= pos`` and live positions are
+    ``< max_len``); for live positions the clamp is an index identity.
+
+    With a model ``mesh`` the residual stream is pinned REPLICATED at
+    every block boundary while the KV cache and the attention math stay
+    sharded over heads: the lanes split into one GROUP of whole heads per
+    shard and ``heads_sum``/``heads_spread`` work group by group, so
+    per-head contractions never cross shards and the sharded loop emits
+    the same tokens as the single-chip one (docs/serving.md
+    "Model-parallel replicas")."""
+    import jax.numpy as jnp
+    import jax
+
+    if mesh is not None:
+        _repl = jax.sharding.NamedSharding(mesh,
+                                           jax.sharding.PartitionSpec())
+
+        def edge(x):
+            return jax.lax.with_sharding_constraint(x, _repl)
+    else:
+        def edge(x):
+            return x
+
+    groups = 1 if mesh is None else int(mesh.devices.size)
+    highest = jax.lax.Precision.HIGHEST
+
+    def token_pass(ck, cv, params, tokens, pos):
+        nslots = tokens.shape[0]
+        rows = ck.shape[2]
+        wpos = jnp.minimum(pos, jnp.int32(rows - 1))
+        with jax.named_scope("embed"):
+            x = edge(params["tok_embed_weight"][tokens]
+                     + params["pos_embed_weight"][wpos])
+        embed = x.shape[1]
+        d = embed // num_heads
+        scale = jnp.float32(1.0 / float(np.sqrt(d)))
+        sidx = jnp.arange(nslots)
+        tmask = (jnp.arange(rows)[None, :] <= pos[:, None])[:, :, None]
+        neg = jnp.float32(-1e30)
+        # lane e of a group belongs to the group's head e // d
+        lanes, gheads = embed // groups, num_heads // groups
+        seg = (jnp.arange(lanes)[:, None] // d
+               == jnp.arange(gheads)[None, :]).astype(jnp.float32)
+
+        def heads_sum(p):       # (slots, rows, embed) -> (slots, rows, heads)
+            p = p.reshape(nslots, rows, groups, lanes)
+            return jnp.einsum("stge,eh->stgh", p, seg, precision=highest
+                              ).reshape(nslots, rows, num_heads)
+
+        def heads_spread(w):    # (slots, rows, heads) -> (slots, rows, embed)
+            w = w.reshape(nslots, rows, groups, gheads)
+            return jnp.einsum("stgh,eh->stge", w, seg, precision=highest
+                              ).reshape(nslots, rows, embed)
+
+        # the scope names are what an operator searches a device trace
+        # for: the same in every layer, so they sum by kind
+        for i in range(num_layers):
+            pre = "layer%d" % i
+            with jax.named_scope("layer/attn"):
+                a = _ln(x, params[pre + "_ln1_gamma"],
+                        params[pre + "_ln1_beta"])
+                qkv = a @ params[pre + "_attn_qkv_weight"].T \
+                    + params[pre + "_attn_qkv_bias"]
+                qkv = qkv.reshape(nslots, 3, embed)
+                q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]   # (slots, H * D)
+            with jax.named_scope("cache_write"):
+                ck = ck.at[i, sidx, wpos].set(k)
+                cv = cv.at[i, sidx, wpos].set(v)
+            with jax.named_scope("layer/attn"):
+                s = heads_sum(q[:, None, :] * ck[i]) * scale
+                s = jnp.where(tmask, s, neg)
+                w = jax.nn.softmax(s, axis=1)
+                o = jnp.sum(heads_spread(w) * cv[i], axis=1)
+                o = o @ params[pre + "_attn_out_weight"].T \
+                    + params[pre + "_attn_out_bias"]
+                x = edge(x + o)
+            with jax.named_scope("layer/mlp"):
+                f = _ln(x, params[pre + "_ln2_gamma"],
+                        params[pre + "_ln2_beta"])
+                f = jnp.maximum(
+                    f @ params[pre + "_ffn_fc1_weight"].T
+                    + params[pre + "_ffn_fc1_bias"], jnp.float32(0.0))
+                f = f @ params[pre + "_ffn_fc2_weight"].T \
+                    + params[pre + "_ffn_fc2_bias"]
+                x = edge(x + f)
+        with jax.named_scope("head"):
+            x = _ln(x, params["final_ln_gamma"], params["final_ln_beta"])
+            logits = x @ params["lm_head_weight"].T + params["lm_head_bias"]
+        return ck, cv, logits
+
+    return token_pass
+
+
+def _build_decode_fn(num_layers, num_heads, mesh=None):
+    """The single-token decode body: one position per slot, sampled
+    in-graph. Returns ``(state, next_tokens)`` — the host reads back one
+    (slots,) int32 vector, never the logits."""
+    token_pass = _build_token_pass(num_layers, num_heads, mesh=mesh)
+
+    def decode_fn(state, params, tokens, pos, temp, top_k, top_p,
+                  fresh_seed, reseed):
+        import jax
+        import jax.numpy as jnp
+        seeds = jnp.where(reseed, fresh_seed, state["seed"])
+        p = dequant_tree(params)
+        ck, cv, logits = token_pass(state["k"], state["v"], p, tokens, pos)
+        with jax.named_scope("sample"):
+            u = position_uniforms(seeds, pos)
+            nxt = sample_rows(logits, u, temp, top_k, top_p)
+        return {"k": ck, "v": cv, "seed": seeds}, nxt
+
+    return decode_fn
+
+
+def _build_verify_fn(num_layers, num_heads, window, mesh=None):
+    """The speculative verify body: ``window`` positions per slot through
+    the SAME per-position pass as the single-token body, unrolled (the
+    cache threads through, so position j attends the rows j' < j wrote),
+    each position sampled with its own (seed, position) uniform. One
+    dispatch scores and samples the whole window."""
+    token_pass = _build_token_pass(num_layers, num_heads, mesh=mesh)
+
+    def verify_fn(state, params, tokens_w, pos0, temp, top_k, top_p,
+                  fresh_seed, reseed):
+        import jax
+        import jax.numpy as jnp
+        seeds = jnp.where(reseed, fresh_seed, state["seed"])
+        p = dequant_tree(params)
+        ck, cv = state["k"], state["v"]
+        outs = []
+        for j in range(window):
+            pos_j = pos0 + jnp.int32(j)
+            ck, cv, logits = token_pass(ck, cv, p, tokens_w[:, j], pos_j)
+            with jax.named_scope("sample"):
+                u = position_uniforms(seeds, pos_j)
+                outs.append(sample_rows(logits, u, temp, top_k, top_p))
+        return ({"k": ck, "v": cv, "seed": seeds},
+                jnp.stack(outs, axis=1))
+
+    return verify_fn
+
+
+# ---- end of the frozen copy ------------------------------------------------
+
+
+def _structs(mode):
+    params = quantize_tree(chip_smoke.lm_params(VOCAB, EMBED, HEADS, LAYERS,
+                                                ROWS, seed=1), mode)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    params = jax.tree_util.tree_map(sds, params)
+    cache = jax.ShapeDtypeStruct((LAYERS, SLOTS, ROWS, EMBED), np.float32)
+    state = {"k": cache, "v": cache,
+             "seed": jax.ShapeDtypeStruct((SLOTS,), np.uint32)}
+    n = (SLOTS,)
+    samp = [jax.ShapeDtypeStruct(n, d) for d in
+            (np.int32, np.int32, np.float32, np.int32, np.float32,
+             np.uint32, np.bool_)]
+    return state, params, samp
+
+
+@pytest.mark.parametrize("mode", ["none", "bf16", "int8"])
+def test_the_step_program_is_the_parents(mode):
+    state, params, samp = _structs(mode)
+    old = jax.jit(_build_decode_fn(LAYERS, HEADS), donate_argnums=(0,))
+    new = jax.jit(decode._build_decode_fn(decode.OptArch(LAYERS, HEADS)),
+                  donate_argnums=(0,))
+    assert new.lower(state, params, *samp).as_text() \
+        == old.lower(state, params, *samp).as_text()
+
+
+@pytest.mark.parametrize("window", [2, 3])
+def test_the_verify_program_is_the_parents(window):
+    state, params, samp = _structs("none")
+    samp[0] = jax.ShapeDtypeStruct((SLOTS, window), np.int32)
+    old = jax.jit(_build_verify_fn(LAYERS, HEADS, window),
+                  donate_argnums=(0,))
+    new = jax.jit(decode._build_verify_fn(decode.OptArch(LAYERS, HEADS),
+                                          window), donate_argnums=(0,))
+    assert new.lower(state, params, *samp).as_text() \
+        == old.lower(state, params, *samp).as_text()
+
+
+def test_the_loop_builds_its_step_from_those_builders():
+    """The loop's own state and feed are the frozen program's arguments:
+    K, V and seeds, seven per-slot arrays, no eighth."""
+    params = chip_smoke.lm_params(VOCAB, EMBED, HEADS, LAYERS, ROWS, seed=1)
+    loop = decode.DecodeLoop(params, LAYERS, HEADS, ROWS, slots=SLOTS,
+                             prefix_cache=False, spec_k=0)
+    try:
+        assert sorted(loop._state) == ["k", "seed", "v"]
+        assert isinstance(loop._arch, decode.OptArch)
+        assert not loop._arch.wants_live and not loop._arch.counters()
+        (_, structs, donate), = loop._programs.values()
+        assert len(structs) == 9 and donate == (0,)
+        assert loop.counter_totals() == {}
+    finally:
+        loop.close()
