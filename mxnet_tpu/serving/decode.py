@@ -1222,7 +1222,7 @@ class DecodeLoop(object):
                 self._retire(i)
                 continue
             self._maybe_harvest(i)
-        self.health.record_decode_step(emitted, prompt)
+        self._count_step(sp, a, emitted, prompt)
         sp.lap("decode_commit")
 
     def _step_spec(self, sp):
@@ -1324,8 +1324,16 @@ class DecodeLoop(object):
         # retire/length break left unverified would deflate the acceptance
         # rate a perfect draft earns (drafted == accepted by construction)
         self.health.record_spec_round(judged, accepted)
-        self.health.record_decode_step(emitted, prompt)
+        self._count_step(sp, a, emitted, prompt)
         sp.lap("decode_commit")
+
+    def _count_step(self, sp, a, emitted, prompt):
+        """The step's counts, for the health report and its span. Rows
+        whose ``temp`` is above 0 sample: any at all and the step's
+        program took the sampler's branch (sampling.py, rule 3)."""
+        sampled = int((a["temp"] > 0).sum())
+        sp.set(sampled=sampled)
+        self.health.record_decode_step(emitted, prompt, sampled)
 
     def _retire(self, i):
         slot = self._slots[i]

@@ -30,6 +30,9 @@ class ServingHealth(object):
         self.tokens_emitted = 0    # tokens decode steps handed to requests
         self.prompt_positions = 0  # cache positions committed that emitted
         #                            none (a prompt being fed)
+        self.sampled_steps = 0     # decode steps in which some row sampled
+        #                            (temperature > 0): the steps that paid
+        #                            for the in-graph sampler
         self.joined = 0            # sequences that entered a decode slot
         self.retired = 0           # sequences that left a decode slot
         self.requeued = 0          # requests moved off a dead/draining
@@ -89,16 +92,19 @@ class ServingHealth(object):
     def record_error(self, err=None):
         self._bump("errors", err=err)
 
-    def record_decode_step(self, emitted=0, prompt=0):
+    def record_decode_step(self, emitted=0, prompt=0, sampled=0):
         """One decode step (or speculative round) that handed ``emitted``
-        tokens to its requests and processed ``prompt`` positions that
-        emitted none: the three counts move under one lock."""
+        tokens to its requests, processed ``prompt`` positions that
+        emitted none and fed ``sampled`` rows with a temperature above 0
+        (any at all and the step ran the sampler): the four counts move
+        under one lock."""
         with self._lock:
             self.decode_steps += 1
             self.tokens_emitted += int(emitted)
             self.prompt_positions += int(prompt)
+            self.sampled_steps += int(sampled > 0)
         if self._parent is not None:
-            self._parent.record_decode_step(emitted, prompt)
+            self._parent.record_decode_step(emitted, prompt, sampled)
 
     def record_join(self):
         self._bump("joined")
@@ -149,6 +155,7 @@ class ServingHealth(object):
                 "decode_steps": self.decode_steps,
                 "tokens_emitted": self.tokens_emitted,
                 "prompt_positions": self.prompt_positions,
+                "sampled_steps": self.sampled_steps,
                 "joined": self.joined,
                 "retired": self.retired, "requeued": self.requeued,
                 "prefix_hits": self.prefix_hits,
@@ -168,6 +175,7 @@ class ServingHealth(object):
             self.padded = self.expired = self.dropped = 0
             self.shed = self.errors = self.decode_steps = 0
             self.tokens_emitted = self.prompt_positions = 0
+            self.sampled_steps = 0
             self.joined = self.retired = self.requeued = 0
             self.prefix_hits = self.prefix_prefills = 0
             self.spec_rounds = self.spec_drafted = self.spec_accepted = 0

@@ -1,7 +1,7 @@
 """In-graph token sampling for the decode loop (docs/serving.md
 "Sampling").
 
-Two design rules make every decode feature on top of this composable:
+Three design rules make every decode feature on top of this composable:
 
 1. **Stateless per-(seed, position) randomness.** The uniform driving a
    slot's sample at position ``p`` is ``uniform(fold_in(PRNGKey(seed),
@@ -19,9 +19,25 @@ Two design rules make every decode feature on top of this composable:
    the SAME function and the emitted stream is token-identical to
    target-only decoding (docs/serving.md "Speculative decoding").
 
+3. **A step pays for sampling only if a row samples.** The sampler's
+   value chain (scale, sort, gather, softmax, the top-k/top-p mask, two
+   cumulative sums, the inverse CDF) stands in one branch of a
+   ``lax.cond`` on ``any(temp > 0)``, a scalar the program computes from
+   its own input; the other branch hands the ``argmax`` through. Still
+   ONE compiled program with the same arguments: no second executable,
+   no recompile when a sampled request joins, no knob. On the chip the
+   ``cond`` is an HLO ``conditional`` and one branch runs: the sort and
+   the gather over slots x vocabulary were 48% of a 64-slot Kimi-K2 step
+   whose requests were all greedy (PERF.md, PR 30). THE CLIFF: one
+   sampled row costs every row of the step the sort, as every step did
+   before; what a row draws does not depend on which branch its
+   co-riders force (tests/test_sampling_branch.py holds the branch
+   against the straight-line body, bitwise).
+
 ``sample_rows`` is the ONE row-wise sampler shared by the single-token
 decode body and the multi-position verify body, so a position sampled
-through either body draws the identical token.
+through either body draws the identical token, and both get rule 3 from
+this one place.
 
 Per-row knobs (all traced, so the decode body stays one program):
 ``temp`` (0 = greedy argmax, bitwise the pre-sampling decode path),
@@ -72,6 +88,10 @@ def sample_rows(logits, u, temp, top_k, top_p):
     keep the top-k ranks, keep the smallest prefix whose EXCLUSIVE
     cumulative probability is < top_p (so the head token always
     survives), renormalize implicitly by sampling u * kept_mass.
+
+    A call whose rows are all greedy runs the argmax alone (rule 3 of
+    the module docstring): everything else stands in ``sampler``, the
+    true branch of the ``cond`` on ``any(temp > 0)``.
     """
     import jax
     import jax.numpy as jnp
@@ -79,26 +99,29 @@ def sample_rows(logits, u, temp, top_k, top_p):
     vocab = logits.shape[-1]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    safe_t = jnp.where(temp > 0, temp, jnp.float32(1.0))
-    scaled = logits / safe_t[:, None]
-    order = jnp.argsort(-scaled, axis=-1)          # stable: ties by index
-    probs = jax.nn.softmax(
-        jnp.take_along_axis(scaled, order, axis=-1), axis=-1)
+    def sampler():
+        safe_t = jnp.where(temp > 0, temp, jnp.float32(1.0))
+        scaled = logits / safe_t[:, None]
+        order = jnp.argsort(-scaled, axis=-1)      # stable: ties by index
+        probs = jax.nn.softmax(
+            jnp.take_along_axis(scaled, order, axis=-1), axis=-1)
 
-    ranks = jnp.arange(vocab, dtype=jnp.int32)[None, :]
-    k_eff = jnp.where(top_k > 0, top_k, jnp.int32(vocab))[:, None]
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (ranks < k_eff) & ((cum - probs) < top_p[:, None])
-    kept = jnp.where(keep, probs, jnp.float32(0.0))
+        ranks = jnp.arange(vocab, dtype=jnp.int32)[None, :]
+        k_eff = jnp.where(top_k > 0, top_k, jnp.int32(vocab))[:, None]
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = (ranks < k_eff) & ((cum - probs) < top_p[:, None])
+        kept = jnp.where(keep, probs, jnp.float32(0.0))
 
-    cdf = jnp.cumsum(kept, axis=-1)
-    target = u[:, None] * cdf[:, -1:]
-    hit = cdf > target
-    # float-edge guard (u ~ 1.0): if no strict crossing, take the last
-    # kept rank — ``keep`` is a prefix mask, so that is count-1
-    rank = jnp.where(jnp.any(hit, axis=-1),
-                     jnp.argmax(hit, axis=-1),
-                     jnp.sum(keep.astype(jnp.int32), axis=-1) - 1)
-    sampled = jnp.take_along_axis(order, rank[:, None],
-                                  axis=-1)[:, 0].astype(jnp.int32)
-    return jnp.where(temp > 0, sampled, greedy)
+        cdf = jnp.cumsum(kept, axis=-1)
+        target = u[:, None] * cdf[:, -1:]
+        hit = cdf > target
+        # float-edge guard (u ~ 1.0): if no strict crossing, take the last
+        # kept rank — ``keep`` is a prefix mask, so that is count-1
+        rank = jnp.where(jnp.any(hit, axis=-1),
+                         jnp.argmax(hit, axis=-1),
+                         jnp.sum(keep.astype(jnp.int32), axis=-1) - 1)
+        sampled = jnp.take_along_axis(order, rank[:, None],
+                                      axis=-1)[:, 0].astype(jnp.int32)
+        return jnp.where(temp > 0, sampled, greedy)
+
+    return jax.lax.cond(jnp.any(temp > 0), sampler, lambda: greedy)

@@ -5,7 +5,12 @@ a FROZEN copy of ``serving/decode.py``'s token pass, decode body and verify
 body as they stood at PR 28; the programs built from the loop's own
 builders lower to the same text, argument for argument, for float32,
 bfloat16 and int8 parameter trees, for the single-token body and the
-speculative window."""
+speculative window.
+
+The frozen bodies call the LIVE ``sample_rows``: a change that stays
+inside ``sample_rows`` (PR 30's ``lax.cond`` around the sampler) reaches
+both sides alike and this file passes unedited; ``sample_rows`` itself is
+held against its own frozen body in ``tests/test_sampling_branch.py``."""
 import numpy as np
 import pytest
 

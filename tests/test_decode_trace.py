@@ -2,9 +2,9 @@
 catalogue", docs/serving.md "Reading a request's token times"): the six
 leaves of the step's host round trip (five laps of ``decode_step`` and
 ``decode_admit``), what each ``decode_step`` span
-says it processed (``pos``/``n``/``emit``/``cpu_us``), the counters an
+says it processed (``pos``/``n``/``emit``/``sampled``/``cpu_us``), the counters an
 operator reads with tracing off (``tokens_emitted``,
-``prompt_positions``), ``GenerateFuture.token_times``, the profiler's
+``prompt_positions``, ``sampled_steps``), ``GenerateFuture.token_times``, the profiler's
 clock sync, and the stable scope names inside the compiled programs.
 """
 import json
@@ -66,12 +66,14 @@ def _clean_tracer():
 
 
 def _serve(loop, requests, **kw):
-    """Run ``(prompt, new)`` pairs to their end with tracing armed; returns
-    ``(futures, complete events, growth of the process-wide counters)``."""
+    """Run ``(prompt, new)`` pairs (or ``(prompt, new, its own arguments)``)
+    to their end with tracing armed; returns ``(futures, complete events,
+    growth of the process-wide counters)``."""
     before = SERVING_HEALTH.report()
     obs_trace.start()
     try:
-        futs = [loop.generate(p, n, **kw) for p, n in requests]
+        futs = [loop.generate(p, n, **dict(kw, **(own[0] if own else {})))
+                for p, n, *own in requests]
         for f in futs:
             f.result(timeout=120.0)
     finally:
@@ -79,7 +81,8 @@ def _serve(loop, requests, **kw):
         obs_trace.stop()
     after = SERVING_HEALTH.report()
     grown = {k: after[k] - before[k]
-             for k in ("decode_steps", "tokens_emitted", "prompt_positions")}
+             for k in ("decode_steps", "tokens_emitted", "prompt_positions",
+                       "sampled_steps")}
     # the laps written out as child spans, as the trace file has them
     evs = obs_trace.expand_laps(obs_trace.events())
     return futs, [e for e in evs if e["ph"] == "X"], grown
@@ -258,8 +261,34 @@ def test_emitted_tokens_agree_across_spans_counters_and_futures(plain_run):
         == sum(len(p) - 1 for p, _ in PLAIN)
     assert h["decode_steps"] == len(steps)
     # mirrored into the process-wide aggregate
+    # every request is greedy: no step took the sampler's branch
+    assert [st["args"]["sampled"] for st in steps] == [0] * len(steps)
+    assert h["sampled_steps"] == 0
     assert grown == {"decode_steps": len(steps), "tokens_emitted": returned,
-                     "prompt_positions": positions - emitted}
+                     "prompt_positions": positions - emitted,
+                     "sampled_steps": 0}
+
+
+@pytest.mark.parametrize("spec_k", [0, 2])
+def test_sampled_steps_counts_the_steps_a_sampled_request_was_seated(spec_k):
+    """One ``temperature=0.8`` request among greedy ones, more requests
+    than slots: ``sampled_steps`` is the number of ``decode_step`` spans
+    whose ``sampled`` > 0, which are exactly the steps (rounds, in a
+    speculative loop) that list the request, prompt positions included."""
+    params = _lm_params()
+    kw = dict(spec_k=2, draft_params=params,
+              draft_num_layers=_LM["num_layers"]) if spec_k else {}
+    loop = _loop(params, **kw)
+    futs, evs, grown = _serve(loop, [
+        ([1, 2, 3], 4), ([4, 5], 3),
+        ([6, 7, 8], 5, dict(temperature=0.8, seed=11)), ([9], 2)])
+    steps = _steps(evs)
+    seated = [st for st in steps if futs[2].rid in st["args"]["reqs"]]
+    sampling = [st for st in steps if st["args"]["sampled"] > 0]
+    assert 0 < len(seated) < len(steps)
+    assert sampling == seated
+    assert {st["args"]["sampled"] for st in sampling} == {1}
+    assert loop.health.sampled_steps == grown["sampled_steps"] == len(seated)
 
 
 def test_the_benchmarks_reader_counts_what_the_counter_counted(plain_run):
@@ -417,27 +446,31 @@ def test_the_recorder_alone_keeps_the_spans_live():
 # counters
 # ---------------------------------------------------------------------------
 
-def test_record_decode_step_moves_three_counts_and_mirrors_them():
+def test_record_decode_step_moves_four_counts_and_mirrors_them():
     parent = ServingHealth()
     h = ServingHealth(parent=parent)
     h.record_decode_step(3, 5)
     h.record_decode_step()
+    h.record_decode_step(2, 0, sampled=7)    # seven rows, ONE sampled step
     for x in (h, parent):
         r = x.report()
         assert (r["decode_steps"], r["tokens_emitted"],
-                r["prompt_positions"]) == (2, 3, 5)
+                r["prompt_positions"], r["sampled_steps"]) == (3, 5, 5, 1)
     h.reset()
-    assert h.tokens_emitted == h.prompt_positions == h.decode_steps == 0
-    assert parent.tokens_emitted == 3
+    assert h.tokens_emitted == h.prompt_positions == h.decode_steps \
+        == h.sampled_steps == 0
+    assert parent.tokens_emitted == 5 and parent.sampled_steps == 1
 
 
 def test_new_counters_reach_the_registry_and_prometheus():
     snap = obs.REGISTRY.snapshot()
     assert "serving_health.tokens_emitted" in snap
     assert "serving_health.prompt_positions" in snap
+    assert "serving_health.sampled_steps" in snap
     prom = obs.REGISTRY.to_prometheus()
     assert "serving_health_tokens_emitted" in prom
     assert "serving_health_prompt_positions" in prom
+    assert "serving_health_sampled_steps" in prom
 
 
 # ---------------------------------------------------------------------------

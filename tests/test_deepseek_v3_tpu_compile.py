@@ -7,7 +7,10 @@ dense layer and one expert layer that holds 12 of 384 experts, 64 slots,
   cache-sized copy (at its bare 576 lanes the chip stores it rows minor and
   the step converts all of it on entry and on exit);
 * no float32 copy of a weight matrix is made;
-* the program fits the chip.
+* the program fits the chip;
+* the sampler (the sort and the gather over slots x vocabulary) stands
+  inside ONE ``conditional``, so a step whose rows are all greedy does not
+  run it (PERF.md, PR 30).
 
 The topology is described inside a fixture (only one process may load the
 TPU's library; a worker that cannot skips), and this is the one file that
@@ -102,6 +105,51 @@ def test_no_float32_copy_of_a_weight_matrix(step):
     big = [i for i in _top_level(compiled)
            if i[1] == "f32" and i[2] >= 4 * 2048 * 7168]   # one expert matrix
     assert big == []
+
+
+def test_the_sampler_stands_inside_the_conditional(step):
+    """What the entry computation reaches without entering a branch of the
+    conditional holds no sort and no gather over slots x vocabulary (the
+    router's top-k sort over 64 x 384 stays, and the embedding's gather);
+    the conditional's branches hold both."""
+    from mxnet_tpu import flopcheck as fc
+    arch, compiled = step
+    comps, entry = fc._parse_computations(compiled.as_text())
+
+    def walk(name, seen):
+        """Instructions of ``name`` and of what they call, a conditional's
+        branches left out."""
+        if name in seen:
+            return []
+        seen.add(name)
+        out = []
+        for ins in comps[name]:
+            out.append(ins)
+            rest = fc._BRANCHES_RE.sub("", ins["rest"])
+            for rx in (fc._CALLS_RE, fc._TO_APPLY_RE, fc._BODY_RE):
+                m = rx.search(rest)
+                if m:
+                    out.extend(walk(m.group(1), seen))
+        return out
+
+    def wide(ins, opcode):
+        return ins["opcode"] == opcode \
+            and fc._type_elems(ins["type"]) >= SLOTS * arch.vocab_size
+
+    outside = walk(entry, set())
+    assert len(outside) > 300                      # it did walk the step
+    cond, = [i for i in outside if i["opcode"] == "conditional"]
+    assert cond["op_path"].endswith("/sample/cond")
+    assert [i["instr"] for i in outside
+            if wide(i, "sort") or wide(i, "gather")] == []
+    branches = [n for m in fc._BRANCHES_RE.finditer(cond["rest"])
+                for n in fc._BRANCH_NAME_RE.findall(m.group(0))]
+    assert len(branches) == 2
+    inside = [i for b in branches for i in walk(b, set())]
+    assert any(wide(i, "sort") for i in inside)
+    assert any(wide(i, "gather") for i in inside)
+    # the greedy branch computes nothing: it hands the argmax through
+    assert min(len(comps[b]) for b in branches) <= 2
 
 
 def test_at_576_lanes_the_step_converts_the_cache(one_chip):

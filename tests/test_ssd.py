@@ -101,6 +101,10 @@ def test_multibox_pallas_kernel_matches_xla_sweep(monkeypatch):
 
 
 def test_ssd_training_smoke_loss_decreases():
+    # Xavier and the iterator's shuffle draw from the global stream: left
+    # unseeded, whatever ran before in this worker decides the run, and 1
+    # seed in 16 does not reach 0.8 in 16 epochs (seed 15; PR 30)
+    mx.random.seed(0)
     rng = np.random.default_rng(0)
     imgs, labels = synth_det_batch(rng, 32, 96, 3)
     it = mx.io.NDArrayIter(imgs, labels, batch_size=16, shuffle=True,
