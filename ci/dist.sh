@@ -5,10 +5,7 @@
 # inside each surviving worker — the emergency checkpoint, the ring
 # re-form at N-1 with re-derived data shards, training to the accuracy
 # floor, bitwise-consistent survivor replicas, and a bitwise-identical
-# fresh resume; then gates the collective throughput (net of the
-# configured MXTPU_DIST_DEAD_FOR detection stall) against a
-# single-worker baseline (floor MXTPU_DIST_MIN_SCALE, default 0.10).
-# Emits DIST_r17.json.
+# fresh resume. Reports no rate.
 set -e
 cd "$(dirname "$0")/.."
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" PYTHONPATH=. \

@@ -35,14 +35,6 @@ JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 # it, a measured winner >= the default persists to the tuning DB, and a
 # fresh Module.fit resolves it (obs-logged) with zero extra retraces
 ./ci/autotune.sh
-# serving-tier smoke: AOT buckets + dynamic batcher at low QPS, zero
-# tracecheck findings on the serving program set (docs/serving.md)
-./ci/serve.sh
-# fleet-tier smoke (docs/serving.md "Fleet tier"): 2 replicas behind the
-# priority-aware router at a QPS one replica cannot hold, mid-run
-# drain+rejoin; zero failed/shed requests, per-class p99 cap, zero
-# static findings across every replica's program set
-./ci/fleet.sh
 # flagship-LM gate (docs/perf.md "Flagship LM"): dp2 x sp2 ring-attention
 # fit parity vs single device, MID-FIT decode hot reload (zero recompiles,
 # bitwise vs a fresh engine), zero retraces, and zero analyzer findings
@@ -53,18 +45,11 @@ JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 # spans nested, dispatch/request IDs consistent), registry snapshot
 # carries every legacy health key, tracing-off cost A/B
 ./ci/obs.sh
-# real-data input-tier smoke (docs/perf.md "Device-fed input pipeline"):
-# small real-JPEG epoch through reader -> decode workers -> prefetch ->
-# fused scan; gates the real/synthetic throughput ratio floor
-# (MXTPU_REALDATA_MIN_RATIO), zero tracecheck findings, and populated
-# DataHealth/PipelineStats
-./ci/realdata.sh
 # elastic-distributed gate (docs/robustness.md "Elastic distributed
 # training"): REAL 3-process dist_sync run that SIGKILLs a worker
 # mid-epoch — emergency checkpoint, ring re-form at N-1 with re-derived
 # shards, accuracy floor, bitwise-consistent survivors, bitwise fresh
-# resume, and a collective-throughput floor vs 1 worker
-# (MXTPU_DIST_MIN_SCALE); emits DIST_r*.json
+# resume
 ./ci/dist.sh
 # chaos gate (docs/robustness.md "Chaos harness"): RED self-test first
 # (a deliberately inverted invariant must fail a run), then seeded
@@ -73,11 +58,9 @@ JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 # zero hangs, committed-regression replays, and the shrinker loop;
 # emits CHAOS_r*.json
 ./ci/chaos.sh
-# multichip gate (docs/perf.md "Data-parallel scaling"): MEASURED — 8-device
-# fused-fit img/s + scaling efficiency vs 1 device (floor
-# MXTPU_MULTICHIP_MIN_EFF, default 0.7), guard + bitwise checkpoint/resume
-# composition, collective/donation audit of the sharded program set; emits
-# MULTICHIP_r*.json
+# multichip gate: the fused fit over an 8-device 'data' mesh runs, guard +
+# bitwise checkpoint/resume compose, collective/donation audit of the
+# sharded program set, dp+tp / dp+sp compile coverage; measures nothing
 python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 # chip stage: hard convergence gates + the ImageNet recipe compile-check
 # (uses the real TPU when attached; tools default to the ambient platform).

@@ -5,7 +5,7 @@ need_mirror; docs/how_to env var MXNET_BACKWARD_DO_MIRROR).
 
 Measures compiled peak memory of a ResNet train step at several remat
 settings via XLA's memory analysis — the bs-vs-speed trade the reference's
-memonger documents (BASELINE.md inception bs128@27img/s vs bs64@30img/s).
+memonger documents.
 
   python memonger.py --depth 50 --batch 64
 """

@@ -1,4 +1,4 @@
-"""mxnet_tpu.autotune — closes the loop between bench and config
+"""mxnet_tpu.autotune — closes the loop between measurement and config
 (docs/perf.md "Autotuning"; the TVM measured-search discipline,
 arXiv:1802.04799, applied to this system's own knobs).
 
@@ -69,13 +69,13 @@ def enabled():
 
 
 # ---------------------------------------------------------------------------
-# resolution (Module.fit / ServingEngine / bench.py consumers)
+# resolution (Module.fit / ServingEngine / DecodeLoop consumers)
 # ---------------------------------------------------------------------------
 
 def note_db_resolution(logger, who, entry_key, applied):
     """The once-per-run resolution log + obs-registry count
     (docs/observability.md): every run that takes knob values from the
-    tuning DB says so exactly once, with the entry key, so a bench or
+    tuning DB says so exactly once, with the entry key, so a serving or
     training log always reveals where its configuration came from."""
     from ..obs import REGISTRY
     REGISTRY.counter(

@@ -4,8 +4,8 @@
     python -m mxnet_tpu.autotune --model mlp --objective img_per_sec \
         --budget 12 --write-db
 
-Progress lines go to stderr; the final result is ONE JSON line on stdout
-(the bench.py house style). Exit status: 0 on a sweep with at least one
+Progress lines go to stderr; the final result is ONE JSON line on stdout.
+Exit status: 0 on a sweep with at least one
 successful trial, 2 when every candidate was pruned/crashed/timed out.
 """
 from __future__ import annotations
@@ -25,7 +25,7 @@ def main(argv=None):
     p = argparse.ArgumentParser(
         prog="python -m mxnet_tpu.autotune",
         description="Search the performance-knob space for one model and "
-                    "objective through the in-process bench harnesses; "
+                    "objective through the in-process trial harnesses; "
                     "optionally persist the winner to the tuning DB.")
     p.add_argument("--model", default="mlp",
                    help="zoo model name (training objectives) or mlp|lenet "

@@ -1,14 +1,12 @@
 """In-process evaluation harnesses for the autotuner (docs/perf.md
 "Autotuning").
 
-The tuner never grows its own measurement methodology: training candidates
-run through the same steady-state fused-scan harness bench.py's headline
-number uses (:func:`measure_scan_ips` LIVES here and bench.py imports it),
-extended with the dispatch-pipeline readback discipline ``Module.fit``
-actually runs (:func:`measure_pipelined_ips`); serving candidates run
-through the same open-loop arrival client loop as ``BENCH_SERVE``
-(:func:`open_loop_run`, also consumed by bench.py). One harness, so a
-tuned winner and a bench line always compare like with like.
+Training candidates run through the fused K-step scan with the
+dispatch-pipeline readback discipline ``Module.fit`` actually runs
+(:func:`measure_pipelined_ips`); serving candidates run through an
+open-loop arrival client loop (:func:`open_loop_run`). The timings rank
+the trials of one sweep against each other; they are not a benchmark
+(``benchmark/`` on the chip is).
 
 Each harness also owns its **static pruner**: a :mod:`mxnet_tpu.memcheck`
 pass over the candidate's compiled program set against the device budget
@@ -17,7 +15,6 @@ candidates cost one compile, never a run.
 """
 from __future__ import annotations
 
-import sys
 import threading
 import time
 
@@ -44,7 +41,7 @@ def prune_budget():
 def budget_findings(reports, set_name, budget=None):
     """The prune decision: ONLY the does-it-fit lints (``hbm-budget`` per
     program + ``resident-set`` over the candidate's program set). Quality
-    lints (donation-waste, temp-blowup) are bench/CI gates, not reasons to
+    lints (donation-waste, temp-blowup) are CI gates, not reasons to
     refuse to measure a config."""
     from .. import memcheck as _mc
     reports = list(reports)
@@ -74,44 +71,8 @@ def _measure_steps(k):
 
 
 # ---------------------------------------------------------------------------
-# shared measurement harnesses (bench.py imports these)
+# measurement loops of the trials
 # ---------------------------------------------------------------------------
-
-def measure_scan_ips(step, state, sb, batch, k, n_short, n_long, rounds=2,
-                     warmup=2):
-    """Steady-state img/s of the fused K-step scan: short/long differencing
-    (fixed per-readback latency cancels — same methodology as the headline
-    bench), best of ``rounds`` so one scheduler hiccup costs a retry, not
-    the measurement (a round whose timing inverts contributes nothing).
-    Shared by bench.py's BENCH_DP_DEVICES mode, the multichip CI gate and
-    the autotuner — ONE harness, so efficiency ratios and tuned winners
-    always compare like with like."""
-    st = [state]
-
-    def run(dispatches):
-        t0 = time.perf_counter()
-        for _ in range(dispatches):
-            st[0], _m = step.run_steps(st[0], sb)
-        np.asarray(st[0]["step"])  # readback ends the timed region
-        return time.perf_counter() - t0
-
-    run(warmup)  # warmup / compile
-    best = 0.0
-    for _ in range(rounds):
-        t_short = run(n_short)
-        t_long = run(n_long)
-        if t_long > t_short:
-            best = max(best, batch * k * (n_long - n_short)
-                       / (t_long - t_short))
-    if best == 0.0:
-        # every round's timing inverted: the 0.0 a caller is about to
-        # publish (or gate on) is a measurement failure, not a throughput
-        print("WARNING: measure_scan_ips produced no valid sample — "
-              "t_long <= t_short in all %d round(s); the host is too "
-              "loaded for n_short=%d/n_long=%d dispatches"
-              % (rounds, n_short, n_long), file=sys.stderr)
-    return best
-
 
 def measure_pipelined_ips(step, state, sb, batch, k, depth, n_short,
                           n_long, rounds=2, warmup=2):
@@ -120,8 +81,10 @@ def measure_pipelined_ips(step, state, sb, batch, k, depth, n_short,
     fetched, but only after ``depth`` further dispatches are enqueued
     (depth 0 = eager fetch after each dispatch) — exactly the host/device
     overlap ``fit(dispatch_pipeline=depth)`` runs, so the tuner measures
-    the knob it is tuning. Same short/long differencing + best-of-rounds
-    as :func:`measure_scan_ips`."""
+    the knob it is tuning. Short/long differencing (the fixed per-readback
+    latency cancels), best of ``rounds`` so one scheduler hiccup costs a
+    retry, not the measurement (a round whose timing inverts contributes
+    nothing; 0.0 means every round inverted)."""
     from collections import deque
     st = [state]
 
@@ -149,13 +112,11 @@ def measure_pipelined_ips(step, state, sb, batch, k, depth, n_short,
 
 
 def open_loop_run(infer, inputs, qps, nreq, nclients=4):
-    """Open-loop arrival client loop (docs/serving.md "Latency bench"):
-    request i is DUE at ``t0 + i/qps`` regardless of how long earlier
-    requests took — queueing delay shows up in the measured latency
+    """Open-loop arrival client loop: request i is DUE at ``t0 + i/qps``
+    regardless of how long earlier requests took — queueing delay shows up in the measured latency
     instead of silently lowering the offered load. ``infer`` is any
     blocking callable (``Batcher.infer``). Returns ``(latency-seconds
-    list, error-repr list, wall seconds)``. Shared by bench.py's
-    BENCH_SERVE mode and the autotuner's serving trials."""
+    list, error-repr list, wall seconds)``."""
     latencies = []
     errors = []
     lock = threading.Lock()
@@ -190,8 +151,8 @@ def open_loop_run(infer, inputs, qps, nreq, nclients=4):
 
 def serve_model(name):
     """Build ``(name, symbol, random params, per-example shape)`` for the
-    serving bench/tuner: deploy-realistic shapes, random weights (weights
-    don't affect latency). Shared by bench.py's serve/fleet modes."""
+    serving trials: deploy-realistic shapes, random weights (weights
+    don't affect latency)."""
     from .. import models
     if name == "lenet":
         sym = models.lenet(num_classes=10)
@@ -316,8 +277,8 @@ class TrainHarness(object):
                 "autotune trial produced no valid sample (timing inverted "
                 "in every round) for knobs %r" % (knobs,))
         # the token multiplier applies ONLY to the tokens objective: an
-        # img_per_sec sweep over a multi-dim-label model (ssd) must stay
-        # comparable with bench.py's img/s lines — one unit, one meaning
+        # img_per_sec sweep over a multi-dim-label model (ssd) counts
+        # images — one unit, one meaning
         if self.objective == "tokens_per_sec":
             return ips * self.tokens_per_sample
         return ips

@@ -1,4 +1,4 @@
-"""Search driver: closes the loop between bench and config.
+"""Search driver: closes the loop between measurement and config.
 
 Two strategies, both deterministic (docs/perf.md "Autotuning"):
 
@@ -102,7 +102,7 @@ class SearchDriver(object):
     """Deterministic bounded search over a knob space.
 
     ``evaluate(knobs) -> score`` (higher is better) runs the candidate
-    through a bench harness in-process; ``prune(knobs) -> findings`` (may
+    through a trial harness in-process; ``prune(knobs) -> findings`` (may
     be None) is the static memcheck pass — any returned finding rejects the
     candidate before execution. ``program_knobs`` names the knob subset
     that actually changes the compiled program set, so prune results are

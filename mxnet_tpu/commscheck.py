@@ -56,12 +56,9 @@ lint id               fires when
 The roofline is a MODEL, not a measurement: ring-algorithm wire bytes
 (all-reduce moves ``2(n-1)/n``x its payload, gather/scatter ``(n-1)/n``x,
 ppermute 1x), a per-device-kind link-bandwidth table, and the existing
-FLOPs lowering (``compiled.cost_analysis()`` — the same source bench.py's
-MFU uses; the XLA cost model counts a while body ONCE, so compute and
-per-iteration comm compare like with like). The multichip gate
-(``__graft_entry__.dryrun_multichip``) cross-checks the prediction against
-the measured 8-device efficiency and records both — a big gap is a note,
-not a failure.
+FLOPs lowering (``compiled.cost_analysis()``; the XLA cost model counts a
+while body ONCE, so compute and per-iteration comm compare like with
+like). No measurement on the chip has been held against it yet.
 
 CLI::
 
@@ -114,23 +111,8 @@ COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
 DEFAULT_LOOP_ALLOW = ("all-reduce", "collective-permute")
 
 # the per-device-kind capability rows live in ONE shared table
-# (mxnet_tpu.devspec) consumed by this roofline, bench MFU and
-# flopcheck; these module-level names are kept as backward-compatible
-# views (bench importing PEAK_FLOPS_PER_S from here keeps working)
-from .devspec import (DEVICE_SPECS, DEFAULT_SPEC,
-                      link_bandwidth, peak_flops)
-
-#: one-directional inter-chip link bandwidth per device kind (bytes/s) —
-#: a VIEW of :data:`mxnet_tpu.devspec.DEVICE_SPECS`
-LINK_BYTES_PER_S = {k: s.link_bytes_per_s for k, s in DEVICE_SPECS.items()}
-#: CPU / unknown backends: a nominal shared-memory "link" so predictions
-#: stay finite and deterministic on the forced-host CI mesh
-DEFAULT_LINK_BYTES_PER_S = DEFAULT_SPEC.link_bytes_per_s
-
-#: peak dense bf16 FLOP/s per device kind — the same devspec rows
-#: bench.py's MFU and flopcheck's roofline use
-PEAK_FLOPS_PER_S = {k: s.peak_flops_per_s for k, s in DEVICE_SPECS.items()}
-DEFAULT_PEAK_FLOPS_PER_S = DEFAULT_SPEC.peak_flops_per_s
+# (mxnet_tpu.devspec) consumed by this roofline and flopcheck's
+from .devspec import link_bandwidth, peak_flops
 
 
 def repl_bytes():
@@ -643,8 +625,8 @@ def struct_args(args):
 
 def analyze_compiled(compiled, name, mesh=None, loop_trips=1):
     """Build a :class:`CommsReport` from an ALREADY-compiled program
-    (``jax.stages.Compiled`` — e.g. the executable bench just measured).
-    Never executes anything."""
+    (``jax.stages.Compiled`` — e.g. the executable another analyzer just
+    read). Never executes anything."""
     import jax
     text_ok = True
     try:

@@ -57,7 +57,7 @@ class DevicePrefetcher(mxio.SuperBatchIter):
         """The training loop's wait for the next superbatch: queue-depth
         sample plus the stall charge — when this time is a large fraction
         of wall clock the run is input-bound, and ``stall_frac`` in the
-        bench JSON / Speedometer suffix says so directly. The wait also
+        Speedometer suffix says so directly. The wait also
         lands as a ``data_wait`` host span carrying the superbatch's
         correlation index (docs/observability.md)."""
         from ..obs import trace as _obs

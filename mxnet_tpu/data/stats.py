@@ -6,8 +6,8 @@ stacking, the H2D copy, or the training step itself. ``PipelineStats``
 makes every stage of ``mxnet_tpu.data`` measurable — read / decode /
 stack / H2D seconds, output-queue depth samples, and the consumer stall
 time (how long the training loop actually waited on data) — so
-"input-bound vs compute-bound" is a number in the bench JSON and the
-Speedometer line, not a guess (docs/perf.md "Device-fed input pipeline").
+"input-bound vs compute-bound" is a number in the Speedometer line,
+not a guess (docs/perf.md "Device-fed input pipeline").
 
 Mirroring follows ``io.DataHealth``: every per-pipeline instance chains
 into the process-global :data:`PIPELINE_STATS` aggregate.
@@ -84,7 +84,7 @@ class PipelineStats(object):
             return self._stages.get(stage, [0.0, 0])[0]
 
     def report(self):
-        """One flat dict (bench JSON / Speedometer / CI assertions)."""
+        """One flat dict (Speedometer / test assertions)."""
         with self._lock:
             elapsed = max(1e-9, time.perf_counter() - self._began)
             out = {}

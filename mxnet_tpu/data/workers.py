@@ -46,7 +46,7 @@ from .stats import PipelineStats, PIPELINE_STATS
 
 def default_num_workers():
     """Env default for decode/augment parallelism: ``MXTPU_DATA_WORKERS``
-    (0 = the legacy in-line decode path; the bench and CI gates set it
+    (0 = the legacy in-line decode path; the CI gates set it
     explicitly)."""
     v = os.environ.get("MXTPU_DATA_WORKERS")
     if v is None or v.strip() == "":

@@ -1,12 +1,11 @@
 """devspec: ONE per-device-kind capability table for every roofline.
 
-Three analyzers and a bench used to carry their own copies of the TPU
-spec sheet: bench.py's MFU peak table, commscheck's ``PEAK_FLOPS_PER_S``
-and ICI ``LINK_BYTES_PER_S``, and (new) flopcheck's HBM-bandwidth
-column. A spec number that lives in two places drifts — one table gets a
-new chip generation, the other silently keeps pricing it as unknown —
-so the three columns live HERE and everybody reads them through the same
-prefix-matched lookup:
+commscheck's wire model (peak FLOP/s, ICI link bandwidth) and
+flopcheck's roofline (peak FLOP/s, HBM bandwidth) price programs from the
+same spec sheet. A spec number that lives in two places drifts — one
+table gets a new chip generation, the other silently keeps pricing it as
+unknown — so the three columns live HERE and both read them through the
+same prefix-matched lookup:
 
 ==============  ===========  ===========  ===========
 device kind     peak bf16    HBM          ICI link
@@ -21,8 +20,7 @@ TPU v6e/lite    918e12       1.64e12      9.0e10
 ==============  ===========  ===========  ===========
 
 (public spec-sheet figures, order-of-magnitude — every consumer's
-roofline is a MODEL and the multichip gate cross-checks predictions
-against measurement). CPU / unknown kinds fall back to nominal figures
+roofline is a MODEL). CPU / unknown kinds fall back to nominal figures
 so the forced-host CI mesh stays finite and deterministic; the
 ``peak_source`` field says which case you got (``"spec"`` vs
 ``"nominal-fallback"``) so an MFU/roofline number is never silently a

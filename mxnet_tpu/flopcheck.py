@@ -64,10 +64,9 @@ lint id               fires when
 
 The roofline is a MODEL, not a measurement: structural FLOP counts,
 spec-sheet peak/bandwidth rows (:mod:`mxnet_tpu.devspec` — the SAME
-table bench.py's MFU and commscheck's wire model read), zero overlap
-assumed. bench.py emits ``predicted_mfu`` next to measured MFU and the
-multichip gate records the prediction gap — a big gap is a note, never
-a failure.
+table commscheck's wire model reads), zero overlap assumed. What holds
+it against the chip: ``chip_smoke.py``'s decode legs check this
+inventory of the chip's own step executable against the cache.
 
 CLI::
 

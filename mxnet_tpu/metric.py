@@ -799,7 +799,7 @@ def update_from_device_sums(metric, sums):
 
     A spec-carrying ``sums`` (the packed-accumulator protocol) folds by
     slot name through its metric's own ``fold``; the spec-less legacy
-    layout (``[loss, correct, nsamp]`` — bench/TrainStep callers) still
+    layout (``[loss, correct, nsamp]`` — direct TrainStep callers) still
     folds acc/ce directly. Folds go through Python float/int regardless
     of what the sums object yields: under NEP 50 a stray np.float32 in
     ``0.0 + x`` DEMOTES the host accumulator to float32 for the rest of

@@ -511,8 +511,7 @@ class CheckpointManager(object):
         #: (written/skipped/errors) stay readable here after the run
         self.last_async_writer = None
         #: cumulative seconds ``save`` spent on the CALLER's thread (full
-        #: write when sync; snapshot+submit when async) — bench.py's
-        #: host-overhead mode reads this for host_stall_frac
+        #: write when sync; snapshot+submit when async)
         self.save_time = 0.0
         d = os.path.dirname(os.path.abspath(self.prefix))
         if d and not os.path.isdir(d):

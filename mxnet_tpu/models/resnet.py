@@ -1,6 +1,6 @@
-"""ResNet v1.5/v2 (He et al. 2015/2016) — the north-star benchmark model
-(ref: example/image-classification/symbols/resnet.py behavior; BASELINE.md
-ResNet-50/152 rows).
+"""ResNet v1.5/v2 (He et al. 2015/2016) — the benchmark's ``resnet50``
+configuration (ref: example/image-classification/symbols/resnet.py
+behavior).
 
 Standard depth configs: 18/34 (basic block), 50/101/152 (bottleneck).
 ``image_shape`` picks the ImageNet stem (7x7/s2 + maxpool) or the CIFAR stem

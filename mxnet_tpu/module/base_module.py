@@ -41,8 +41,7 @@ class _DispatchPipeline(object):
     eager, only later in wall-clock). Depth 0 is eager mode.
 
     ``host_stall`` accumulates the seconds actually spent blocked in
-    readbacks — the Speedometer pipeline suffix and bench.py's
-    ``host_stall_frac`` read it.
+    readbacks — the Speedometer pipeline suffix reads it.
     """
 
     # __weakref__: the Speedometer's windowed-suffix store holds its
@@ -277,8 +276,8 @@ class BaseModule(object):
         if checkpoint_prefix is not None:
             from ..model import CheckpointManager
             if isinstance(checkpoint_prefix, CheckpointManager):
-                # callers (bench.py host-overhead mode, tests) may pass a
-                # preconfigured manager to read its counters afterwards
+                # callers (tests) may pass a preconfigured manager to
+                # read its counters afterwards
                 ckpt_mgr = checkpoint_prefix
             else:
                 ckpt_mgr = CheckpointManager(checkpoint_prefix,

@@ -326,8 +326,7 @@ class Window(object):
         return out
 
 
-#: THE process-global registry (the one bench.py exports and the flight
-#: recorder snapshots)
+#: THE process-global registry (the one the flight recorder snapshots)
 REGISTRY = Registry()
 
 _default_views_done = False

@@ -16,7 +16,7 @@ Three layers over the standalone :class:`~mxnet_tpu.predictor.Predictor`:
   :class:`DeepseekV3Arch` keeps latent rows and holds a share of a routed
   expert layer) is donated device state stepped by one compiled decode
   body; sequences join and leave mid-stream. The
-  production decode path layers four separately-benchable legs on top,
+  production decode path layers four separate legs on top,
   each behind a knob (docs/serving.md):
 
   - **in-graph sampling** (temperature/top-k/top-p, per-slot seed

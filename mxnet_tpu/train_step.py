@@ -4,8 +4,9 @@ This is the TPU-native analog of everything the reference engine pipeline did
 per batch — RunOps over bulked segments, gradient reduce, updater
 (ref: call stack SURVEY.md §3.1) — collapsed into a single donated XLA
 computation. Module uses the lazy executor path for API fidelity; this module
-is the performance path used by bench.py, the multichip dry-run, and any
-training loop that wants max throughput.
+is the performance path: ``Module.fit(steps_per_dispatch=k)`` (what
+``benchmark/entries/`` and ``chip_smoke.py`` drive), the autotuner's trials,
+the multichip dry-run, and any training loop that wants max throughput.
 
 Sharding: pass a Mesh plus optional per-parameter PartitionSpecs. Batch
 arrays are sharded along ``data``; parameters default to replicated
@@ -216,8 +217,8 @@ def _stable_sig(sig):
 def _default_slot_sums(outs, labels, batch_size):
     """The legacy packed layout ``(ce_loss, top1_correct, num_samples)``
     as a slot tuple — what ``run_steps`` accumulates when no
-    packed-accumulator spec is passed (TrainStep API users, bench.py, the
-    multichip gate). Bit-for-bit the pre-protocol scan accumulation."""
+    packed-accumulator spec is passed (TrainStep API users, the autotuner,
+    the multichip gate). Bit-for-bit the pre-protocol scan accumulation."""
     zero = jnp.zeros((), jnp.float32)
     loss, correct = _metric_step_sums(outs, labels, zero)
     return (loss, correct, jnp.float32(batch_size))

@@ -300,18 +300,14 @@ def run_elastic(mx, rank, nproc):
     if rank == victim:
         faults.inject("kv.worker_die", nth=30, kind="die")
 
-    import time
-
     mod = mx.mod.Module(net())
     train = ElasticIter(x_all[rank::nproc],
                         labels_all[rank::nproc].astype(np.float32),
                         batch_size=batch_size, shuffle=False)
-    t0 = time.time()
     mod.fit(train, num_epoch=num_epoch, kvstore="dist_sync",
             optimizer="sgd", optimizer_params=opt_params,
             initializer=mx.initializer.Xavier(),
             checkpoint_prefix=prefix, checkpoint_keep=50)
-    fit_s = time.time() - t0
     assert rank != victim, "victim outlived its SIGKILL"
 
     # survivors: exactly one re-form, membership shrank to N-1
@@ -363,13 +359,10 @@ def run_elastic(mx, rank, nproc):
                 == arg_live[name].asnumpy().tobytes()), \
             "rank %d: resumed %r differs from live state" % (rank, name)
 
-    # machine-readable line for tools/dist_gate.py: collective wall time
-    # + post-reform membership (the dataset is partitioned, so aggregate
-    # throughput = num_epoch * full dataset / max survivor fit_s)
-    print("RANK-%d-ELASTIC-STATS fit_s=%.3f epochs=%d samples=%d "
-          "reforms=%d workers=%d"
-          % (rank, fit_s, num_epoch, len(x_all), kv.reforms,
-             kv.num_workers), flush=True)
+    # machine-readable line for tools/dist_gate.py: the post-reform
+    # membership
+    print("RANK-%d-ELASTIC-STATS reforms=%d workers=%d"
+          % (rank, kv.reforms, kv.num_workers), flush=True)
     _survivor_sync(rank, nproc, victim, "elastic")
 
 

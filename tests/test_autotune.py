@@ -15,11 +15,9 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import autotune, models
-from mxnet_tpu.autotune.benchcfg import benv
 from mxnet_tpu.autotune.db import SCHEMA_VERSION, TuningDB
 from mxnet_tpu.autotune.harness import TrainHarness
 from mxnet_tpu.autotune.search import NEG_INF, Knob, SearchDriver
-from mxnet_tpu.base import MXNetError
 from mxnet_tpu.tracecheck import ZOO
 
 
@@ -259,9 +257,8 @@ def test_mismatch_note_survives_objective_preference_loop(tmp_path,
 
 def test_img_per_sec_score_not_inflated_by_label_tokens():
     """An img_per_sec sweep over a multi-dim-label model must report
-    samples/sec, not samples*tokens/sec — DB scores stay comparable with
-    bench.py's img/s lines; the token multiplier is the tokens_per_sec
-    objective's alone."""
+    samples/sec, not samples*tokens/sec — one unit, one meaning; the token
+    multiplier is the tokens_per_sec objective's alone."""
     h_img = TrainHarness(model="transformer", batch=4,
                          objective="img_per_sec")
     h_tok = TrainHarness(model="transformer", batch=4,
@@ -476,26 +473,6 @@ def test_serving_engine_bucket_precedence(tmp_path, monkeypatch):
                                     buckets=(1, 3))
     assert eng_arg.buckets == (1, 3)
     assert eng_arg._autotuned is None
-
-
-# -- benchcfg ---------------------------------------------------------------
-
-def test_benv_types_defaults_and_junk(monkeypatch):
-    assert benv("BENCH_BATCH") == 128
-    monkeypatch.setenv("BENCH_BATCH", "64")
-    assert benv("BENCH_BATCH") == 64
-    monkeypatch.setenv("BENCH_BATCH", "12q")
-    with pytest.raises(MXNetError, match="BENCH_BATCH"):
-        benv("BENCH_BATCH")
-    monkeypatch.setenv("BENCH_SERVE_QPS", "not-a-number")
-    with pytest.raises(MXNetError, match="BENCH_SERVE_QPS"):
-        benv("BENCH_SERVE_QPS")
-    # flags: unset -> default, off spellings -> False
-    assert benv("BENCH_FLEET_DRAIN") is True
-    monkeypatch.setenv("BENCH_FLEET_DRAIN", "0")
-    assert benv("BENCH_FLEET_DRAIN") is False
-    with pytest.raises(MXNetError, match="declared bench knob"):
-        benv("BENCH_NOT_A_KNOB")
 
 
 # -- end-to-end sweep (tiny) ------------------------------------------------

@@ -481,6 +481,37 @@ def test_fit_bitwise_parity_across_worker_counts(tmp_path):
         np.testing.assert_array_equal(p1[n], p4[n], err_msg=n)
 
 
+def test_fit_through_tier_programs_lint_clean_and_health_reported(tmp_path):
+    """A real-JPEG epoch through reader -> 2 decode workers -> superbatch
+    stack -> prefetch-to-device -> fused K-step scan: the programs the
+    fit registered audit at zero unsuppressed tracecheck findings, the
+    iterator's own DataHealth reports its counters (all zero: nothing
+    went wrong), and PipelineStats saw every stage."""
+    from mxnet_tpu import tracecheck
+    rec = _make_rec(str(tmp_path / "a.rec"), n=64)
+    mx.random.seed(0)
+    it = _record_iter(rec, 2, shuffle=True, seed=5)
+    mod = mx.mod.Module(_small_convnet())
+    mod.fit(it, num_epoch=1, steps_per_dispatch=2,
+            optimizer_params={"learning_rate": 0.1, "momentum": 0.9})
+    assert mod._fused._jit_scan               # the scan path took the data
+    health = it.data_health.report()
+    assert {k: health[k] for k in ("retries", "skipped_records",
+                                   "failures")} == {
+        "retries": 0, "skipped_records": 0, "failures": 0}
+    stats = it.data_stats.report()
+    for stage in ("read_s", "decode_s", "stack_s", "h2d_s"):
+        assert stats.get(stage, 0) > 0, (stage, stats)
+    it.close()
+    prefix = mod._fused._watcher.name + "/"
+    names = [r.name for r in tracecheck.registered_programs()
+             if r.name.startswith(prefix)]
+    assert any("scan[" in n for n in names), names
+    findings = tracecheck.unsuppressed(
+        tracecheck.check_registered(match=prefix))
+    assert [f.format() for f in findings] == []
+
+
 def test_fit_resume_through_pool_bitwise(tmp_path):
     """Kill-free resume equivalence: train epoch 0 with checkpoints, then
     a FRESH process-state (new module + new iterator) resumes at epoch 1

@@ -328,19 +328,29 @@ def test_spec_decode_token_identical_weak_draft():
         spec.close()
 
 
-@pytest.mark.slow
-def test_spec_program_set_audits_clean():
+@pytest.mark.parametrize("leg", ["int8", "spec"])
+def test_decode_program_set_audits_clean(leg):
+    """Every leg of the production decode path keeps its whole program
+    set lint-clean (tracecheck + the memory lints): the quantized loop's
+    step, and the speculative loop's verify and draft bodies beside the
+    prefix helpers. The default loop's set is held by
+    tests/test_serving.py::test_decode_memory_report_cache_aliased."""
     params = _lm_params()
-    spec = _loop(params, prefix_cache=True, spec_k=2,
-                 draft_params=_lm_params(seed=8, num_layers=1),
-                 draft_num_layers=1)
+    if leg == "int8":
+        loop = _loop(params, quantize="int8", prefix_cache=False)
+        want = ("step[",)
+    else:
+        loop = _loop(params, prefix_cache=True, spec_k=2,
+                     draft_params=_lm_params(seed=8, num_layers=1),
+                     draft_num_layers=1)
+        want = ("verify[", "draft[")
     try:
-        names = sorted(spec.memory_report())
-        assert any("verify[" in n for n in names)
-        assert any("draft[" in n for n in names)
-        assert [f.format() for f in spec.check(memory=True)] == []
+        names = sorted(loop.memory_report())
+        for w in want:
+            assert any(w in n for n in names), (w, names)
+        assert [f.format() for f in loop.check(memory=True)] == []
     finally:
-        spec.close()
+        loop.close()
 
 
 def test_spec_k_without_draft_raises():

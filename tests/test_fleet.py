@@ -430,6 +430,62 @@ def test_fleet_join_warms_and_enters_rotation():
         router.close()
 
 
+def test_fleet_drain_and_warm_rejoin_under_load_sheds_nothing():
+    """Two replicas take mixed-class requests from three clients while
+    r0 is drained and its engine rejoins warm as r0b: every request
+    completes equal to the engine's own answer, nothing fails or is shed
+    in either class, and ``router.check`` finds the in-rotation replicas'
+    program sets (tracecheck + memory + comms lints) clean, the shared
+    engine audited once."""
+    b0, b1 = _batcher(), _batcher()
+    r0_engine = b0.engine
+    router = serving.FleetRouter({"r0": b0, "r1": b1})
+    x = _x(1)
+    ref = _engine().infer({"data": x})[0]
+    outs, errs = [], []
+    begun = threading.Event()
+
+    def client(cid):
+        try:
+            for i in range(20):
+                cls = "batch" if (cid + i) % 4 == 0 else "interactive"
+                outs.append(router.infer({"data": x}, priority=cls,
+                                         deadline_ms=20000)[0])
+                begun.set()
+        except Exception as e:   # surface in the main thread
+            errs.append(e)
+
+    try:
+        threads = [threading.Thread(target=client, args=(c,))
+                   for c in range(3)]
+        for t in threads:
+            t.start()
+        assert begun.wait(20.0)
+        assert len(outs) < 60              # the drain lands mid-load
+        rep = router.drain("r0", timeout=30.0)
+        assert rep["state"] == serving.fleet.RETIRED
+        router.join("r0b", lambda: serving.Batcher(r0_engine,
+                                                   max_latency_ms=1.0))
+        for t in threads:
+            t.join(60.0)
+        assert not any(t.is_alive() for t in threads)
+        assert not errs, errs
+        assert len(outs) == 60
+        for o in outs:
+            assert np.array_equal(o, ref)
+        fleet = router.report()["fleet"]
+        assert fleet["shed"] == 0 and fleet["errors"] == 0
+        assert fleet["expired"] == 0 and fleet["dropped"] == 0
+        assert sum(router.class_health[c].requests
+                   for c in serving.FLEET_CLASSES) == 60
+        assert sorted(router.replica_names()) == ["r0b", "r1"]
+        findings = [f for f in router.check(memory=True, comms=True)
+                    if not f.suppressed]
+        assert findings == [], [f.format() for f in findings]
+    finally:
+        router.close()
+
+
 def test_fleet_join_rejects_mismatched_signature():
     router = serving.FleetRouter([_batcher()])
     try:

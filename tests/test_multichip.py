@@ -285,6 +285,25 @@ def test_module_global_batch_scale_defaults_to_one():
     assert mod._global_batch_scale() == 1
 
 
+# -- the driver's entry points ----------------------------------------------
+
+def test_graft_entry_traces_and_holds_no_reader_of_a_rate():
+    """``entry()`` hands the driver a jittable ResNet-50 forward (traced
+    here by shape only: nothing runs), and the module that holds the
+    multichip gate measures nothing — a rate comes from ``benchmark/`` on
+    the chip, never from forced-host CPU devices."""
+    import inspect
+    import __graft_entry__ as g
+    fn, args = g.entry()
+    out = jax.eval_shape(fn, *args)
+    assert out.shape == (8, 1000) and out.dtype == jnp.float32
+    src = inspect.getsource(g)
+    for gone in ("measure_scan_ips", "MIN_EFF", "img_per_sec",
+                 "scaling_efficiency", "perf_counter", "MULTICHIP_r"):
+        assert gone not in src, gone
+    assert callable(g.dryrun_multichip)
+
+
 # -- the real thing: SIGKILL an 8-device run and resume it ------------------
 
 @pytest.mark.slow

@@ -158,9 +158,56 @@ def test_engine_stale_executables_fall_back(tmp_path):
 
 def test_engine_tracecheck_clean():
     """The serving bucket programs gate at zero findings, like the train
-    step programs (ci/serve.sh runs the same audit)."""
+    step programs."""
     eng = _engine(buckets=(2, 4))
     findings = eng.check()
+    assert [f.format() for f in findings] == []
+
+
+@pytest.mark.parametrize("model,quantize", [("mlp", "none"),
+                                            ("lenet", "none"),
+                                            ("mlp", "int8")])
+def test_served_program_set_stays_lint_clean_under_load(model, quantize):
+    """A zoo model (dense and conv; float32 and int8 weights) behind the
+    batcher with concurrent clients: every request completes, and the
+    programs the engine REGISTERED while serving audit at zero
+    unsuppressed findings."""
+    import threading
+    from mxnet_tpu import tracecheck
+    from mxnet_tpu.autotune.harness import serve_model
+    _name, sym, params, shape = serve_model(model)
+    eng = serving.ServingEngine(sym, params, {"data": shape},
+                                buckets=(1, 8), quantize=quantize)
+    b = serving.Batcher(eng, max_latency_ms=5.0)
+    x1 = np.random.RandomState(1).rand(1, *shape).astype(np.float32)
+    ref = eng.infer({"data": x1})[0]
+    outs, errs = [], []
+
+    def client():
+        try:
+            for _ in range(8):
+                outs.append(b.infer({"data": x1})[0])
+        except Exception as e:   # surface in the main thread
+            errs.append(e)
+
+    threads = [threading.Thread(target=client) for _ in range(3)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60.0)
+    b.close()
+    assert not errs and len(outs) == 24
+    assert b.health.requests == 24 and b.health.errors == 0
+    # co-riders share a larger bucket's program: XLA:CPU picks its dot
+    # kernel by shape, so equal to rounding, not bitwise
+    for o in outs:
+        np.testing.assert_allclose(o, ref, rtol=1e-5, atol=1e-6)
+    assert eng.quant_mode == quantize
+    served = [r.name for r in tracecheck.registered_programs()
+              if r.name.startswith(eng.name + "/")]
+    assert len(served) == 2, served            # one program a bucket
+    findings = tracecheck.unsuppressed(
+        tracecheck.check_registered(match=eng.name + "/"))
     assert [f.format() for f in findings] == []
 
 

@@ -6,7 +6,7 @@ per-buffer table showing which tensors account for the step's HBM traffic
 (the reference's analog is the memory section of docs/how_to/perf.md plus
 the memonger study; here the source of truth is XLA itself).
 
-Method: lower+compile the exact train step bench.py times, then walk the
+Method: lower+compile the ResNet train step, then walk the
 optimized HLO ENTRY computation. Every top-level instruction materializes
 its output in HBM and reads its operands from HBM (internals of a fusion
 are VMEM/register-resident and never touch HBM), so
