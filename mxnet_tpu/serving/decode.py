@@ -18,9 +18,18 @@ dispatch substrate PR 1/PR 4 built for training:
   (its prompt is teacher-forced through the same decode body, one token
   per step, overwriting whatever the retired occupant left in the cache —
   positions past ``pos`` are masked, so stale rows are unreachable);
-* the host only supplies next tokens and reads back the SAMPLED token
+* the host only supplies prompt tokens and reads back the SAMPLED token
   ids (one (slots,) int32 readback per step — smaller than the logits
-  readback it replaced).
+  readback it replaced);
+* the loop runs ONE STEP AHEAD of that readback: a generating slot's next
+  input token stays on the device (``state["tok"]``, selected where the
+  host sends :data:`FED_BACK`), and everything else the next dispatch
+  needs is a count the host has (``pos``, the prompt left to feed,
+  ``max_new_tokens``, ``max_len``), so step n+1 is fed and dispatched
+  while the device computes step n, and step n's tokens are read and
+  handed to their requests after that (docs/serving.md "What a step is").
+  Only ``eos_id`` needs a token's value: it is learned one step late, and
+  the slot-step dispatched meanwhile is dropped (``trash_slot_steps``).
 
 The four legs, each behind a knob (docs/serving.md has the full table):
 
@@ -285,17 +294,33 @@ class OptArch(Architecture):
         return token_pass
 
 
+#: the members of the donated state that are the loop's own, beside the
+#: architecture's: each slot's seed, and the token the decode body last
+#: sampled for it (only in a state that body steps)
+LOOP_STATE = ("seed", "tok")
+#: what the host puts in ``tokens`` for a slot whose input token is the one
+#: the device sampled for it the step before
+FED_BACK = -1
+
+
 def _model_state(state):
-    """The donated state less the slots' seeds: what the architecture's
-    token pass reads and writes."""
-    return {k: v for k, v in state.items() if k != "seed"}
+    """The donated state less the loop's own members: what the
+    architecture's token pass reads and writes."""
+    return {k: v for k, v in state.items() if k not in LOOP_STATE}
 
 
 def _build_decode_fn(arch, mesh=None):
     """The single-token decode body: one position per slot, sampled
     in-graph. Returns ``(state, next_tokens)`` — the host reads back one
     (slots,) int32 vector, never the logits. ``live`` is the eighth
-    per-slot array of an architecture that asks for it."""
+    per-slot array of an architecture that asks for it.
+
+    The sampled tokens also STAY ON THE DEVICE, as ``state["tok"]``: a
+    slot whose ``tokens`` entry is negative (:data:`FED_BACK`) takes the
+    token this body sampled for it the step before, so the loop can
+    dispatch a generating slot's next step before it has read the last
+    one back. A host token (a prompt position, a newly seated slot, every
+    speculative pass) is taken as it is."""
     token_pass = arch.build_token_pass(mesh=mesh)
 
     def decode_fn(state, params, tokens, pos, temp, top_k, top_p,
@@ -303,12 +328,13 @@ def _build_decode_fn(arch, mesh=None):
         import jax
         import jax.numpy as jnp
         seeds = jnp.where(reseed, fresh_seed, state["seed"])
+        tokens = jnp.where(tokens < 0, state["tok"], tokens)
         p = arch.load(params)
         new, logits = token_pass(_model_state(state), p, tokens, pos, *live)
         with jax.named_scope("sample"):
             u = position_uniforms(seeds, pos)
             nxt = sample_rows(logits, u, temp, top_k, top_p)
-        return dict(new, seed=seeds), nxt
+        return dict(new, seed=seeds, tok=nxt), nxt
 
     return decode_fn
 
@@ -430,15 +456,25 @@ class GenerateFuture(Settleable):
 
 
 class _Slot(object):
-    __slots__ = ("fut", "pending", "pos", "next_token", "emitted",
+    """One seated request, as far as the loop has DISPATCHED it: ``pos``,
+    ``pending`` and ``sent`` move when a step is dispatched (they are
+    counts the host knows), ``emitted`` when that step's tokens are read
+    back, one step later."""
+
+    __slots__ = ("fut", "pending", "pos", "next_token", "emitted", "sent",
                  "reseed", "producing")
 
     def __init__(self, fut):
         self.fut = fut
         self.pending = list(fut.prompt)   # prompt tokens still to feed
         self.pos = 0                      # next cache write position
+        #: the next input token where the host has it (a prompt's), else
+        #: :data:`FED_BACK`: the one the device sampled the step before
         self.next_token = self.pending.pop(0)
         self.emitted = []
+        self.sent = 0                     # tokens its dispatched steps emit:
+        #                                   ahead of emitted by the step in
+        #                                   flight
         self.reseed = True                # seed lands in-state next step
         self.producing = None             # (key, L): harvest prefix at L
 
@@ -651,6 +687,9 @@ class DecodeLoop(object):
         self._closed = False
         self.dead = None
         self._steps = 0   # decode-step ordinal for the host trace
+        #: the step dispatched and not read back yet: ``[its tokens on the
+        #: device, [(slot index, _Slot, emits, last)]]``, or None
+        self._inflight = None
         self._cpu_ns = None   # loop thread's CPU clock at the last traced
         #                       step's end (None: the next has no cpu_us)
         self._wake = threading.Event()
@@ -747,7 +786,8 @@ class DecodeLoop(object):
         """The donated device state of one model: the arrays the
         architecture's ``slot_state`` names, each ``(layers, slots, rows,
         width)`` (for :class:`OptArch` a K and a V cache of ``heads *
-        head_dim`` float32), its counters, and the slots' seeds. The
+        head_dim`` float32), its counters, the slots' seeds and, where the
+        decode body steps it, the token that body last sampled a slot. The
         ``width`` is the minor dimension (128 lanes at a time) and rows
         the second minor, a multiple of the sublanes a tile of the
         narrowest dtype holds (8 of four bytes, 16 of two): the chip's
@@ -767,6 +807,9 @@ class DecodeLoop(object):
         state.update({k: jnp.zeros(shape, np.int32)
                       for k, shape in arch.counters().items()})
         state["seed"] = jnp.zeros((self.slots,), np.uint32)
+        if arch is not self._arch or not self.spec_k:
+            # stepped by the decode body (the verify body hands no token on)
+            state["tok"] = jnp.zeros((self.slots,), np.int32)
         if self._mesh is not None:
             P = jax.sharding.PartitionSpec
             slot_sh = jax.sharding.NamedSharding(
@@ -778,7 +821,7 @@ class DecodeLoop(object):
 
     def _prefix_programs(self, compile_one, jax, which, arch, state_s,
                          slot_s):
-        names = sorted(set(state_s) - set(arch.counters()) - {"seed"})
+        names = sorted(set(state_s) - set(arch.counters()) - set(LOOP_STATE))
         sh = None
         if self._mesh is not None:
             part = arch.slot_partition()
@@ -1002,6 +1045,12 @@ class DecodeLoop(object):
                 slot.fut.fail(exc)
                 self._slots[i] = None
                 shed += 1
+        # and the requests whose last step is in flight: they left their
+        # slots when it was dispatched, and their tokens will not be read
+        rec, self._inflight = self._inflight, None
+        for _, slot, _, last in rec[1] if rec is not None else ():
+            if last and slot.fut.fail(exc):
+                shed += 1
         while True:
             try:
                 fut = self._join_q.get_nowait()
@@ -1087,6 +1136,11 @@ class DecodeLoop(object):
                                   time.perf_counter() - t_admit,
                                   step=self._steps + 1, joined=joined)
                 if all(s is None for s in self._slots):
+                    if self._inflight is not None:
+                        # every slot was dispatched for the last time:
+                        # nothing to run ahead of, so settle that step
+                        self._drain()
+                        continue
                     self._wake.wait(timeout=0.05)
                     self._wake.clear()
                     continue
@@ -1118,13 +1172,14 @@ class DecodeLoop(object):
         with _obs.span("decode_step", step=self._steps,
                        reqs=[s.fut.rid for s in occ]) as sp:
             pos = [s.pos for s in occ]
-            had = [len(s.emitted) for s in occ]
+            had = [s.sent for s in occ]
             body(sp)
-            # a retired slot keeps its last pos and emitted: aligned with
-            # reqs, where each request started, the positions it committed
-            # and the tokens it was handed in this step
+            # of the step DISPATCHED in this span, aligned with reqs (a
+            # slot that left keeps its counts): where each request stood,
+            # the positions the step commits and the tokens it emits. A
+            # slot-step the loop knows to be trash by now has 0 and 0
             sp.set(pos=pos, n=[s.pos - p for s, p in zip(occ, pos)],
-                   emit=[len(s.emitted) - h for s, h in zip(occ, had)])
+                   emit=[s.sent - h for s, h in zip(occ, had)])
             # the thread's CPU clock is a system call (20-40 us each in a
             # process with JAX's threads; chip run, PR 27): read once a
             # step, and for the trace file only, not for the recorder
@@ -1174,10 +1229,19 @@ class DecodeLoop(object):
         return arrs
 
     def _step_inner(self, sp):
-        """One step. ``sp`` is the step's span (or the no-op): each phase
-        of the host round trip ends in a lap of it (docs/observability.md
-        "Span catalogue"): ``decode_gather``, ``decode_h2d``,
-        ``decode_dispatch``, ``decode_readback``, ``decode_commit``."""
+        """One step, one step AHEAD of its readback: step n is fed and
+        dispatched, THEN step n-1's tokens are read back and committed,
+        so the device runs n while the host does that and gets n+1 ready.
+        What n's dispatch needs of n-1 the host has as a count (``pos``,
+        ``pending``, ``max_new``, ``max_len``) or the device kept
+        (:data:`FED_BACK`); only ``eos_id`` needs the token's value, and
+        is learned a step late (:meth:`_commit`).
+
+        ``sp`` is the step's span (or the no-op): each phase ends in a lap
+        of it (docs/observability.md "Span catalogue"): ``decode_gather``,
+        ``decode_h2d``, ``decode_dispatch`` of step n, then
+        ``decode_readback``, ``decode_commit`` of step n-1 (of length 0
+        where no step is in flight: the first after an empty loop)."""
         from .. import faults as _faults
         a = self._gather_sampling()
         sp.lap("decode_gather")
@@ -1186,44 +1250,108 @@ class DecodeLoop(object):
                          a["top_p"], a["fresh"], a["reseed"]]
                         + [a["live"]] * self._arch.wants_live)
         sp.lap("decode_h2d")
+        before = self._inflight
         with self._state_lock:
             self._state, toks = self._step_c(self._state, self._params,
                                              *dev)
+        # this step's copy to the host starts behind IT: asked for only
+        # when it is read, after the next dispatch, it would wait for that
+        toks.copy_to_host_async()
         # the argument buffers are released here, while the device works,
         # and the token buffer with the readback: left to this function's
         # end, eight releases fall into the device's idle time after the
         # commit, in no phase (chip run, PR 27)
         del dev
-        sp.lap("decode_dispatch")
-        host_toks = np.asarray(toks)   # the one per-step readback
+        rows, prompt = self._schedule()
+        self._inflight = [toks, rows]
         del toks
-        sp.lap("decode_readback")
-        now = time.perf_counter()
-        emitted = prompt = 0
+        self._count_step(sp, a, 0, prompt, ahead=int(before is not None))
+        sp.lap("decode_dispatch")
+        self._commit(sp, before)
+
+    def _schedule(self):
+        """Advance every seated slot over the step just dispatched, by
+        count: ``[(slot index, slot, emits, last)]`` and how many of them
+        fed a prompt position. A slot dispatched for the last time
+        (``max_new`` tokens sent, or the cache full) LEAVES here, free for
+        :meth:`_admit`; its request settles when the step is read back."""
+        rows, prompt = [], 0
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
             slot.pos += 1
-            if slot.pending:
+            emits = not slot.pending
+            if emits:
+                slot.sent += 1
+                slot.next_token = FED_BACK
+            else:
                 # prompt still feeding: next input is teacher-forced
                 slot.next_token = slot.pending.pop(0)
                 prompt += 1
+            last = (emits and slot.sent >= slot.fut.max_new) \
+                or slot.pos >= self.max_len
+            rows.append((i, slot, emits, last))
+            if last:
+                self._slots[i] = None
             else:
+                self._maybe_harvest(i)
+        return rows, prompt
+
+    def _commit(self, sp, rec):
+        """Read back and commit ``rec``, the step dispatched before the
+        one now in flight (or the last one, from :meth:`_drain`): hand
+        each emitting slot its token, stamped now, when the host has it,
+        and settle the requests this was the last step of.
+
+        A token equal to ``eos_id`` ends its request here, ONE STEP LATE:
+        the slot is in the step in flight already. That slot-step is
+        trash: its token is dropped when it is read (``trash_slot_steps``),
+        and its cache write went to the slot's own next row, which the
+        next occupant rewrites before any of its queries attends it."""
+        if rec is None:
+            sp.lap("decode_readback")
+            sp.lap("decode_commit")
+            return
+        host_toks = np.asarray(rec[0])   # the one per-step readback
+        rec[0] = None      # its buffer is released inside this phase
+        rows = rec[1]
+        sp.lap("decode_readback")
+        now = time.perf_counter()
+        emitted = trash = 0
+        leaving = []
+        for i, slot, emits, last in rows:
+            if slot.fut.done():     # its eos was read a step ago
+                trash += 1
+                continue
+            if emits:
                 tok = int(host_toks[i])
                 slot.emitted.append(tok)
                 slot.fut.token_times.append(now)
                 emitted += 1
-                slot.next_token = tok
-                if (len(slot.emitted) >= slot.fut.max_new
-                        or (self.eos_id is not None and tok == self.eos_id)):
-                    self._retire(i)
-                    continue
-            if slot.pos >= self.max_len:
-                self._retire(i)
+                last = last or (self.eos_id is not None
+                                and tok == self.eos_id)
+            if not last:
                 continue
-            self._maybe_harvest(i)
-        self._count_step(sp, a, emitted, prompt)
+            leaving.append((i, slot))
+            if self._slots[i] is slot:
+                # eos: still seated, so in the step in flight, which now
+                # commits no position and emits no token of its own
+                self._slots[i] = None
+                slot.pos -= 1
+                slot.sent -= 1
+        self.health.record_tokens(emitted, trash)
+        for i, slot in leaving:
+            self._settle(i, slot)
         sp.lap("decode_commit")
+
+    def _drain(self):
+        """Settle the step in flight with no step to run ahead of it: the
+        loop goes idle (or seats whoever was waiting for these tokens)."""
+        t0 = time.perf_counter()
+        rec, self._inflight = self._inflight, None
+        self._commit(_obs.NOOP, rec)
+        _obs.complete("loop_drain", time.perf_counter() - t0,
+                      step=self._steps)
 
     def _step_spec(self, sp):
         """One draft-K-then-verify round: K+1 cheap draft passes chain
@@ -1293,6 +1421,7 @@ class DecodeLoop(object):
                 else:
                     tok = int(s[i, j])
                     slot.emitted.append(tok)
+                    slot.sent += 1
                     slot.fut.token_times.append(now)
                     emitted += 1
                     nxt = tok
@@ -1327,21 +1456,26 @@ class DecodeLoop(object):
         self._count_step(sp, a, emitted, prompt)
         sp.lap("decode_commit")
 
-    def _count_step(self, sp, a, emitted, prompt):
-        """The step's counts, for the health report and its span. Rows
-        whose ``temp`` is above 0 sample: any at all and the step's
-        program took the sampler's branch (sampling.py, rule 3)."""
+    def _count_step(self, sp, a, emitted, prompt, ahead=0):
+        """The dispatched step's counts, for the health report and its
+        span. Rows whose ``temp`` is above 0 sample: any at all and the
+        step's program took the sampler's branch (sampling.py, rule 3).
+        ``ahead``: 1 where the step before was still unread."""
         sampled = int((a["temp"] > 0).sum())
-        sp.set(sampled=sampled)
-        self.health.record_decode_step(emitted, prompt, sampled)
+        sp.set(sampled=sampled, ahead=ahead)
+        self.health.record_decode_step(emitted, prompt, sampled, ahead)
 
     def _retire(self, i):
         slot = self._slots[i]
         self._slots[i] = None
+        self._settle(i, slot)
+
+    def _settle(self, i, slot):
+        """Hand a request that left slot ``i`` its tokens."""
+        self.health.record_retire()
         slot.fut.fulfill(list(slot.emitted))
         _obs.instant("decode_retire", req=slot.fut.rid, slot=i,
                      emitted=len(slot.emitted))
-        self.health.record_retire()
 
     # ------------------------------------------------------------------
     def counter_totals(self):

@@ -33,6 +33,11 @@ class ServingHealth(object):
         self.sampled_steps = 0     # decode steps in which some row sampled
         #                            (temperature > 0): the steps that paid
         #                            for the in-graph sampler
+        self.steps_ahead = 0       # decode steps dispatched while the step
+        #                            before was still unread (run-ahead)
+        self.trash_slot_steps = 0  # slot-steps dispatched for a request
+        #                            whose eos was learned a step late:
+        #                            their tokens were dropped
         self.joined = 0            # sequences that entered a decode slot
         self.retired = 0           # sequences that left a decode slot
         self.requeued = 0          # requests moved off a dead/draining
@@ -92,19 +97,31 @@ class ServingHealth(object):
     def record_error(self, err=None):
         self._bump("errors", err=err)
 
-    def record_decode_step(self, emitted=0, prompt=0, sampled=0):
-        """One decode step (or speculative round) that handed ``emitted``
-        tokens to its requests, processed ``prompt`` positions that
-        emitted none and fed ``sampled`` rows with a temperature above 0
-        (any at all and the step ran the sampler): the four counts move
-        under one lock."""
+    def record_decode_step(self, emitted=0, prompt=0, sampled=0, ahead=0):
+        """One decode step (or speculative round) DISPATCHED: it handed
+        ``emitted`` tokens to its requests (a run-ahead step hands its
+        tokens over a step later, through :meth:`record_tokens`),
+        processed ``prompt`` positions that emitted none and fed
+        ``sampled`` rows with a temperature above 0 (any at all and the
+        step ran the sampler); ``ahead`` is 1 where the step before was
+        still unread. The counts move under one lock."""
         with self._lock:
             self.decode_steps += 1
             self.tokens_emitted += int(emitted)
             self.prompt_positions += int(prompt)
             self.sampled_steps += int(sampled > 0)
+            self.steps_ahead += int(ahead)
         if self._parent is not None:
-            self._parent.record_decode_step(emitted, prompt, sampled)
+            self._parent.record_decode_step(emitted, prompt, sampled, ahead)
+
+    def record_tokens(self, emitted, trash=0):
+        """A run-ahead step read back: ``emitted`` tokens handed to their
+        requests, ``trash`` slot-steps whose token was dropped."""
+        with self._lock:
+            self.tokens_emitted += int(emitted)
+            self.trash_slot_steps += int(trash)
+        if self._parent is not None:
+            self._parent.record_tokens(emitted, trash)
 
     def record_join(self):
         self._bump("joined")
@@ -156,6 +173,8 @@ class ServingHealth(object):
                 "tokens_emitted": self.tokens_emitted,
                 "prompt_positions": self.prompt_positions,
                 "sampled_steps": self.sampled_steps,
+                "steps_ahead": self.steps_ahead,
+                "trash_slot_steps": self.trash_slot_steps,
                 "joined": self.joined,
                 "retired": self.retired, "requeued": self.requeued,
                 "prefix_hits": self.prefix_hits,
@@ -175,7 +194,8 @@ class ServingHealth(object):
             self.padded = self.expired = self.dropped = 0
             self.shed = self.errors = self.decode_steps = 0
             self.tokens_emitted = self.prompt_positions = 0
-            self.sampled_steps = 0
+            self.sampled_steps = self.steps_ahead = 0
+            self.trash_slot_steps = 0
             self.joined = self.retired = self.requeued = 0
             self.prefix_hits = self.prefix_prefills = 0
             self.spec_rounds = self.spec_drafted = self.spec_accepted = 0

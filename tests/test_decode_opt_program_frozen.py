@@ -7,6 +7,14 @@ builders lower to the same text, argument for argument, for float32,
 bfloat16 and int8 parameter trees, for the single-token body and the
 speculative window.
 
+RE-FROZEN at PR 33, ``decode_fn`` ONLY: the single-token body takes a
+slot's input token from the device where the host marks it (a negative id
+selects ``state["tok"]``, the token the body sampled for that slot the step
+before, which it now also leaves in the state), so the loop can dispatch a
+step before it has read the last one back. Nothing else of the body moved;
+``_build_token_pass`` and ``verify_fn`` below are PR 28's, untouched (the
+verify body's state carries no ``tok``).
+
 The frozen bodies call the LIVE ``sample_rows``: a change that stays
 inside ``sample_rows`` (PR 30's ``lax.cond`` around the sampler) reaches
 both sides alike and this file passes unedited; ``sample_rows`` itself is
@@ -158,6 +166,7 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
     return token_pass
 
 
+# (re-frozen at PR 33 with the token select: the two lines marked so)
 def _build_decode_fn(num_layers, num_heads, mesh=None):
     """The single-token decode body: one position per slot, sampled
     in-graph. Returns ``(state, next_tokens)`` — the host reads back one
@@ -169,12 +178,13 @@ def _build_decode_fn(num_layers, num_heads, mesh=None):
         import jax
         import jax.numpy as jnp
         seeds = jnp.where(reseed, fresh_seed, state["seed"])
+        tokens = jnp.where(tokens < 0, state["tok"], tokens)      # PR 33
         p = dequant_tree(params)
         ck, cv, logits = token_pass(state["k"], state["v"], p, tokens, pos)
         with jax.named_scope("sample"):
             u = position_uniforms(seeds, pos)
             nxt = sample_rows(logits, u, temp, top_k, top_p)
-        return {"k": ck, "v": cv, "seed": seeds}, nxt
+        return {"k": ck, "v": cv, "seed": seeds, "tok": nxt}, nxt  # PR 33
 
     return decode_fn
 
@@ -210,7 +220,7 @@ def _build_verify_fn(num_layers, num_heads, window, mesh=None):
 # ---- end of the frozen copy ------------------------------------------------
 
 
-def _structs(mode):
+def _structs(mode, fed_back=False):
     params = quantize_tree(chip_smoke.lm_params(VOCAB, EMBED, HEADS, LAYERS,
                                                 ROWS, seed=1), mode)
     sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
@@ -219,6 +229,8 @@ def _structs(mode):
     state = {"k": cache, "v": cache,
              "seed": jax.ShapeDtypeStruct((SLOTS,), np.uint32)}
     n = (SLOTS,)
+    if fed_back:        # the decode body's state; the verify body's has none
+        state["tok"] = jax.ShapeDtypeStruct(n, np.int32)
     samp = [jax.ShapeDtypeStruct(n, d) for d in
             (np.int32, np.int32, np.float32, np.int32, np.float32,
              np.uint32, np.bool_)]
@@ -227,7 +239,7 @@ def _structs(mode):
 
 @pytest.mark.parametrize("mode", ["none", "bf16", "int8"])
 def test_the_step_program_is_the_parents(mode):
-    state, params, samp = _structs(mode)
+    state, params, samp = _structs(mode, fed_back=True)
     old = jax.jit(_build_decode_fn(LAYERS, HEADS), donate_argnums=(0,))
     new = jax.jit(decode._build_decode_fn(decode.OptArch(LAYERS, HEADS)),
                   donate_argnums=(0,))
@@ -249,12 +261,12 @@ def test_the_verify_program_is_the_parents(window):
 
 def test_the_loop_builds_its_step_from_those_builders():
     """The loop's own state and feed are the frozen program's arguments:
-    K, V and seeds, seven per-slot arrays, no eighth."""
+    K, V, seeds and the fed-back token, seven per-slot arrays, no eighth."""
     params = chip_smoke.lm_params(VOCAB, EMBED, HEADS, LAYERS, ROWS, seed=1)
     loop = decode.DecodeLoop(params, LAYERS, HEADS, ROWS, slots=SLOTS,
                              prefix_cache=False, spec_k=0)
     try:
-        assert sorted(loop._state) == ["k", "seed", "v"]
+        assert sorted(loop._state) == ["k", "seed", "tok", "v"]
         assert isinstance(loop._arch, decode.OptArch)
         assert not loop._arch.wants_live and not loop._arch.counters()
         (_, structs, donate), = loop._programs.values()

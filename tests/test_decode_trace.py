@@ -2,10 +2,17 @@
 catalogue", docs/serving.md "Reading a request's token times"): the six
 leaves of the step's host round trip (five laps of ``decode_step`` and
 ``decode_admit``), what each ``decode_step`` span
-says it processed (``pos``/``n``/``emit``/``sampled``/``cpu_us``), the counters an
+says it processed (``pos``/``n``/``emit``/``sampled``/``ahead``/``cpu_us``), the counters an
 operator reads with tracing off (``tokens_emitted``,
-``prompt_positions``, ``sampled_steps``), ``GenerateFuture.token_times``, the profiler's
+``prompt_positions``, ``sampled_steps``, ``steps_ahead``), ``GenerateFuture.token_times``, the profiler's
 clock sync, and the stable scope names inside the compiled programs.
+
+The loop runs one step ahead of its readback (PR 33): of the five laps of
+span n, ``decode_gather``, ``decode_h2d`` and ``decode_dispatch`` belong to
+step n, the step the span's arguments describe, and ``decode_readback`` and
+``decode_commit`` to step n-1, whose tokens are handed over there (of
+length 0 where no step was in flight). The last step of a busy stretch is
+read back in a ``loop_drain`` span, outside any ``decode_step``.
 """
 import json
 import os
@@ -82,7 +89,7 @@ def _serve(loop, requests, **kw):
     after = SERVING_HEALTH.report()
     grown = {k: after[k] - before[k]
              for k in ("decode_steps", "tokens_emitted", "prompt_positions",
-                       "sampled_steps")}
+                       "sampled_steps", "steps_ahead")}
     # the laps written out as child spans, as the trace file has them
     evs = obs_trace.expand_laps(obs_trace.events())
     return futs, [e for e in evs if e["ph"] == "X"], grown
@@ -193,6 +200,9 @@ def test_nested_spans_nest_in_whole_microseconds():
 # ---------------------------------------------------------------------------
 
 def test_every_step_holds_its_five_leaves_in_order(plain_run):
+    """Gather, h2d and dispatch of the span's own step, then readback and
+    commit of the step before it: five laps, in that order, in EVERY span
+    (the two last empty where no step was in flight)."""
     _, _, evs, _, raw = plain_run
     assert obs_trace.nest_check(evs) == []
     steps = _steps(evs)
@@ -241,6 +251,9 @@ def test_admit_is_the_leaf_outside_the_step(plain_run):
 # ---------------------------------------------------------------------------
 
 def test_emitted_tokens_agree_across_spans_counters_and_futures(plain_run):
+    """``pos``/``n``/``emit``/``sampled`` describe the step DISPATCHED in
+    the span; ``tokens_emitted`` counts a step's tokens when they are read
+    back, a span later. Over a finished run the sums agree."""
     loop, futs, evs, grown, _ = plain_run
     steps = _steps(evs)
     for st in steps:
@@ -266,7 +279,29 @@ def test_emitted_tokens_agree_across_spans_counters_and_futures(plain_run):
     assert h["sampled_steps"] == 0
     assert grown == {"decode_steps": len(steps), "tokens_emitted": returned,
                      "prompt_positions": positions - emitted,
-                     "sampled_steps": 0}
+                     "sampled_steps": 0,
+                     "steps_ahead": h["steps_ahead"]}
+
+
+def test_ahead_marks_the_steps_dispatched_before_the_last_was_read(plain_run):
+    """``ahead`` is 1 where the step before was still unread at dispatch:
+    every step but a start from an empty loop. ``steps_ahead`` counts
+    them; a span that ran ahead holds step n-1's readback, one that did
+    not holds none, and the busy stretch's last step is read in a
+    ``loop_drain`` span that no reader takes for a step."""
+    loop, _, evs, _, _ = plain_run
+    steps = _steps(evs)
+    ahead = [st["args"]["ahead"] for st in steps]
+    assert ahead[0] == 0 and set(ahead) == {0, 1}
+    h = loop.health.report()
+    assert h["steps_ahead"] == sum(ahead) and h["trash_slot_steps"] == 0
+    drains = [e for e in evs if e["name"] == "loop_drain"]
+    assert 1 <= len(drains) and ahead.count(0) <= len(drains)
+    assert all("reqs" not in e["args"] for e in drains)
+    assert "loop_drain" not in stepgaps.LEAVES
+    # the last drain follows the last step: nothing is left in flight
+    assert max(e["ts"] for e in drains) >= steps[-1]["ts"] + steps[-1]["dur"]
+    assert loop._inflight is None
 
 
 @pytest.mark.parametrize("spec_k", [0, 2])
@@ -330,6 +365,8 @@ def test_plain_traffic_agrees_with_the_inference_from_outside(plain_run):
 
 
 def test_token_times_stamp_every_emitted_token(plain_run):
+    """Stamped at the readback, when the host really has the token: one
+    clock read per step read back, so as many stamps as emitting steps."""
     _, futs, evs, _, _ = plain_run
     for f in futs:
         assert len(f.token_times) == len(f.tokens)
@@ -467,10 +504,14 @@ def test_new_counters_reach_the_registry_and_prometheus():
     assert "serving_health.tokens_emitted" in snap
     assert "serving_health.prompt_positions" in snap
     assert "serving_health.sampled_steps" in snap
+    assert "serving_health.steps_ahead" in snap
+    assert "serving_health.trash_slot_steps" in snap
     prom = obs.REGISTRY.to_prometheus()
     assert "serving_health_tokens_emitted" in prom
     assert "serving_health_prompt_positions" in prom
     assert "serving_health_sampled_steps" in prom
+    assert "serving_health_steps_ahead" in prom
+    assert "serving_health_trash_slot_steps" in prom
 
 
 # ---------------------------------------------------------------------------

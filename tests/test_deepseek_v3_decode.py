@@ -18,6 +18,7 @@ by path: it imports nothing of ``mxnet_tpu``):
 import importlib.util
 import math
 import os
+import time
 
 import numpy as np
 import pytest
@@ -393,6 +394,55 @@ def test_routing_counters_equal_a_host_recount(ref, params, served):
     assert health["moe_pairs_here"] == int(want.sum())
     assert health["moe_busiest_expert"] == int(want.max())
     assert health["prompt_positions"] + health["tokens_emitted"] == positions
+
+
+@pytest.fixture(scope="module")
+def eos_served(params, served):
+    """The same requests with ``eos_id`` a token that ends some of the
+    plain streams early: the loop runs one step ahead of its readback, so
+    it learns of an ``eos`` one step late (docs/serving.md)."""
+    outs = served["outs"]
+    for eos in range(TINY["vocab_size"]):
+        cut = [o.index(eos) + 1 if eos in o else len(o) for o in outs]
+        late = sum(c < len(o) for c, o in zip(cut, outs))
+        if 1 <= late < len(outs):
+            break
+    else:
+        raise AssertionError("no token ends some plain stream early")
+    loop = _loop(params, eos_id=eos)
+    futs = [loop.generate(p, 12) for p in PROMPTS]
+    got = [f.result(timeout=120) for f in futs]
+    # a trash slot-step is counted when it is read back, after its request
+    # has its tokens: wait for the loop to drain the step in flight
+    deadline = time.monotonic() + 10.0
+    while loop._inflight is not None and time.monotonic() < deadline:
+        time.sleep(0.001)
+    health, counts = loop.health.report(), loop.counter_totals()
+    loop.close()
+    return {"eos": eos, "cut": cut, "late": late, "outs": got,
+            "health": health, "counts": counts}
+
+
+@pytest.mark.parametrize("i", range(len(PROMPTS)))
+def test_run_ahead_delivers_nothing_after_eos(served, eos_served, i):
+    """And the next occupant of a slot whose last slot-step was trash (its
+    latent row written one past the ``eos``) decodes the plain stream."""
+    assert eos_served["outs"][i] == served["outs"][i][:eos_served["cut"][i]]
+
+
+def test_a_trash_slot_step_is_counted_as_routed_work(eos_served):
+    """``live`` gates the device's routing counters and a slot-step that
+    turns out to be trash was dispatched live: the counters hold every
+    position processed AND the late slot-steps."""
+    h, late = eos_served["health"], eos_served["late"]
+    assert h["trash_slot_steps"] == late
+    assert h["tokens_emitted"] == sum(len(o) for o in eos_served["outs"])
+    positions = sum(len(p) + len(o) - 1
+                    for p, o in zip(PROMPTS, eos_served["outs"]))
+    assert h["prompt_positions"] + h["tokens_emitted"] == positions
+    assert eos_served["counts"]["moe_routed"].tolist() \
+        == [4 * (positions + late)] * 2
+    assert h["steps_ahead"] > 0 and h["joined"] == h["retired"] == 4
 
 
 def test_health_counts_increments_and_mirrors_them(params):

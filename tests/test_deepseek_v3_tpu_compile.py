@@ -56,7 +56,8 @@ def _compile(one_chip, latent_width=None):
     params = {k: s(v, jnp.bfloat16) for k, v in arch.param_shapes().items()}
     state = {"latent": s((arch.num_layers, SLOTS, ROWS, arch.latent_width),
                          jnp.bfloat16),
-             "seed": s((SLOTS,), np.uint32)}
+             "seed": s((SLOTS,), np.uint32),
+             "tok": s((SLOTS,), np.int32)}
     state.update({k: s(v, np.int32) for k, v in arch.counters().items()})
     feed = [s((SLOTS,), d) for d in (np.int32, np.int32, np.float32,
                                      np.int32, np.float32, np.uint32,

@@ -108,6 +108,7 @@ def _program(case):
         build = lambda: decode._build_verify_fn(arch, window)
     else:
         build = lambda: decode._build_decode_fn(arch)
+        state["tok"] = np.zeros(SLOTS, np.int32)   # the fed-back token
     return build, state, params, [np.ones(SLOTS, np.bool_)] * arch.wants_live
 
 
