@@ -1,0 +1,14 @@
+"""Builds the system under test for ``entry: decode_loop`` configurations of
+the LFM2 hybrid block (``model_type: lfm2_moe``): ``serving.DecodeLoop`` with
+``arch=serving.Lfm2Arch(cfg)``, which reads the configuration's own
+``config.json`` keys, with only the programs the cells use (this
+architecture's recurrent state refuses the prefix cache and speculation)."""
+
+
+def build(cfg, params, contexts=None):
+    from mxnet_tpu import serving
+    serve = cfg["serve"]
+    return serving.DecodeLoop(
+        params, max_len=int(serve["max_len"]), slots=int(serve["slots"]),
+        quantize=serve["quantize"], prefix_cache=False, spec_k=0,
+        contexts=contexts, arch=serving.Lfm2Arch(cfg))

@@ -14,7 +14,9 @@ Three layers over the standalone :class:`~mxnet_tpu.predictor.Predictor`:
   model: the slots' state (K and V rows, or whatever arrays the model's
   :class:`Architecture` names: :class:`OptArch` is the default,
   :class:`DeepseekV3Arch` keeps latent rows and holds a share of a routed
-  expert layer) is donated device state stepped by one compiled decode
+  expert layer, :class:`Lfm2Arch` keeps K and V rows over its attention
+  layers and a two-row conv state over the others) is donated device state
+  stepped by one compiled decode
   body; sequences join and leave mid-stream. The
   production decode path layers four separate legs on top,
   each behind a knob (docs/serving.md):
@@ -46,13 +48,14 @@ from .batcher import (Batcher, ServingError, ServingDeadlineError,
 from .arch import Architecture
 from .decode import DecodeLoop, GenerateFuture, OptArch
 from .deepseek_v3 import DeepseekV3Arch
+from .lfm2 import Lfm2Arch
 from .fleet import FleetRouter, FleetRequest, CLASSES as FLEET_CLASSES
 from .quantize import (QUANT_MODES, check_quality, quality_report,
                        quantize_tree, tree_bytes)
 
 __all__ = [
     "ServingEngine", "Batcher", "DecodeLoop", "GenerateFuture",
-    "Architecture", "OptArch", "DeepseekV3Arch",
+    "Architecture", "OptArch", "DeepseekV3Arch", "Lfm2Arch",
     "FleetRouter", "FleetRequest", "FLEET_CLASSES",
     "ServingHealth", "SERVING_HEALTH", "default_buckets",
     "ServingError", "ServingDeadlineError", "ServingOverloadedError",

@@ -8,15 +8,52 @@ arrays hold a slot's state, and how ONE position per slot goes through its
 layers. The default is :class:`~mxnet_tpu.serving.decode.OptArch`
 (``models/transformer.py``: K and V rows);
 :class:`~mxnet_tpu.serving.deepseek_v3.DeepseekV3Arch` keeps latent rows
-and routing counters. This is the serving third of the per-layer-type state
-protocol (ROADMAP D1): a slot's state is whatever arrays ``slot_state``
-names, each ``(layers, slots, rows, width)``, and the loop allocates,
-donates, extracts and implants them without knowing what they hold.
+and routing counters; :class:`~mxnet_tpu.serving.lfm2.Lfm2Arch` keeps K and
+V rows over its attention layers and a conv state two rows deep over the
+others. This is the serving third of the per-layer-type state protocol
+(ROADMAP D1): a slot's state is whatever arrays ``slot_state`` names, each
+a :class:`SlotArray` that says over how many layers it runs, how deep it is
+and how wide, and the loop allocates, donates, extracts and implants them
+without knowing what they hold.
 """
 from __future__ import annotations
 
+import collections
+
+import numpy as np
+
 from ..base import MXNetError
 from .quantize import dequant_tree
+
+#: ``SlotArray.rows`` of an array that keeps one row a POSITION: the loop
+#: makes it ``max_len`` deep (one more under speculation)
+PER_POSITION = None
+
+
+class SlotArray(collections.namedtuple("SlotArray",
+                                       "layers rows width dtype")):
+    """One array of the slots' state, allocated ``(layers, slots, depth,
+    width)``: ``layers`` of the model's layers keep it (an architecture
+    with layers of several kinds numbers each kind's own), ``rows`` is
+    :data:`PER_POSITION` for a cache addressed by position or a fixed
+    number for a state that never grows, ``width`` is the minor dimension
+    (whole 128-lane tiles, or the chip pads) and ``dtype`` what is stored."""
+
+    __slots__ = ()
+
+    def depth(self, positions):
+        """Rows allocated where a per-position array holds ``positions``:
+        its rows lie on the chip's sublanes (8 of four bytes, 16 of two)
+        and are allocated in whole tiles, so the chip pads nothing; the
+        surplus rows are trash rows no live query attends. A fixed number
+        of rows is allocated as it is: the chip stores a few rows in tiles
+        that many rows deep, and padded to 16 it stores the padding too and
+        the step program re-lays the whole array out on entry and on exit
+        (PERF.md, PR 34; ``tests/test_deepseek_v3_tpu_compile.py``)."""
+        if self.rows is not PER_POSITION:
+            return int(self.rows)
+        tile = 8 * 4 // np.dtype(self.dtype).itemsize
+        return -(-int(positions) // tile) * tile
 
 
 class Architecture(object):
@@ -31,22 +68,34 @@ class Architecture(object):
     #: it processes needs it
     wants_live = False
 
-    def validate(self, host_params, max_len, mesh, quant_mode):
-        """Raise :class:`MXNetError` for parameters, a cache length, a mesh
-        or a quantization this architecture cannot serve; return the
-        vocabulary size."""
+    def validate(self, host_params, max_len, mesh, quant_mode, spec_k=0,
+                 prefix_cache=False):
+        """Raise :class:`MXNetError` for parameters, a cache length, a
+        mesh, a quantization, speculation (``spec_k`` > 0; the draft model
+        is asked too) or a prefix cache this architecture cannot serve;
+        return the vocabulary size. Speculation writes rows past ``pos``
+        and abandons them, and the prefix cache implants a slab at a
+        shorter length than it was cut at: both are sound only for state
+        addressed by position."""
         raise NotImplementedError
 
     def slot_state(self, host_params, quant_mode):
-        """``{name: (width, dtype)}`` of the arrays that hold the slots'
-        state. The loop allocates each as ``(num_layers, slots, rows,
-        width)``: the prefix cache copies ``[:, slot]`` of every one out
-        and in, and a speculative window runs its rows past ``max_len``."""
+        """``{name: SlotArray}`` of the arrays that hold the slots' state.
+        The loop allocates each as ``(layers, slots, depth, width)``; the
+        prefix cache copies ``[:, slot]`` of every one out and in, and a
+        speculative window runs a per-position array's rows past
+        ``max_len``."""
         raise NotImplementedError
 
     def counters(self):
         """``{name: shape}`` of int32 arrays in the donated state that the
         token pass adds to and the loop reads rarely (never once a step)."""
+        return {}
+
+    def compiler_options(self, platform):
+        """Options this architecture's step program is compiled with on
+        ``platform`` (``jax.default_backend()``): ``{}`` leaves the
+        compiler to itself, as every architecture but one does."""
         return {}
 
     def slot_partition(self):
@@ -64,8 +113,9 @@ class Architecture(object):
         """``token_pass(state, params, tokens, pos[, live]) -> (state,
         logits)``: ONE position per slot through every layer. ``state``
         holds the arrays of ``slot_state`` and ``counters``; the pass
-        writes position ``pos`` of each slot (clamped to the last row: rows
-        past ``max_len`` are trash rows no live query attends) and returns
+        writes position ``pos`` of each slot into the per-position arrays
+        (clamped to the last row: rows past ``max_len`` are trash rows no
+        live query attends), steps a fixed-depth state once, and returns
         float32 logits ``(slots, vocab)``. The single-token body runs it
         once, the speculative verify body unrolls it over the window."""
         raise NotImplementedError

@@ -1,0 +1,152 @@
+"""What more than one :class:`~mxnet_tpu.serving.arch.Architecture`'s token
+pass is made of (docs/serving.md "Architectures"): RMSNorm, the product
+with a stored weight, SwiGLU, and the routed-expert layer that is told
+which experts it holds: the sigmoid router with its selection bias, the
+held experts' dense product, and the device counters of both.
+:mod:`.deepseek_v3` and :mod:`.lfm2` import them from here, so a change to
+the expert layer reaches both models.
+
+**The share.** The layer routes every row over ALL ``router_width``
+experts, adds only the terms of the ``held`` experts from ``first`` on for
+the (row, choice) pairs that chose them, and THAT partial sum goes on. On
+one chip the layer runs without its exchange; nothing stands in for the
+absent chips. The held experts are computed densely (every row through
+every held expert, the unchosen weighted 0): at decode batch sizes an
+expert's product is bound by reading its weights, which a step does once
+either way.
+
+**Precision.** The operands of a weight product are the STORED dtype
+(bfloat16 under ``quantize="bf16"``: no float32 copy of a weight is ever
+made), accumulation float32; norms, the router (its product at
+``HIGHEST``) and the gating products float32.
+
+**Counters**, on the device in the donated state: ``moe_served`` (expert
+layer, held expert): (token, choice) pairs served here; ``moe_routed``
+(expert layer): pairs routed in all. Only live slots count.
+"""
+from __future__ import annotations
+
+import collections
+
+import numpy as np
+
+
+def rms_norm(x, gamma, eps):
+    """``x * rsqrt(mean(x^2) + eps) * gamma`` in float32."""
+    import jax
+    import jax.numpy as jnp
+    x = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(var + jnp.float32(eps)) \
+        * gamma.astype(jnp.float32)
+
+
+def linear(x, w):
+    """``x @ w.T`` with the operands in the WEIGHT's stored dtype and
+    float32 accumulation."""
+    import jax.numpy as jnp
+    return jnp.einsum("se,fe->sf", x.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)
+
+
+def swiglu(x, gate, up, down):
+    import jax
+    return linear(jax.nn.silu(linear(x, gate)) * linear(x, up), down)
+
+
+def route(f, weight, bias, top_k, scaling, normalise=True, eps=1e-20):
+    """``(indices, weights)`` ``(rows, top_k)`` of the experts each row
+    chooses among ALL the router's experts, in float32: chosen by
+    ``sigmoid(W f) + bias``, weighted by the sigmoid alone over the chosen
+    ones' sum (+ ``eps``: the model's own, DeepSeek-V3's where none is
+    given), times ``scaling``."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    logits = jnp.einsum("se,xe->sx", f.astype(f32), weight.astype(f32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + bias.astype(f32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if normalise:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + f32(eps))
+    return idx, w * f32(scaling)
+
+
+def held_weights(idx, w, first, held):
+    """``(hit, dense)``: ``hit`` (rows, top_k, held) marks the (row,
+    choice) pairs that chose held expert ``first + j``; ``dense`` (rows,
+    held) is each row's weight for each held expert, 0 where unchosen."""
+    import jax.numpy as jnp
+    hit = (idx - first)[:, :, None] == jnp.arange(held)[None, None, :]
+    return hit, jnp.sum(jnp.where(hit, w[:, :, None], jnp.float32(0.0)),
+                        axis=1)
+
+
+def held_experts(f, dense, gate, up, down):
+    """The held experts' part of the layer's output: every row through
+    every held expert (stacked ``(held, ...)`` weights, each read once),
+    summed with ``dense`` (rows, held) in float32."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    fb = f.astype(gate.dtype)
+    g = jnp.einsum("se,xfe->xsf", fb, gate, preferred_element_type=f32)
+    u = jnp.einsum("se,xfe->xsf", fb, up, preferred_element_type=f32)
+    act = (jax.nn.silu(g) * u).astype(down.dtype)
+    y = jnp.einsum("xsf,xef->xse", act, down, preferred_element_type=f32)
+    return jnp.sum(y * dense.T[:, :, None], axis=0)
+
+
+class ExpertShare(collections.namedtuple(
+        "ExpertShare", "top_k scaling normalise eps first held norm_eps")):
+    """What an expert layer is told: the router's ``top_k``, ``scaling``,
+    whether it normalises and with which ``eps``; the ``held`` experts from
+    index ``first`` on; the ``norm_eps`` of the RMSNorm before it."""
+
+    __slots__ = ()
+
+
+def routed_share(x, p, share, live, nlive, counts, m):
+    """Expert layer ``m`` (of the expert layers) on this share: ``(f, y,
+    counts)`` with ``f`` the normed input, ``y`` the held experts' terms
+    (rows, hidden) float32 and ``counts`` = ``(moe_served, moe_routed)``
+    brought up to date for the ``live`` rows. ``p(name)`` gives the layer's
+    ``ffn_norm_gamma``, ``router_{weight,bias}`` and
+    ``experts_{gate,up,down}_weight``. The scopes are what a device trace
+    is searched for: the same in every layer and model."""
+    import jax
+    import jax.numpy as jnp
+    served, routed = counts
+    with jax.named_scope("layer/moe/router"):
+        f = rms_norm(x, p("ffn_norm_gamma"), share.norm_eps)
+        idx, w = route(f, p("router_weight"), p("router_bias"), share.top_k,
+                       share.scaling, share.normalise, share.eps)
+        hit, dense = held_weights(idx, w, share.first, share.held)
+        here = jnp.sum(hit & live[:, None, None], axis=(0, 1),
+                       dtype=jnp.int32)
+        served = served.at[m].add(here)
+        routed = routed.at[m].add(nlive * jnp.int32(share.top_k))
+    with jax.named_scope("layer/moe/experts"):
+        y = held_experts(f, dense, p("experts_gate_weight"),
+                         p("experts_up_weight"), p("experts_down_weight"))
+    return f, y, (served, routed)
+
+
+def moe_counters(expert_layers, held):
+    """The ``counters()`` of an architecture with ``expert_layers`` expert
+    layers of ``held`` experts each (none: no counters)."""
+    if not expert_layers:
+        return {}
+    return {"moe_served": (expert_layers, held),
+            "moe_routed": (expert_layers,)}
+
+
+def record_moe(health, counts, before):
+    """The ``record_counters`` of such an architecture: what the two
+    counters grew by since ``before`` into ``health.record_moe``."""
+    def total(name):
+        return int(np.sum(counts[name], dtype=np.int64)) \
+            - int(np.sum(before.get(name, 0), dtype=np.int64))
+    health.record_moe(total("moe_routed"), total("moe_served"),
+                      int(np.max(counts["moe_served"])))

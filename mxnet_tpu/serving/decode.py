@@ -88,7 +88,7 @@ import numpy as np
 
 from ..base import MXNetError, env_int, env_str
 from ..obs import trace as _obs
-from .arch import Architecture
+from .arch import PER_POSITION, Architecture, SlotArray
 from .batcher import REQUEST_IDS, ServingClosedError, Settleable
 from .health import ServingHealth, SERVING_HEALTH
 from .quantize import (is_quantized_leaf, quantize_array, quantize_tree,
@@ -242,7 +242,8 @@ class OptArch(Architecture):
         self.num_heads = int(num_heads)
         self.who = who
 
-    def validate(self, host_params, max_len, mesh, quant_mode):
+    def validate(self, host_params, max_len, mesh, quant_mode, spec_k=0,
+                 prefix_cache=False):
         draft = self.who != "params"
         for need in ("tok_embed_weight", "pos_embed_weight",
                      "final_ln_gamma", "lm_head_weight") \
@@ -276,8 +277,10 @@ class OptArch(Architecture):
         return int(vocab)
 
     def slot_state(self, host_params, quant_mode):
-        width = int(host_params["tok_embed_weight"].shape[1])
-        return {"k": (width, np.float32), "v": (width, np.float32)}
+        rows = SlotArray(self.num_layers, PER_POSITION,
+                         int(host_params["tok_embed_weight"].shape[1]),
+                         np.float32)
+        return {"k": rows, "v": rows}
 
     def slot_partition(self):
         from ..parallel.mesh import AXIS_MODEL
@@ -369,9 +372,12 @@ def _build_verify_fn(arch, window, mesh=None):
 
 def _build_extract_fn(names, slab_sharding=None):
     """Prefix harvest: copy one slot's full slab, every slot-state array
-    of ``names`` less its slot axis, out of the state (non-donating — the
-    state keeps serving). Garbage rows past the prefix length ride along;
-    every consumer rewrites them before any query can attend them."""
+    of ``names`` less its slot axis (each with its own layers, depth and
+    width), out of the state (non-donating — the state keeps serving).
+    Garbage rows past the prefix length ride along; every consumer
+    rewrites them before any query can attend them, which holds for
+    arrays addressed by position only (an architecture with any other
+    state refuses the prefix cache in ``validate``)."""
     def extract_fn(state, slot):
         slab = {k: state[k][:, slot] for k in names}
         if slab_sharding is not None:
@@ -556,10 +562,10 @@ class DecodeLoop(object):
             else env_str("MXTPU_SERVE_QUANT", "none"))
         host_params = {k: _host_leaf(v, self.quant_mode)
                        for k, v in params.items()}
-        self.vocab_size = int(arch.validate(host_params, self.max_len,
-                                            self._mesh, self.quant_mode))
-
         self._resolve_knobs(host_params, prefix_cache, spec_k, draft_params)
+        self.vocab_size = int(arch.validate(
+            host_params, self.max_len, self._mesh, self.quant_mode,
+            spec_k=self.spec_k, prefix_cache=self.prefix_enabled))
         self.prefix_max = env_int("MXTPU_SERVE_PREFIX_MAX", 8)
 
         self._params = {
@@ -590,8 +596,9 @@ class DecodeLoop(object):
             self._draft_arch = draft_arch
             self.draft_num_layers = int(draft_arch.num_layers)
             self.draft_num_heads = int(draft_arch.num_heads)
-            dvocab = int(draft_arch.validate(dhost, self.max_len, self._mesh,
-                                             self.quant_mode))
+            dvocab = int(draft_arch.validate(
+                dhost, self.max_len, self._mesh, self.quant_mode,
+                spec_k=self.spec_k, prefix_cache=self.prefix_enabled))
             if dvocab != self.vocab_size:
                 raise MXNetError(
                     "DecodeLoop: draft vocab %d != target vocab %d — "
@@ -603,10 +610,7 @@ class DecodeLoop(object):
 
         # --- device state: the slots' state + per-slot seeds ----------
         # speculative windows run past a retiring sequence's last row;
-        # one extra TRASH row absorbs those writes (see _build_token_pass).
-        # Rows lie on the chip's sublanes, 8 of four bytes: allocated in
-        # whole tiles, so the chip pads nothing; the surplus rows are trash
-        # rows too
+        # one extra TRASH row absorbs those writes (see _build_token_pass)
         self._state = self._init_state(arch, host_params)
         self._draft_state = None
         if self.spec_k:
@@ -626,9 +630,10 @@ class DecodeLoop(object):
         self._jfns = []
         self._programs = {}
 
-        def compile_one(tag, fn, structs, donate):
+        def compile_one(tag, fn, structs, donate, options=None):
             jfn = jax.jit(fn, donate_argnums=donate)
-            compiled = jfn.lower(*structs).compile()
+            compiled = jfn.lower(*structs).compile(
+                compiler_options=options or None)
             pname = "%s/%s" % (self.name, tag)
             _tc.register_program(pname, jfn, structs,
                                  donate_argnums=donate)
@@ -661,7 +666,7 @@ class DecodeLoop(object):
                 "step[slots=%d,len=%d]" % (self.slots, self.max_len),
                 _build_decode_fn(arch, mesh=self._mesh),
                 (state_s, params_s) + samp + live_s * arch.wants_live,
-                (0,))
+                (0,), arch.compiler_options(jax.default_backend()))
             self._jfn = self._jfns[-1]   # the main decode body
         if self.prefix_enabled:
             slot_s = self._vec_struct(jax, (), np.int32)
@@ -784,26 +789,33 @@ class DecodeLoop(object):
 
     def _init_state(self, arch, host_params):
         """The donated device state of one model: the arrays the
-        architecture's ``slot_state`` names, each ``(layers, slots, rows,
-        width)`` (for :class:`OptArch` a K and a V cache of ``heads *
-        head_dim`` float32), its counters, the slots' seeds and, where the
-        decode body steps it, the token that body last sampled a slot. The
-        ``width`` is the minor dimension (128 lanes at a time) and rows
-        the second minor, a multiple of the sublanes a tile of the
-        narrowest dtype holds (8 of four bytes, 16 of two): the chip's
-        tiles hold no padding in the rows, and the step program computes
-        in this layout as stored (see :func:`_build_token_pass`). A model
-        mesh shards a slot-state array as the architecture says (OptArch:
-        the minor dimension, a group of whole heads per chip)."""
+        architecture's ``slot_state`` names, each ``(layers, slots, depth,
+        width)`` with its OWN layers, depth and width (for :class:`OptArch`
+        a K and a V cache of ``heads * head_dim`` float32 over every layer,
+        a row a position; a recurrent state is a fixed few rows over the
+        layers that keep one), its counters, the slots' seeds and, where
+        the decode body steps it, the token that body last sampled a slot.
+        The ``width`` is the minor dimension (128 lanes at a time) and the
+        depth the second minor, for a per-position array a multiple of
+        the sublanes a tile of its dtype holds (:meth:`SlotArray.depth`):
+        the chip's tiles hold no padding in the rows, and the step program
+        computes in this layout as stored (see :func:`_build_token_pass`). A model mesh
+        shards a slot-state array as the architecture says (OptArch: the
+        minor dimension, a group of whole heads per chip)."""
         import jax
         import jax.numpy as jnp
         spec = arch.slot_state(host_params, self.quant_mode)
-        tile = 8 * 4 // min(np.dtype(d).itemsize for _, d in spec.values())
-        rows = -(-(self.max_len + (1 if self.spec_k else 0)) // tile) * tile
+        positions = self.max_len + (1 if self.spec_k else 0)
+        state = {k: jnp.zeros((a.layers, self.slots, a.depth(positions),
+                               a.width), a.dtype) for k, a in spec.items()}
         if arch is self._arch:
-            self._rows = rows
-        state = {k: jnp.zeros((arch.num_layers, self.slots, rows, width),
-                              dtype) for k, (width, dtype) in spec.items()}
+            #: the depth of the arrays that keep a row a position
+            self._rows = max([a.depth(positions) for a in spec.values()
+                              if a.rows is PER_POSITION] or [0])
+            self._state_arrays = {
+                k: [int(v.shape[0]), int(v.shape[2]), int(v.shape[3]),
+                    str(v.dtype), int(v.nbytes)]
+                for k, v in state.items()}
         state.update({k: jnp.zeros(shape, np.int32)
                       for k, shape in arch.counters().items()})
         state["seed"] = jnp.zeros((self.slots,), np.uint32)
@@ -1505,6 +1517,12 @@ class DecodeLoop(object):
                                            self._counter_base)
                 self._counter_base = counts
 
+    def state_arrays(self):
+        """``{array: [layers, rows, width, dtype, bytes]}`` of the slots'
+        state as allocated (the rows in whole tiles; the bytes of the whole
+        array, all slots): what the state costs on the device, by kind."""
+        return {k: list(v) for k, v in self._state_arrays.items()}
+
     def program_scopes(self):
         """``{instruction name: scope}`` of the step program (the verify
         program of a speculative loop): for every instruction of the
@@ -1541,7 +1559,8 @@ class DecodeLoop(object):
             return
         _obs.complete("loop_program", time.perf_counter() - t0,
                       program="jit_verify_fn" if self.spec_k
-                      else "jit_decode_fn", scopes=scopes)
+                      else "jit_decode_fn", scopes=scopes,
+                      state=self.state_arrays())
 
     # ------------------------------------------------------------------
     def memory_report(self, top=8):

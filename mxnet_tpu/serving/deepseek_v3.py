@@ -35,11 +35,8 @@ rows, and the weighted sum of ``c'`` goes through the V half.
 chip HOLDS: indices ``share_index * n ..`` of the ``router_width`` experts
 the router ranks. The layer routes over all of them, adds only its own
 experts' terms for the (token, choice) pairs that chose them, plus the
-shared expert, and THAT partial sum goes on. On one chip the layer runs
-without its exchange; nothing stands in for the absent chips. The held
-experts are computed densely (every live row through every held expert,
-the unchosen weighted 0): at decode batch sizes an expert's product is
-bound by reading its weights, which a step does once either way.
+shared expert, and THAT partial sum goes on (:mod:`.blocks` has the layer,
+shared with :mod:`.lfm2`, and says what it leaves out).
 
 **Precision.** The operands of every weight product and of the two cache
 products are the STORED dtype (bfloat16 under ``quantize="bf16"``: no
@@ -47,9 +44,7 @@ float32 copy of a weight is ever made), accumulation float32; norms,
 softmax, the router (its product at ``HIGHEST``) and the residual stream
 float32.
 
-**Counters**, on the device in the donated state: ``moe_served`` (expert
-layer, held expert): (token, choice) pairs served here; ``moe_routed``
-(expert layer): pairs routed in all. Only live slots count.
+**Counters**: ``moe_served`` and ``moe_routed`` of :mod:`.blocks`.
 """
 from __future__ import annotations
 
@@ -58,7 +53,11 @@ import math
 import numpy as np
 
 from ..base import MXNetError
-from .arch import Architecture
+from .arch import PER_POSITION, Architecture, SlotArray
+# shared with serving/lfm2.py (these names stay this module's too)
+from .blocks import (ExpertShare, held_experts, held_weights,  # noqa: F401
+                     linear, moe_counters, record_moe, rms_norm, route,
+                     routed_share, swiglu)
 
 _KEYS = ("hidden_size", "num_attention_heads", "q_lora_rank", "kv_lora_rank",
          "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
@@ -96,16 +95,6 @@ def yarn_mscale(factor, m):
     return 1.0 if factor <= 1 else 0.1 * m * math.log(factor) + 1.0
 
 
-def rms_norm(x, gamma, eps):
-    """``x * rsqrt(mean(x^2) + eps) * gamma`` in float32."""
-    import jax
-    import jax.numpy as jnp
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + jnp.float32(eps)) \
-        * gamma.astype(jnp.float32)
-
-
 def rope(x, cos, sin):
     """Rotate the interleaved pairs ``(x[2i], x[2i+1])`` of the minor
     dimension by the angles whose cos and sin are given per pair."""
@@ -115,62 +104,6 @@ def rope(x, cos, sin):
     a, b = x[..., 0], x[..., 1]
     return jnp.stack([a * cos - b * sin, b * cos + a * sin],
                      axis=-1).reshape(shape)
-
-
-def linear(x, w):
-    """``x @ w.T`` with the operands in the WEIGHT's stored dtype and
-    float32 accumulation."""
-    import jax.numpy as jnp
-    return jnp.einsum("se,fe->sf", x.astype(w.dtype), w,
-                      preferred_element_type=jnp.float32)
-
-
-def swiglu(x, gate, up, down):
-    import jax
-    return linear(jax.nn.silu(linear(x, gate)) * linear(x, up), down)
-
-
-def route(f, weight, bias, top_k, scaling, normalise=True):
-    """``(indices, weights)`` ``(rows, top_k)`` of the experts each row
-    chooses among ALL the router's experts, in float32: chosen by
-    ``sigmoid(W f) + bias``, weighted by the sigmoid alone over the chosen
-    ones' sum (+1e-20), times ``scaling``."""
-    import jax
-    import jax.numpy as jnp
-    f32 = jnp.float32
-    logits = jnp.einsum("se,xe->sx", f.astype(f32), weight.astype(f32),
-                        precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(scores + bias.astype(f32), top_k)
-    w = jnp.take_along_axis(scores, idx, axis=-1)
-    if normalise:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + f32(1e-20))
-    return idx, w * f32(scaling)
-
-
-def held_weights(idx, w, first, held):
-    """``(hit, dense)``: ``hit`` (rows, top_k, held) marks the (row,
-    choice) pairs that chose held expert ``first + j``; ``dense`` (rows,
-    held) is each row's weight for each held expert, 0 where unchosen."""
-    import jax.numpy as jnp
-    hit = (idx - first)[:, :, None] == jnp.arange(held)[None, None, :]
-    return hit, jnp.sum(jnp.where(hit, w[:, :, None], jnp.float32(0.0)),
-                        axis=1)
-
-
-def held_experts(f, dense, gate, up, down):
-    """The held experts' part of the layer's output: every row through
-    every held expert (stacked ``(held, ...)`` weights, each read once),
-    summed with ``dense`` (rows, held) in float32."""
-    import jax
-    import jax.numpy as jnp
-    f32 = jnp.float32
-    fb = f.astype(gate.dtype)
-    g = jnp.einsum("se,xfe->xsf", fb, gate, preferred_element_type=f32)
-    u = jnp.einsum("se,xfe->xsf", fb, up, preferred_element_type=f32)
-    act = (jax.nn.silu(g) * u).astype(down.dtype)
-    y = jnp.einsum("xsf,xef->xse", act, down, preferred_element_type=f32)
-    return jnp.sum(y * dense.T[:, :, None], axis=0)
 
 
 def _pad_lanes(x, width):
@@ -243,6 +176,10 @@ class DeepseekV3Arch(Architecture):
                                            self.n_routed_experts))
         self.share_index = int(config.get("share_index", 0))
         self.first_expert = self.share_index * self.n_routed_experts
+        self.share = ExpertShare(
+            self.num_experts_per_tok, self.routed_scaling,
+            self.norm_topk_prob, 1e-20, self.first_expert,
+            self.n_routed_experts, self.eps)
         if self.first_expert + self.n_routed_experts > self.router_width:
             raise MXNetError(
                 "DeepseekV3Arch: share %d of %d held experts lies outside "
@@ -309,7 +246,8 @@ class DeepseekV3Arch(Architecture):
                         pre + "experts_down_weight": (n, e, f)})
         return out
 
-    def validate(self, host_params, max_len, mesh, quant_mode):
+    def validate(self, host_params, max_len, mesh, quant_mode, spec_k=0,
+                 prefix_cache=False):
         if mesh is not None:
             raise MXNetError(
                 "DecodeLoop: no model mesh over the %s architecture yet — "
@@ -334,24 +272,17 @@ class DeepseekV3Arch(Architecture):
     def slot_state(self, host_params, quant_mode):
         import jax.numpy as jnp
         dtype = jnp.bfloat16 if quant_mode == "bf16" else np.float32
-        return {"latent": (self.latent_width, dtype)}
+        return {"latent": SlotArray(self.num_layers, PER_POSITION,
+                                    self.latent_width, dtype)}
 
     def counters(self):
-        n = len(self.moe_layers)
-        if not n:
-            return {}
-        return {"moe_served": (n, self.n_routed_experts),
-                "moe_routed": (n,)}
+        return moe_counters(len(self.moe_layers), self.n_routed_experts)
 
     def load(self, params):
         return params      # as stored: no float32 copy (int8 was refused)
 
     def record_counters(self, health, counts, before):
-        def total(name):
-            return int(np.sum(counts[name], dtype=np.int64)) \
-                - int(np.sum(before.get(name, 0), dtype=np.int64))
-        health.record_moe(total("moe_routed"), total("moe_served"),
-                          int(np.max(counts["moe_served"])))
+        record_moe(health, counts, before)
 
     # -- one position per slot through every layer -----------------------------
     def build_token_pass(self, mesh=None):
@@ -376,8 +307,7 @@ class DeepseekV3Arch(Architecture):
                 cos = jnp.cos(angle) * f32(self.rope_scale)
                 sin = jnp.sin(angle) * f32(self.rope_scale)
             tmask = jnp.arange(rows)[None, :] <= pos[:, None]
-            served = state.get("moe_served")
-            routed = state.get("moe_routed")
+            counts = (state.get("moe_served"), state.get("moe_routed"))
             nlive = jnp.sum(live.astype(jnp.int32))
             # the scope names are what a device trace is searched for: the
             # same in every layer, so they sum by kind
@@ -412,23 +342,8 @@ class DeepseekV3Arch(Architecture):
                                        p("ffn_up_weight"),
                                        p("ffn_down_weight"))
                     continue
-                m = moe_index[i]
-                with jax.named_scope("layer/moe/router"):
-                    f = rms_norm(x, p("ffn_norm_gamma"), eps)
-                    idx, w = route(f, p("router_weight"), p("router_bias"),
-                                   self.num_experts_per_tok,
-                                   self.routed_scaling, self.norm_topk_prob)
-                    hit, dense = held_weights(idx, w, self.first_expert,
-                                              self.n_routed_experts)
-                    here = jnp.sum(hit & live[:, None, None], axis=(0, 1),
-                                   dtype=jnp.int32)
-                    served = served.at[m].add(here)
-                    routed = routed.at[m].add(
-                        nlive * jnp.int32(self.num_experts_per_tok))
-                with jax.named_scope("layer/moe/experts"):
-                    y = held_experts(f, dense, p("experts_gate_weight"),
-                                     p("experts_up_weight"),
-                                     p("experts_down_weight"))
+                f, y, counts = routed_share(x, p, self.share, live, nlive,
+                                            counts, moe_index[i])
                 with jax.named_scope("layer/moe/shared"):
                     y = y + swiglu(f, p("shared_gate_weight"),
                                    p("shared_up_weight"),
@@ -438,8 +353,8 @@ class DeepseekV3Arch(Architecture):
                 logits = linear(rms_norm(x, params["final_norm_gamma"], eps),
                                 params["lm_head_weight"])
             out = {"latent": lat}
-            if served is not None:
-                out.update(moe_served=served, moe_routed=routed)
+            if counts[0] is not None:
+                out.update(moe_served=counts[0], moe_routed=counts[1])
             return out, logits
 
         return token_pass

@@ -12,6 +12,12 @@ dense layer and one expert layer that holds 12 of 384 experts, 64 slots,
   inside ONE ``conditional``, so a step whose rows are all greedy does not
   run it (PERF.md, PR 30).
 
+The LFM2 step (PERF.md PR 34) at published widths, its first six layers
+(five conv and one attention; two dense and four expert layers that hold 8
+of 64 experts), is compiled here too: K, V and the two-row conv state pass
+through without a copy, and padded to a 16-row tile the conv state is
+re-laid out on entry and on exit (why it is allocated as it is).
+
 The topology is described inside a fixture (only one process may load the
 TPU's library; a worker that cannot skips), and this is the one file that
 does so."""
@@ -24,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 import chip_smoke
-from mxnet_tpu.serving import decode, deepseek_v3
+from mxnet_tpu.serving import decode, deepseek_v3, lfm2
 
 SLOTS, ROWS = 64, 1024
 ITEM = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
@@ -160,3 +166,105 @@ def test_at_576_lanes_the_step_converts_the_cache(one_chip):
     copies = [i for i in _top_level(compiled)
               if i[3] == "copy" and i[2] >= cache]
     assert len(copies) >= 2
+
+
+# ---------------------------------------------------------------------------
+# LFM2: two kinds of state
+# ---------------------------------------------------------------------------
+
+LFM2_DEPTH6 = {
+    "hidden_size": 2048, "num_attention_heads": 32, "num_key_value_heads": 8,
+    "num_hidden_layers": 6, "vocab_size": 65536, "intermediate_size": 11776,
+    "moe_intermediate_size": 1536, "num_experts": 8, "router_width": 64,
+    "num_experts_per_tok": 4, "num_dense_layers": 2, "conv_L_cache": 3,
+    "layer_types": ["conv", "conv", "full_attention", "conv", "conv", "conv"],
+    "norm_eps": 1e-5, "routed_scaling_factor": 1,
+    "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"}}
+
+
+def _compile_lfm2(one_chip, conv_rows=None, options="the architecture's"):
+    arch = lfm2.Lfm2Arch(LFM2_DEPTH6)
+    if options == "the architecture's":
+        options = arch.compiler_options("tpu")
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {k: s(v, jnp.bfloat16) for k, v in arch.param_shapes().items()}
+    state = {k: s((a.layers, SLOTS, a.depth(ROWS), a.width), a.dtype)
+             for k, a in arch.slot_state(None, "bf16").items()}
+    if conv_rows is not None:
+        state["conv"] = s((5, SLOTS, conv_rows, 2048), jnp.bfloat16)
+    state.update(seed=s((SLOTS,), np.uint32), tok=s((SLOTS,), np.int32))
+    state.update({k: s(v, np.int32) for k, v in arch.counters().items()})
+    feed = [s((SLOTS,), d) for d in (np.int32, np.int32, np.float32,
+                                     np.int32, np.float32, np.uint32,
+                                     np.bool_, np.bool_)]
+    fn = jax.jit(decode._build_decode_fn(arch), donate_argnums=(0,))
+    return state, fn.lower(state, params, *feed).compile(
+        compiler_options=options or None)
+
+
+def _state_copies(compiled, dims):
+    """Top-level copies and transposes of an array of ``dims``."""
+    want = int(np.prod(dims)) * 2
+    return [i for i in _top_level(compiled)
+            if i[3] in ("copy", "transpose") and i[1] == "bf16"
+            and i[2] == want]
+
+
+@pytest.fixture(scope="module")
+def lfm2_step(one_chip):
+    return _compile_lfm2(one_chip)
+
+
+def test_lfm2_both_kinds_of_state_pass_through_without_a_copy(lfm2_step):
+    state, compiled = lfm2_step
+    assert state["k"].shape == (1, SLOTS, ROWS, 512)
+    assert state["conv"].shape == (5, SLOTS, 2, 2048)
+    cache = SLOTS * ROWS * 512 * 2                    # one layer's K, bf16
+    moved = [i for i in _top_level(compiled)
+             if i[3] in ("copy", "transpose") and i[1] == "bf16"
+             and i[2] >= cache // 4]
+    assert moved == []
+    # no float32 copy of an expert stack or of the dense feed-forward
+    big = [i for i in _top_level(compiled)
+           if i[1] == "f32" and i[2] >= 4 * 1536 * 2048 * 4]
+    assert [i for i in big if "copy" in i[3] or "convert" in i[3]] == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cache       # donated in place
+    assert mem.temp_size_in_bytes < 0.3e9
+
+
+def test_lfm2_a_conv_state_padded_to_the_tile_is_re_laid_out(one_chip):
+    """Why a fixed number of rows is allocated as it is
+    (``SlotArray.depth``): the finding, kept as a test. At 2 rows the chip
+    stores the state in tiles 2 rows deep and what the step moves of it is
+    its 2.6 MB; at 16 rows it stores the padding too, and the step copies
+    all 21 MB on entry and on exit."""
+    _, padded = _compile_lfm2(one_chip, conv_rows=16)
+    assert len(_state_copies(padded, (5, SLOTS, 16, 2048))) >= 2
+
+
+def _fetches_ahead(compiled):
+    """The asynchronous copies and slices of the entry computation: a
+    weight fetched into fast memory ahead of the product that reads it."""
+    return len(re.findall(r"^\s*%?(?:copy|slice)-start[\w.\-]* = ",
+                          compiled.as_text(), re.M))
+
+
+def test_lfm2_is_compiled_with_one_fetch_ahead_in_flight(one_chip,
+                                                         lfm2_step):
+    """Why ``Lfm2Arch.compiler_options`` says what it says on the chip:
+    the finding, kept as a test. Left to itself the compiler fetches this
+    model's matrices (50 MB expert stacks in slices, and smaller) into
+    fast memory ahead of their products, as some 20 asynchronous pairs a
+    layer that carry no scope: a device trace holds twice the events, and
+    the time under a layer's scope leaves out part of its work. With one
+    in flight a few whole matrices a layer are still fetched ahead
+    (PERF.md, PR 34)."""
+    _, asked = lfm2_step
+    _, left_alone = _compile_lfm2(one_chip, options=None)
+    assert 0 < _fetches_ahead(asked) <= 24            # six layers
+    assert _fetches_ahead(left_alone) >= 3 * _fetches_ahead(asked)
+    assert _fetches_ahead(left_alone) >= 60
