@@ -116,8 +116,11 @@ class Architecture(object):
         writes position ``pos`` of each slot into the per-position arrays
         (clamped to the last row: rows past ``max_len`` are trash rows no
         live query attends), steps a fixed-depth state once, and returns
-        float32 logits ``(slots, vocab)``. The single-token body runs it
-        once, the speculative verify body unrolls it over the window."""
+        float32 logits ``(slots, vocab)``. Attention over a per-position
+        array goes through :func:`.blocks.over_filled_rows`, so that a
+        step reads the prefix of the rows its positions fill and not all
+        ``max_len``. The single-token body runs it once, the speculative
+        verify body unrolls it over the window."""
         raise NotImplementedError
 
     def record_counters(self, health, counts, before):

@@ -20,6 +20,16 @@ either way.
 made), accumulation float32; norms, the router (its product at
 ``HIGHEST``) and the gating products float32.
 
+**The rows a step attends.** A cache addressed by position is allocated
+``max_len`` rows deep and mostly filled far less. Every architecture's
+attention runs under :func:`over_filled_rows`: its unchanged score, mask,
+softmax and mix over a PREFIX of the layer's rows, the smallest rung of
+:func:`rows_ladder` that holds every slot's position, chosen inside the
+step program from the ``pos`` it is fed (:func:`filled_rung`). Rows past
+the prefix are rows whose mask is false for every slot, so their softmax
+weight is exactly 0 and leaving them out changes no value, only which
+rows leave the device's memory.
+
 **Counters**, on the device in the donated state: ``moe_served`` (expert
 layer, held expert): (token, choice) pairs served here; ``moe_routed``
 (expert layer): pairs routed in all. Only live slots count.
@@ -96,6 +106,75 @@ def held_experts(f, dense, gate, up, down):
     act = (jax.nn.silu(g) * u).astype(down.dtype)
     y = jnp.einsum("xsf,xef->xse", act, down, preferred_element_type=f32)
     return jnp.sum(y * dense.T[:, :, None], axis=0)
+
+
+#: the shallowest prefix of a cache's rows worth a branch of its own: a
+#: rung costs a process a third of a second of set-up (its branch of every
+#: attention layer is traced, lowered and read from the compile cache),
+#: and under 192 rows the attention is a tenth of a step (PERF.md, PR 35)
+MIN_PREFIX_ROWS = 192
+#: a prefix ends on a whole tile of rows (16 of two bytes, two of 8 of four)
+PREFIX_TILE = 16
+
+
+def rows_ladder(rows):
+    """The prefixes of a cache ``rows`` deep that a step's attention may
+    cover, ascending, the last one all of it: ``rows`` halved (to whole
+    tiles) while a half holds :data:`MIN_PREFIX_ROWS` (768: 192, 384, 768;
+    1024: 256, 512, 1024; a cache under 384 rows has one rung and no
+    branch). A constant of the allocated depth: nothing to tune."""
+    ladder, part = {int(rows)}, -(-int(rows) // 2)
+    while part >= MIN_PREFIX_ROWS:
+        ladder.add(-(-part // PREFIX_TILE) * PREFIX_TILE)
+        part = -(-part // 2)
+    return tuple(sorted(ladder))
+
+
+def filled_rung(top, ladder, xp):
+    """The index of the smallest rung of ``ladder`` above the position
+    ``top``, the last where none is (a speculative window's positions past
+    ``max_len``). The ONE rule of host and device: ``xp`` is ``jax.numpy``
+    in the step program, where ``top`` is the deepest ``pos`` it is fed
+    (an empty slot is fed 0), and ``numpy`` for what the spans and
+    counters say (:func:`rows_covered`)."""
+    return xp.sum(top >= xp.asarray(ladder[:-1], "int32"), dtype="int32")
+
+
+def rows_covered(ladder, top):
+    """The rung that a step whose deepest position is ``top`` attends, on
+    the host."""
+    import numpy as np
+    return ladder[int(filled_rung(top, ladder, np))]
+
+
+def over_filled_rows(pos, rows):
+    """For one token pass over caches ``rows`` deep: ``over(caches, layer,
+    attend)``, which gives ``attend(mask, *[c[layer, :, :R] for c in
+    caches])`` with ``R`` the smallest rung of :func:`rows_ladder` above
+    every slot's ``pos`` and ``mask`` (slots, R) true on the rows a slot
+    attends (``<= pos``). The rung is picked once a pass; a layer's call
+    is one ``lax.switch`` with a branch a rung, each over a STATIC slice,
+    which fuses into the read of the product that takes it, so that only
+    ``R`` rows leave memory. The ``caches`` (each ``(layers, slots, rows,
+    width)``) go into the branches whole, as operands that are only read:
+    no copy of a layer's rows is made to hand it over. ``attend`` is traced
+    inside the call, so it may close over the layer's own values."""
+    import jax
+    import jax.numpy as jnp
+    ladder = rows_ladder(rows)
+    rung = filled_rung(jnp.max(pos), ladder, jnp)
+
+    def over(caches, layer, attend):
+        def branch(depth):
+            def run(pos, *cs):
+                mask = jnp.arange(depth)[None, :] <= pos[:, None]
+                return attend(mask, *[c[layer, :, :depth] for c in cs])
+            return run
+
+        return jax.lax.switch(rung, [branch(r) for r in ladder], pos,
+                              *caches)
+
+    return over
 
 
 class ExpertShare(collections.namedtuple(

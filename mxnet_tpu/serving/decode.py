@@ -90,6 +90,7 @@ from ..base import MXNetError, env_int, env_str
 from ..obs import trace as _obs
 from .arch import PER_POSITION, Architecture, SlotArray
 from .batcher import REQUEST_IDS, ServingClosedError, Settleable
+from .blocks import over_filled_rows, rows_covered, rows_ladder
 from .health import ServingHealth, SERVING_HEALTH
 from .quantize import (is_quantized_leaf, quantize_array, quantize_tree,
                        resolve_mode, tree_bytes)
@@ -132,6 +133,16 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
     precision, where a product with 1 is exact: the same float32 products
     and sums as ever, with no bfloat16 rounding anywhere.
 
+    THE ATTENTION COVERS A PREFIX OF THE ROWS: the smallest rung of
+    ``blocks.rows_ladder(rows)`` that holds every slot's ``pos``, picked
+    once a pass from the ``pos`` it is fed and run as one branch of a
+    ``lax.switch`` a layer, each over a static slice of the donated array
+    (:func:`.blocks.over_filled_rows`). The rows a branch leaves out are
+    rows the mask gives a softmax weight of exactly 0, so the logits are
+    those of the whole cache but for the order of a sum; the write stays
+    on the whole array, in place. What a step reads of the cache follows
+    the deepest position in it, not ``max_len`` (PERF.md, PR 35).
+
     The write/embed position is clamped to the last cache row. Rows past
     ``max_len`` are TRASH rows: a speculative window's positions past
     ``max_len`` land there and no valid query ever attends them (the
@@ -172,7 +183,6 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
         d = embed // num_heads
         scale = jnp.float32(1.0 / float(np.sqrt(d)))
         sidx = jnp.arange(nslots)
-        tmask = (jnp.arange(rows)[None, :] <= pos[:, None])[:, :, None]
         neg = jnp.float32(-1e30)
         # lane e of a group belongs to the group's head e // d
         lanes, gheads = embed // groups, num_heads // groups
@@ -180,14 +190,18 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
                == jnp.arange(gheads)[None, :]).astype(jnp.float32)
 
         def heads_sum(p):       # (slots, rows, embed) -> (slots, rows, heads)
-            p = p.reshape(nslots, rows, groups, lanes)
+            p = p.reshape(nslots, -1, groups, lanes)
             return jnp.einsum("stge,eh->stgh", p, seg, precision=highest
-                              ).reshape(nslots, rows, num_heads)
+                              ).reshape(nslots, -1, num_heads)
 
         def heads_spread(w):    # (slots, rows, heads) -> (slots, rows, embed)
-            w = w.reshape(nslots, rows, groups, gheads)
+            w = w.reshape(nslots, -1, groups, gheads)
             return jnp.einsum("stgh,eh->stge", w, seg, precision=highest
-                              ).reshape(nslots, rows, embed)
+                              ).reshape(nslots, -1, embed)
+
+        # the rows a step attends: the prefix that holds every slot's
+        # position, picked from ``pos`` once a pass (serving/blocks.py)
+        over = over_filled_rows(pos, rows)
 
         # the scope names are what an operator searches a device trace
         # for: the same in every layer, so they sum by kind
@@ -204,10 +218,13 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
                 ck = ck.at[i, sidx, wpos].set(k)
                 cv = cv.at[i, sidx, wpos].set(v)
             with jax.named_scope("layer/attn"):
-                s = heads_sum(q[:, None, :] * ck[i]) * scale
-                s = jnp.where(tmask, s, neg)
-                w = jax.nn.softmax(s, axis=1)
-                o = jnp.sum(heads_spread(w) * cv[i], axis=1)
+                def attend(mask, krows, vrows):
+                    s = heads_sum(q[:, None, :] * krows) * scale
+                    s = jnp.where(mask[:, :, None], s, neg)
+                    w = jax.nn.softmax(s, axis=1)
+                    return jnp.sum(heads_spread(w) * vrows, axis=1)
+
+                o = over((ck, cv), i, attend)
                 o = o @ params[pre + "_attn_out_weight"].T \
                     + params[pre + "_attn_out_bias"]
                 x = edge(x + o)
@@ -809,9 +826,12 @@ class DecodeLoop(object):
         state = {k: jnp.zeros((a.layers, self.slots, a.depth(positions),
                                a.width), a.dtype) for k, a in spec.items()}
         if arch is self._arch:
-            #: the depth of the arrays that keep a row a position
+            #: the depth of the arrays that keep a row a position, and the
+            #: prefixes of it a step's attention may cover (the token pass
+            #: derives the same ladder from the same depth)
             self._rows = max([a.depth(positions) for a in spec.values()
                               if a.rows is PER_POSITION] or [0])
+            self._ladder = rows_ladder(self._rows)
             self._state_arrays = {
                 k: [int(v.shape[0]), int(v.shape[2]), int(v.shape[3]),
                     str(v.dtype), int(v.nbytes)]
@@ -1465,17 +1485,25 @@ class DecodeLoop(object):
         # retire/length break left unverified would deflate the acceptance
         # rate a perfect draft earns (drafted == accepted by construction)
         self.health.record_spec_round(judged, accepted)
-        self._count_step(sp, a, emitted, prompt)
+        self._count_step(sp, a, emitted, prompt, passes=window)
         sp.lap("decode_commit")
 
-    def _count_step(self, sp, a, emitted, prompt, ahead=0):
+    def _count_step(self, sp, a, emitted, prompt, ahead=0, passes=1):
         """The dispatched step's counts, for the health report and its
         span. Rows whose ``temp`` is above 0 sample: any at all and the
         step's program took the sampler's branch (sampling.py, rule 3).
-        ``ahead``: 1 where the step before was still unread."""
+        ``ahead``: 1 where the step before was still unread. ``rows``: the
+        prefix of the cache's rows its attention covered, as the program
+        picked it from the ``pos`` it was fed (:func:`.blocks.filled_rung`;
+        pass j of a speculative window's ``passes`` stands j deeper),
+        summed over the passes; 0 for a model without such a cache."""
         sampled = int((a["temp"] > 0).sum())
-        sp.set(sampled=sampled, ahead=ahead)
-        self.health.record_decode_step(emitted, prompt, sampled, ahead)
+        top = int(a["pos"].max())
+        rows = sum(rows_covered(self._ladder, top + j)
+                   for j in range(passes))
+        sp.set(sampled=sampled, ahead=ahead, rows=rows)
+        self.health.record_decode_step(emitted, prompt, sampled, ahead,
+                                       rows, passes * self._rows)
 
     def _retire(self, i):
         slot = self._slots[i]

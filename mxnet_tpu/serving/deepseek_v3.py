@@ -56,8 +56,8 @@ from ..base import MXNetError
 from .arch import PER_POSITION, Architecture, SlotArray
 # shared with serving/lfm2.py (these names stay this module's too)
 from .blocks import (ExpertShare, held_experts, held_weights,  # noqa: F401
-                     linear, moe_counters, record_moe, rms_norm, route,
-                     routed_share, swiglu)
+                     linear, moe_counters, over_filled_rows, record_moe,
+                     rms_norm, route, routed_share, swiglu)
 
 _KEYS = ("hidden_size", "num_attention_heads", "q_lora_rank", "kv_lora_rank",
          "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
@@ -306,7 +306,7 @@ class DeepseekV3Arch(Architecture):
                 angle = wpos.astype(f32)[:, None] * inv_freq[None, :]
                 cos = jnp.cos(angle) * f32(self.rope_scale)
                 sin = jnp.sin(angle) * f32(self.rope_scale)
-            tmask = jnp.arange(rows)[None, :] <= pos[:, None]
+            over = over_filled_rows(pos, rows)
             counts = (state.get("moe_served"), state.get("moe_routed"))
             nlive = jnp.sum(live.astype(jnp.int32))
             # the scope names are what a device trace is searched for: the
@@ -330,10 +330,12 @@ class DeepseekV3Arch(Architecture):
                 with jax.named_scope("cache_write"):
                     lat = lat.at[i, sidx, wpos].set(row.astype(lat.dtype))
                 with jax.named_scope("layer/mla"):
-                    o = mla_absorbed(
-                        q[..., :nope], q_pe, lat[i],
-                        p("attn_kv_b_weight").reshape(heads, -1, lora),
-                        tmask, self.softmax_scale, nope)
+                    o = over(
+                        (lat,), i,
+                        lambda mask, rows_i: mla_absorbed(
+                            q[..., :nope], q_pe, rows_i,
+                            p("attn_kv_b_weight").reshape(heads, -1, lora),
+                            mask, self.softmax_scale, nope))
                     x = x + linear(o, p("attn_out_weight"))
                 if i not in moe_index:
                     with jax.named_scope("layer/mlp"):

@@ -35,6 +35,13 @@ class ServingHealth(object):
         #                            for the in-graph sampler
         self.steps_ahead = 0       # decode steps dispatched while the step
         #                            before was still unread (run-ahead)
+        self.cache_rows_read = 0   # rows of a cache addressed by position
+        #                            (a slot's, a layer's) that the steps'
+        #                            attention covered: the prefix each
+        #                            picked from the positions it was fed
+        self.cache_rows_allocated = 0   # the same had each read every row:
+        #                            read / allocated is the share of the
+        #                            cache a loop's attention touches
         self.trash_slot_steps = 0  # slot-steps dispatched for a request
         #                            whose eos was learned a step late:
         #                            their tokens were dropped
@@ -97,22 +104,28 @@ class ServingHealth(object):
     def record_error(self, err=None):
         self._bump("errors", err=err)
 
-    def record_decode_step(self, emitted=0, prompt=0, sampled=0, ahead=0):
+    def record_decode_step(self, emitted=0, prompt=0, sampled=0, ahead=0,
+                           rows=0, allocated=0):
         """One decode step (or speculative round) DISPATCHED: it handed
         ``emitted`` tokens to its requests (a run-ahead step hands its
         tokens over a step later, through :meth:`record_tokens`),
         processed ``prompt`` positions that emitted none and fed
         ``sampled`` rows with a temperature above 0 (any at all and the
         step ran the sampler); ``ahead`` is 1 where the step before was
-        still unread. The counts move under one lock."""
+        still unread; its attention covered ``rows`` of the ``allocated``
+        rows a slot's cache holds in a layer (each summed over the passes
+        of a speculative window). The counts move under one lock."""
         with self._lock:
             self.decode_steps += 1
             self.tokens_emitted += int(emitted)
             self.prompt_positions += int(prompt)
             self.sampled_steps += int(sampled > 0)
             self.steps_ahead += int(ahead)
+            self.cache_rows_read += int(rows)
+            self.cache_rows_allocated += int(allocated)
         if self._parent is not None:
-            self._parent.record_decode_step(emitted, prompt, sampled, ahead)
+            self._parent.record_decode_step(emitted, prompt, sampled, ahead,
+                                            rows, allocated)
 
     def record_tokens(self, emitted, trash=0):
         """A run-ahead step read back: ``emitted`` tokens handed to their
@@ -174,6 +187,8 @@ class ServingHealth(object):
                 "prompt_positions": self.prompt_positions,
                 "sampled_steps": self.sampled_steps,
                 "steps_ahead": self.steps_ahead,
+                "cache_rows_read": self.cache_rows_read,
+                "cache_rows_allocated": self.cache_rows_allocated,
                 "trash_slot_steps": self.trash_slot_steps,
                 "joined": self.joined,
                 "retired": self.retired, "requeued": self.requeued,
@@ -195,6 +210,7 @@ class ServingHealth(object):
             self.shed = self.errors = self.decode_steps = 0
             self.tokens_emitted = self.prompt_positions = 0
             self.sampled_steps = self.steps_ahead = 0
+            self.cache_rows_read = self.cache_rows_allocated = 0
             self.trash_slot_steps = 0
             self.joined = self.retired = self.requeued = 0
             self.prefix_hits = self.prefix_prefills = 0

@@ -68,8 +68,8 @@ import numpy as np
 
 from ..base import MXNetError
 from .arch import PER_POSITION, Architecture, SlotArray
-from .blocks import (ExpertShare, linear, moe_counters, record_moe, rms_norm,
-                     routed_share, swiglu)
+from .blocks import (ExpertShare, linear, moe_counters, over_filled_rows,
+                     record_moe, rms_norm, routed_share, swiglu)
 
 _KEYS = ("hidden_size", "num_attention_heads", "num_key_value_heads",
          "num_hidden_layers", "vocab_size", "intermediate_size",
@@ -342,7 +342,7 @@ class Lfm2Arch(Architecture):
                 with jax.named_scope("embed"):
                     angle = wpos.astype(f32)[:, None] * inv_freq[None, :]
                     cos, sin = jnp.cos(angle)[:, None], jnp.sin(angle)[:, None]
-                tmask = jnp.arange(rows)[None, :] <= pos[:, None]
+                over = over_filled_rows(pos, rows)
             counts = (state.get("moe_served"), state.get("moe_routed"))
             nlive = jnp.sum(live.astype(jnp.int32))
             # the scope names are what a device trace is searched for: the
@@ -384,7 +384,9 @@ class Lfm2Arch(Architecture):
                             k.reshape(nslots, -1).astype(ck.dtype))
                         cv = cv.at[n, sidx, wpos].set(v.astype(cv.dtype))
                     with jax.named_scope("layer/attn"):
-                        o = gqa_attention(q, ck[n], cv[n], tmask, scale)
+                        o = over((ck, cv), n,
+                                 lambda mask, kr, vr: gqa_attention(
+                                     q, kr, vr, mask, scale))
                         x = x + linear(o, p("attn_out_weight"))
                 if i not in moe_index:
                     with jax.named_scope("layer/mlp"):

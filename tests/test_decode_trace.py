@@ -43,8 +43,9 @@ INNER = ("decode_gather", "decode_h2d", "decode_dispatch",
          "decode_readback", "decode_commit")
 
 
-def _lm_params(seed=3, num_layers=None):
-    cfg = dict(_LM, num_layers=num_layers or _LM["num_layers"])
+def _lm_params(seed=3, num_layers=None, seq_len=None):
+    cfg = dict(_LM, num_layers=num_layers or _LM["num_layers"],
+               seq_len=seq_len or _LM["seq_len"])
     sym = models.transformer(**cfg)
     s = cfg["seq_len"]
     arg_shapes, _, _ = sym.infer_shape(data=(1, s), softmax_label=(1, s))
@@ -326,6 +327,41 @@ def test_sampled_steps_counts_the_steps_a_sampled_request_was_seated(spec_k):
     assert loop.health.sampled_steps == grown["sampled_steps"] == len(seated)
 
 
+@pytest.mark.parametrize("spec_k", [0, 2])
+def test_rows_read_agree_across_spans_counters_and_positions(spec_k):
+    """``rows`` of a ``decode_step`` span is the prefix of the cache's rows
+    that the dispatched step's attention covered: the rung of the ladder
+    above the deepest position it was fed (pass j of a speculative window
+    stands j deeper; the sum over the passes). ``cache_rows_read`` is the
+    spans' sum, ``cache_rows_allocated`` what the steps would have read
+    of a cache attended whole. A prompt of 200 carries one request over
+    the first rung's edge (208 rows) while the others stay shallow."""
+    params = _lm_params(seq_len=400)
+    kw = dict(spec_k=2, draft_params=params,
+              draft_num_layers=_LM["num_layers"]) if spec_k else {}
+    loop = serving.DecodeLoop(params, num_layers=_LM["num_layers"],
+                              num_heads=_LM["num_heads"], max_len=400,
+                              slots=2, prefix_cache=False, **kw)
+    ladder = serving.blocks.rows_ladder(loop._rows)
+    assert ladder == (208, 408 if spec_k else 400) == loop._ladder
+    long_prompt = [1 + i % 16 for i in range(200)]
+    futs, evs, _ = _serve(loop, [(long_prompt, 12), ([4, 5], 3), ([6], 5)])
+    steps = _steps(evs)
+    passes = spec_k + 1
+    for st in steps:
+        top = max(st["args"]["pos"])
+        assert st["args"]["rows"] == sum(
+            serving.blocks.rows_covered(ladder, top + j)
+            for j in range(passes))
+    rows = [st["args"]["rows"] for st in steps]
+    assert min(rows) == passes * ladder[0] and max(rows) == passes * ladder[1]
+    h = loop.health.report()
+    assert h["cache_rows_read"] == sum(rows)
+    assert h["cache_rows_allocated"] == len(steps) * passes * loop._rows
+    assert 0 < h["cache_rows_read"] < h["cache_rows_allocated"]
+    assert [len(f.tokens) for f in futs] == [12, 3, 5]
+
+
 def test_the_benchmarks_reader_counts_what_the_counter_counted(plain_run):
     """``emitted_tok_per_s`` times the traced seconds is the growth of
     ``SERVING_HEALTH.tokens_emitted`` over the same steps."""
@@ -489,13 +525,15 @@ def test_record_decode_step_moves_four_counts_and_mirrors_them():
     h.record_decode_step(3, 5)
     h.record_decode_step()
     h.record_decode_step(2, 0, sampled=7)    # seven rows, ONE sampled step
+    h.record_decode_step(rows=96, allocated=768)
     for x in (h, parent):
         r = x.report()
         assert (r["decode_steps"], r["tokens_emitted"],
-                r["prompt_positions"], r["sampled_steps"]) == (3, 5, 5, 1)
+                r["prompt_positions"], r["sampled_steps"]) == (4, 5, 5, 1)
+        assert (r["cache_rows_read"], r["cache_rows_allocated"]) == (96, 768)
     h.reset()
     assert h.tokens_emitted == h.prompt_positions == h.decode_steps \
-        == h.sampled_steps == 0
+        == h.sampled_steps == h.cache_rows_read == h.cache_rows_allocated == 0
     assert parent.tokens_emitted == 5 and parent.sampled_steps == 1
 
 
@@ -506,6 +544,8 @@ def test_new_counters_reach_the_registry_and_prometheus():
     assert "serving_health.sampled_steps" in snap
     assert "serving_health.steps_ahead" in snap
     assert "serving_health.trash_slot_steps" in snap
+    assert "serving_health.cache_rows_read" in snap
+    assert "serving_health.cache_rows_allocated" in snap
     prom = obs.REGISTRY.to_prometheus()
     assert "serving_health_tokens_emitted" in prom
     assert "serving_health_prompt_positions" in prom

@@ -18,6 +18,16 @@ of 64 experts), is compiled here too: K, V and the two-row conv state pass
 through without a copy, and padded to a 16-row tile the conv state is
 re-laid out on entry and on exit (why it is allocated as it is).
 
+From PR 35 every architecture's attention covers a PREFIX of the cache's
+rows, in one branch a rung of a ``conditional`` (``serving/blocks.py``):
+held here for Kimi's latent rows, LFM2's K and V and, at two layers of its
+published widths, OPT's, that the caches still pass through without a
+cache-sized copy with the branches in, that there is ONE step executable,
+that every branch but the last reads fewer rows than the array holds and
+makes nothing larger than its prefix, and that LFM2's step stays under a
+stated number of device-visible instructions (a traced run's cost is
+quadratic in them, PERF.md PR 34).
+
 The topology is described inside a fixture (only one process may load the
 TPU's library; a worker that cannot skips), and this is the one file that
 does so."""
@@ -30,7 +40,7 @@ import jax
 import jax.numpy as jnp
 
 import chip_smoke
-from mxnet_tpu.serving import decode, deepseek_v3, lfm2
+from mxnet_tpu.serving import blocks, decode, deepseek_v3, lfm2
 
 SLOTS, ROWS = 64, 1024
 ITEM = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
@@ -145,8 +155,10 @@ def test_the_sampler_stands_inside_the_conditional(step):
 
     outside = walk(entry, set())
     assert len(outside) > 300                      # it did walk the step
-    cond, = [i for i in outside if i["opcode"] == "conditional"]
-    assert cond["op_path"].endswith("/sample/cond")
+    # (the attention of each layer stands in a conditional of its own, one
+    # branch a rung of the rows' ladder: held further down)
+    cond, = [i for i in outside if i["opcode"] == "conditional"
+             and (i["op_path"] or "").endswith("/sample/cond")]
     assert [i["instr"] for i in outside
             if wide(i, "sort") or wide(i, "gather")] == []
     branches = [n for m in fc._BRANCHES_RE.finditer(cond["rest"])
@@ -268,3 +280,157 @@ def test_lfm2_is_compiled_with_one_fetch_ahead_in_flight(one_chip,
     assert 0 < _fetches_ahead(asked) <= 24            # six layers
     assert _fetches_ahead(left_alone) >= 3 * _fetches_ahead(asked)
     assert _fetches_ahead(left_alone) >= 60
+
+
+# ---------------------------------------------------------------------------
+# attention over the filled rows (PR 35)
+# ---------------------------------------------------------------------------
+
+def _compile_opt(one_chip, layers=2, slots=8, rows=768):
+    """OPT-1.3b's published widths at ``layers`` layers, float32."""
+    e, f, v = 2048, 8192, 50272
+
+    def s(shape, dtype=np.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"tok_embed_weight": s((v, e)), "pos_embed_weight": s((2048, e)),
+              "final_ln_gamma": s((e,)), "final_ln_beta": s((e,)),
+              "lm_head_weight": s((v, e)), "lm_head_bias": s((v,))}
+    for i in range(layers):
+        for name, shape in (("ln1_gamma", (e,)), ("ln1_beta", (e,)),
+                            ("attn_qkv_weight", (3 * e, e)),
+                            ("attn_qkv_bias", (3 * e,)),
+                            ("attn_out_weight", (e, e)),
+                            ("attn_out_bias", (e,)), ("ln2_gamma", (e,)),
+                            ("ln2_beta", (e,)), ("ffn_fc1_weight", (f, e)),
+                            ("ffn_fc1_bias", (f,)), ("ffn_fc2_weight", (e, f)),
+                            ("ffn_fc2_bias", (e,))):
+            params["layer%d_%s" % (i, name)] = s(shape)
+    cache = s((layers, slots, rows, e))
+    state = {"k": cache, "v": cache, "seed": s((slots,), np.uint32),
+             "tok": s((slots,), np.int32)}
+    feed = [s((slots,), d) for d in (np.int32, np.int32, np.float32,
+                                     np.int32, np.float32, np.uint32,
+                                     np.bool_)]
+    fn = jax.jit(decode._build_decode_fn(decode.OptArch(layers, 32)),
+                 donate_argnums=(0,))
+    return fn.lower(state, params, *feed).compile()
+
+
+def _callees(comps, ins):
+    from mxnet_tpu import flopcheck as fc
+    m = fc._CALLS_RE.search(fc._BRANCHES_RE.sub("", ins["rest"]))
+    return [m.group(1)] if m and m.group(1) in comps else []
+
+
+def _reach(comps, name):
+    """Every instruction a branch runs, its fusions' own included."""
+    out = []
+    for ins in comps[name]:
+        out.append(ins)
+        for callee in _callees(comps, ins):
+            out.extend(_reach(comps, callee))
+    return out
+
+
+def _attention_branches(compiled, ladder):
+    """``[[branch computation a rung]]`` of every conditional that has one
+    branch a rung, and the parsed computations."""
+    from mxnet_tpu import flopcheck as fc
+    text = compiled.as_text()
+    assert len(re.findall(r"^ENTRY ", text, re.M)) == 1   # ONE executable
+    comps, _ = fc._parse_computations(text)
+    found = []
+    for ins in (i for c in comps.values() for i in c):
+        if ins["opcode"] != "conditional":
+            continue
+        names = [n for m in fc._BRANCHES_RE.finditer(ins["rest"])
+                 for n in fc._BRANCH_NAME_RE.findall(m.group(0))]
+        if len(names) == len(ladder):
+            found.append(names)
+    return found, comps
+
+
+def _holds_the_prefix_alone(compiled, ladder, slots, width, layers):
+    """Branch k of every attention layer reads rows ``[:ladder[k]]`` of the
+    cache by a static slice inside the product that takes it, and makes
+    nothing as large as the next rung's rows would be."""
+    from mxnet_tpu import flopcheck as fc
+    found, comps = _attention_branches(compiled, ladder)
+    assert len(found) == layers
+    for names in found:
+        for rung, name in zip(ladder, names):
+            made = [i for i in _reach(comps, name)
+                    if i["opcode"] not in ("parameter", "get-tuple-element",
+                                           "bitcast")]     # handed in whole
+            cut = {int(m.group(1)) for i in made if i["opcode"] == "slice"
+                   for m in [re.match(r"\w+\[1,%d,(\d+),\d+\]" % slots,
+                                      i["type"])] if m}
+            # (the last rung is the array's own depth: no slice need show)
+            assert cut == {rung} or (rung == ladder[-1] and not cut), \
+                (name, cut)
+            assert max(fc._type_elems(i["type"]) for i in made
+                       if not i["type"].startswith("(")) \
+                <= slots * rung * width, name
+
+
+def test_kimi_attends_a_prefix_of_the_latent_rows(step):
+    arch, compiled = step
+    ladder = blocks.rows_ladder(ROWS)
+    assert ladder == (256, 512, 1024)
+    _holds_the_prefix_alone(compiled, ladder, SLOTS, arch.latent_width,
+                            arch.num_layers)
+
+
+def test_lfm2_attends_a_prefix_of_k_and_v(lfm2_step):
+    _, compiled = lfm2_step
+    _holds_the_prefix_alone(compiled, blocks.rows_ladder(ROWS), SLOTS, 512, 1)
+
+
+def test_opt_attends_a_prefix_and_moves_no_cache(one_chip):
+    """The claimed cells' program: 8 slots, 768 rows, float32."""
+    compiled = _compile_opt(one_chip)
+    ladder = blocks.rows_ladder(768)
+    assert ladder == (192, 384, 768)
+    _holds_the_prefix_alone(compiled, ladder, 8, 2048, 2)
+    cache = 2 * 8 * 768 * 2048 * 4
+    moved = [i for i in _top_level(compiled)
+             if i[3] in ("copy", "transpose") and i[2] >= cache // 8]
+    assert moved == []
+    found, facts = chip_smoke.cache_relayouts(compiled, "opt/step", cache)
+    assert found == [], facts
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cache           # donated in place
+    assert mem.temp_size_in_bytes < 0.05e9
+
+
+#: what a traced run shows of a step: every instruction outside the fused
+#: computations but these, and of a conditional its longest branch
+_SILENT = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
+
+
+def _device_visible(comps, name):
+    from mxnet_tpu import flopcheck as fc
+    n = 0
+    for ins in comps[name]:
+        if ins["opcode"] in _SILENT:
+            continue
+        n += 1
+        if ins["opcode"] == "conditional":
+            n += max(_device_visible(comps, b)
+                     for m in fc._BRANCHES_RE.finditer(ins["rest"])
+                     for b in fc._BRANCH_NAME_RE.findall(m.group(0)))
+    return n
+
+
+def test_lfm2_step_stays_under_its_count_of_device_instructions(lfm2_step):
+    """The harness's gap attribution is gaps x spans (PERF.md PR 34, §7 j):
+    a traced run of the 40-layer step took 692 s of the driver's 1200 at
+    1,737 device events. These six layers showed 287 before the branches
+    and 300 with them (13 an attention layer: the conditional and what a
+    branch computes again of the query's layout); 40 layers with 10
+    attention layers stay under 1,900."""
+    from mxnet_tpu import flopcheck as fc
+    _, compiled = lfm2_step
+    comps, entry = fc._parse_computations(compiled.as_text())
+    assert 250 < _device_visible(comps, entry) <= 310
