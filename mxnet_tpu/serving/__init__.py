@@ -15,7 +15,9 @@ Three layers over the standalone :class:`~mxnet_tpu.predictor.Predictor`:
   :class:`Architecture` names: :class:`OptArch` is the default,
   :class:`DeepseekV3Arch` keeps latent rows and holds a share of a routed
   expert layer, :class:`Lfm2Arch` keeps K and V rows over its attention
-  layers and a two-row conv state over the others) is donated device state
+  layers and a two-row conv state over the others, :class:`MellumArch` a
+  ring of K and V rows over its sliding-window layers beside K and V rows a
+  position over its full ones) is donated device state
   stepped by one compiled decode
   body; sequences join and leave mid-stream. The
   production decode path layers four separate legs on top,
@@ -49,13 +51,14 @@ from .arch import Architecture
 from .decode import DecodeLoop, GenerateFuture, OptArch
 from .deepseek_v3 import DeepseekV3Arch
 from .lfm2 import Lfm2Arch
+from .mellum import MellumArch
 from .fleet import FleetRouter, FleetRequest, CLASSES as FLEET_CLASSES
 from .quantize import (QUANT_MODES, check_quality, quality_report,
                        quantize_tree, tree_bytes)
 
 __all__ = [
     "ServingEngine", "Batcher", "DecodeLoop", "GenerateFuture",
-    "Architecture", "OptArch", "DeepseekV3Arch", "Lfm2Arch",
+    "Architecture", "OptArch", "DeepseekV3Arch", "Lfm2Arch", "MellumArch",
     "FleetRouter", "FleetRequest", "FLEET_CLASSES",
     "ServingHealth", "SERVING_HEALTH", "default_buckets",
     "ServingError", "ServingDeadlineError", "ServingOverloadedError",

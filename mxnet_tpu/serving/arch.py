@@ -10,7 +10,9 @@ layers. The default is :class:`~mxnet_tpu.serving.decode.OptArch`
 :class:`~mxnet_tpu.serving.deepseek_v3.DeepseekV3Arch` keeps latent rows
 and routing counters; :class:`~mxnet_tpu.serving.lfm2.Lfm2Arch` keeps K and
 V rows over its attention layers and a conv state two rows deep over the
-others. This is the serving third of the per-layer-type state protocol
+others; :class:`~mxnet_tpu.serving.mellum.MellumArch` keeps a RING of
+``sliding_window`` K and V rows over its window layers beside K and V rows
+a position over its full ones. This is the serving third of the per-layer-type state protocol
 (ROADMAP D1): a slot's state is whatever arrays ``slot_state`` names, each
 a :class:`SlotArray` that says over how many layers it runs, how deep it is
 and how wide, and the loop allocates, donates, extracts and implants them
@@ -31,13 +33,20 @@ PER_POSITION = None
 
 
 class SlotArray(collections.namedtuple("SlotArray",
-                                       "layers rows width dtype")):
+                                       "layers rows width dtype ring",
+                                       defaults=(False,))):
     """One array of the slots' state, allocated ``(layers, slots, depth,
     width)``: ``layers`` of the model's layers keep it (an architecture
     with layers of several kinds numbers each kind's own), ``rows`` is
     :data:`PER_POSITION` for a cache addressed by position or a fixed
     number for a state that never grows, ``width`` is the minor dimension
-    (whole 128-lane tiles, or the chip pads) and ``dtype`` what is stored."""
+    (whole 128-lane tiles, or the chip pads) and ``dtype`` what is stored.
+    ``ring`` marks a fixed number of rows that IS addressed by position:
+    position ``p`` lives in row ``p % rows`` and overwrites position ``p -
+    rows``, so the array holds the last ``rows`` positions of a slot (a
+    sliding window's K and V). The loop counts a ring's rows beside the
+    per-position arrays' (``ring_rows``); an architecture with one refuses
+    speculation and the prefix cache in ``validate``."""
 
     __slots__ = ()
 
@@ -49,11 +58,15 @@ class SlotArray(collections.namedtuple("SlotArray",
         of rows is allocated as it is: the chip stores a few rows in tiles
         that many rows deep, and padded to 16 it stores the padding too and
         the step program re-lays the whole array out on entry and on exit
-        (PERF.md, PR 34; ``tests/test_deepseek_v3_tpu_compile.py``)."""
-        if self.rows is not PER_POSITION:
+        (PERF.md, PR 34; ``tests/test_deepseek_v3_tpu_compile.py``). A ring
+        is never deeper than a per-position array would be: slots that
+        hold fewer positions than its rows never wrap it."""
+        if self.rows is not PER_POSITION and not self.ring:
             return int(self.rows)
         tile = 8 * 4 // np.dtype(self.dtype).itemsize
-        return -(-int(positions) // tile) * tile
+        whole = -(-int(positions) // tile) * tile
+        return whole if self.rows is PER_POSITION \
+            else min(int(self.rows), whole)
 
 
 class Architecture(object):
@@ -76,7 +89,10 @@ class Architecture(object):
         return the vocabulary size. Speculation writes rows past ``pos``
         and abandons them, and the prefix cache implants a slab at a
         shorter length than it was cut at: both are sound only for state
-        addressed by position."""
+        that keeps a row a position (a ring is addressed by position and
+        still unsound for both: rows written past ``pos`` overwrite rows
+        inside the window, and a slab holds the window of the length it
+        was cut at)."""
         raise NotImplementedError
 
     def slot_state(self, host_params, quant_mode):
@@ -115,11 +131,12 @@ class Architecture(object):
         holds the arrays of ``slot_state`` and ``counters``; the pass
         writes position ``pos`` of each slot into the per-position arrays
         (clamped to the last row: rows past ``max_len`` are trash rows no
-        live query attends), steps a fixed-depth state once, and returns
-        float32 logits ``(slots, vocab)``. Attention over a per-position
-        array goes through :func:`.blocks.over_filled_rows`, so that a
-        step reads the prefix of the rows its positions fill and not all
-        ``max_len``. The single-token body runs it once, the speculative
+        live query attends), writes row ``pos % rows`` of a ring, steps
+        any other fixed-depth state once, and returns float32 logits
+        ``(slots, vocab)``. Attention over a per-position array or a ring
+        goes through :func:`.blocks.over_filled_rows` (one call a depth),
+        so that a step reads the prefix of the rows its positions fill
+        and not all ``max_len``. The single-token body runs it once, the speculative
         verify body unrolls it over the window."""
         raise NotImplementedError
 

@@ -832,6 +832,11 @@ class DecodeLoop(object):
             self._rows = max([a.depth(positions) for a in spec.values()
                               if a.rows is PER_POSITION] or [0])
             self._ladder = rows_ladder(self._rows)
+            #: the same of a RING (rows addressed by ``pos % depth``), 0
+            #: without one: its ladder is climbed until the ring is full
+            self._ring_rows = max([a.depth(positions)
+                                   for a in spec.values() if a.ring] or [0])
+            self._ring_ladder = rows_ladder(self._ring_rows)
             self._state_arrays = {
                 k: [int(v.shape[0]), int(v.shape[2]), int(v.shape[3]),
                     str(v.dtype), int(v.nbytes)]
@@ -1496,7 +1501,10 @@ class DecodeLoop(object):
         prefix of the cache's rows its attention covered, as the program
         picked it from the ``pos`` it was fed (:func:`.blocks.filled_rung`;
         pass j of a speculative window's ``passes`` stands j deeper),
-        summed over the passes; 0 for a model without such a cache."""
+        summed over the passes; 0 for a model without such a cache.
+        ``ring_rows``, of an architecture with a ring only: the same over
+        the ring's own ladder, its whole depth once the deepest slot has
+        wrapped it (a ring refuses speculation: one pass)."""
         sampled = int((a["temp"] > 0).sum())
         top = int(a["pos"].max())
         rows = sum(rows_covered(self._ladder, top + j)
@@ -1504,6 +1512,12 @@ class DecodeLoop(object):
         sp.set(sampled=sampled, ahead=ahead, rows=rows)
         self.health.record_decode_step(emitted, prompt, sampled, ahead,
                                        rows, passes * self._rows)
+        if self._ring_rows:
+            ring = rows_covered(self._ring_ladder, top)
+            sp.set(ring_rows=ring)
+            self.health.record_ring_step(
+                ring, self._ring_rows,
+                int((a["pos"][a["live"]] >= self._ring_rows).sum()))
 
     def _retire(self, i):
         slot = self._slots[i]

@@ -57,38 +57,14 @@ from .arch import PER_POSITION, Architecture, SlotArray
 # shared with serving/lfm2.py (these names stay this module's too)
 from .blocks import (ExpertShare, held_experts, held_weights,  # noqa: F401
                      linear, moe_counters, over_filled_rows, record_moe,
-                     rms_norm, route, routed_share, swiglu)
+                     rms_norm, route, routed_share, swiglu, validate_share,
+                     yarn_inv_freq)
 
 _KEYS = ("hidden_size", "num_attention_heads", "q_lora_rank", "kv_lora_rank",
          "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
          "intermediate_size", "moe_intermediate_size", "num_experts_per_tok",
          "n_shared_experts", "n_routed_experts", "first_k_dense_replace",
          "num_hidden_layers", "vocab_size")
-
-
-def yarn_inv_freq(dim, theta, scaling=None):
-    """The ``dim / 2`` inverse frequencies of the rotary pairs (float64):
-    plain ``theta^(-2i/dim)`` without ``scaling``; with it (``type: yarn``)
-    the blend of those with the same over ``factor``, by a linear ramp
-    between the pairs that turn ``beta_fast`` and ``beta_slow`` times over
-    ``original_max_position_embeddings``."""
-    extra = 1.0 / float(theta) ** (np.arange(0, dim, 2, dtype=np.float64)
-                                   / dim)
-    if not scaling:
-        return extra
-    orig = float(scaling["original_max_position_embeddings"])
-
-    def pair_of(turns):
-        return dim * math.log(orig / (turns * 2 * math.pi)) \
-            / (2 * math.log(float(theta)))
-
-    low = max(math.floor(pair_of(float(scaling["beta_fast"]))), 0)
-    high = min(math.ceil(pair_of(float(scaling["beta_slow"]))), dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
-                   / (high - low), 0.0, 1.0)
-    return extra / float(scaling["factor"]) * ramp + extra * (1.0 - ramp)
 
 
 def yarn_mscale(factor, m):
@@ -248,25 +224,7 @@ class DeepseekV3Arch(Architecture):
 
     def validate(self, host_params, max_len, mesh, quant_mode, spec_k=0,
                  prefix_cache=False):
-        if mesh is not None:
-            raise MXNetError(
-                "DecodeLoop: no model mesh over the %s architecture yet — "
-                "its expert layer has no 'expert' mesh axis and no "
-                "exchange (ROADMAP); serve it on one chip" % self.name)
-        if quant_mode == "int8":
-            raise MXNetError(
-                "DecodeLoop: quantize='int8' is not implemented for the %s "
-                "architecture (none and bf16 are)" % self.name)
-        for name, shape in self.param_shapes().items():
-            if name not in host_params:
-                raise MXNetError(
-                    "DecodeLoop: params missing %r — expected the "
-                    "serving/deepseek_v3.py parameter naming" % name)
-            got = tuple(np.shape(host_params[name]))
-            if got != tuple(shape):
-                raise MXNetError(
-                    "DecodeLoop: %r has shape %s, the %s config gives %s"
-                    % (name, got, self.name, tuple(shape)))
+        validate_share(self, "deepseek_v3", host_params, mesh, quant_mode)
         return self.vocab_size
 
     def slot_state(self, host_params, quant_mode):

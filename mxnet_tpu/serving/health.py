@@ -42,6 +42,16 @@ class ServingHealth(object):
         self.cache_rows_allocated = 0   # the same had each read every row:
         #                            read / allocated is the share of the
         #                            cache a loop's attention touches
+        self.ring_rows_read = 0    # rows of a ring (a sliding window's K
+        #                            and V: a slot's, a layer's) the steps'
+        #                            attention covered; these three are
+        #                            reported by a loop with a ring only
+        self.ring_rows_allocated = 0    # the same had each read the whole
+        #                            ring
+        self.ring_wrapped_slot_steps = 0   # slot-steps dispatched at a
+        #                            position at or past the ring's depth:
+        #                            where a window layer reads fewer rows
+        #                            than a full one would
         self.trash_slot_steps = 0  # slot-steps dispatched for a request
         #                            whose eos was learned a step late:
         #                            their tokens were dropped
@@ -127,6 +137,18 @@ class ServingHealth(object):
             self._parent.record_decode_step(emitted, prompt, sampled, ahead,
                                             rows, allocated)
 
+    def record_ring_step(self, rows, allocated, wrapped):
+        """One decode step DISPATCHED by a loop whose architecture keeps a
+        ring: its window layers' attention covered ``rows`` of the
+        ``allocated`` rows of a slot's ring in a layer, and ``wrapped`` of
+        its live slots stood at a position past the ring's depth."""
+        with self._lock:
+            self.ring_rows_read += int(rows)
+            self.ring_rows_allocated += int(allocated)
+            self.ring_wrapped_slot_steps += int(wrapped)
+        if self._parent is not None:
+            self._parent.record_ring_step(rows, allocated, wrapped)
+
     def record_tokens(self, emitted, trash=0):
         """A run-ahead step read back: ``emitted`` tokens handed to their
         requests, ``trash`` slot-steps whose token was dropped."""
@@ -177,6 +199,10 @@ class ServingHealth(object):
         for fn in sources:
             fn()
         with self._lock:
+            ring = {} if not self.ring_rows_allocated else {
+                "ring_rows_read": self.ring_rows_read,
+                "ring_rows_allocated": self.ring_rows_allocated,
+                "ring_wrapped_slot_steps": self.ring_wrapped_slot_steps}
             return {
                 "requests": self.requests, "batches": self.batches,
                 "examples": self.examples, "padded": self.padded,
@@ -189,7 +215,7 @@ class ServingHealth(object):
                 "steps_ahead": self.steps_ahead,
                 "cache_rows_read": self.cache_rows_read,
                 "cache_rows_allocated": self.cache_rows_allocated,
-                "trash_slot_steps": self.trash_slot_steps,
+                "trash_slot_steps": self.trash_slot_steps, **ring,
                 "joined": self.joined,
                 "retired": self.retired, "requeued": self.requeued,
                 "prefix_hits": self.prefix_hits,
@@ -212,6 +238,8 @@ class ServingHealth(object):
             self.sampled_steps = self.steps_ahead = 0
             self.cache_rows_read = self.cache_rows_allocated = 0
             self.trash_slot_steps = 0
+            self.ring_rows_read = self.ring_rows_allocated = 0
+            self.ring_wrapped_slot_steps = 0
             self.joined = self.retired = self.requeued = 0
             self.prefix_hits = self.prefix_prefills = 0
             self.spec_rounds = self.spec_drafted = self.spec_accepted = 0

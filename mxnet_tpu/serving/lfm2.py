@@ -43,10 +43,11 @@ prefix cache (a slab implanted at a shorter length than it was cut at)
 would both leave the state at the wrong position: ``validate`` refuses
 them.
 
-**Attention.** 32 query heads over 8 K/V heads of 64: RMSNorm over each
-head's q and k, rotary positions over the whole head in HALVES (pairs ``(i,
-i + head_dim / 2)``, ``rotate_half``; :func:`.deepseek_v3.rope` rotates
-interleaved pairs, which is not this convention). The cache keeps the K/V
+**Attention.** 32 query heads over 8 K/V heads of 64
+(:func:`.blocks.gqa_attention`): RMSNorm over each head's q and k, rotary
+positions over the whole head in HALVES (:func:`.blocks.rope_half`: pairs
+``(i, i + head_dim / 2)``, ``rotate_half``; :func:`.deepseek_v3.rope`
+rotates interleaved pairs, which is not this convention). The cache keeps the K/V
 heads folded into the minor dimension (512 lanes: whole tiles, no padding,
 PERF.md PR 28), and it is never reshaped into heads: each query head is laid
 into the lanes of ITS K/V head, zeros elsewhere, so that the scores are one
@@ -68,8 +69,9 @@ import numpy as np
 
 from ..base import MXNetError
 from .arch import PER_POSITION, Architecture, SlotArray
-from .blocks import (ExpertShare, linear, moe_counters, over_filled_rows,
-                     record_moe, rms_norm, routed_share, swiglu)
+from .blocks import (ExpertShare, gqa_attention, linear, moe_counters,
+                     over_filled_rows, record_moe, rms_norm, rope_half,
+                     routed_share, swiglu, validate_share)
 
 _KEYS = ("hidden_size", "num_attention_heads", "num_key_value_heads",
          "num_hidden_layers", "vocab_size", "intermediate_size",
@@ -78,41 +80,6 @@ _KEYS = ("hidden_size", "num_attention_heads", "num_key_value_heads",
 #: the normaliser's epsilon of the published ``lfm2_moe`` router
 ROUTE_EPS = 1e-6
 LAYER_TYPES = ("conv", "full_attention")
-
-
-def rope_half(x, cos, sin):
-    """Rotate the pairs ``(x[i], x[i + d/2])`` of the minor dimension by
-    the angles whose cos and sin are given per pair (``rotate_half``)."""
-    import jax.numpy as jnp
-    half = x.shape[-1] // 2
-    a, b = x[..., :half], x[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-
-
-def gqa_attention(q, krows, vrows, tmask, scale):
-    """Grouped-query attention of one position per slot: ``q`` (slots,
-    heads, head_dim) float32 over ``krows``/``vrows`` (slots, rows, kv_heads
-    * head_dim) as stored, float32 softmax under ``tmask`` (slots, rows);
-    query head ``h`` attends K/V head ``h // (heads / kv_heads)``. Returns
-    (slots, heads * head_dim) float32."""
-    import jax
-    import jax.numpy as jnp
-    f32 = jnp.float32
-    op = krows.dtype
-    nslots, heads, d = q.shape
-    groups = krows.shape[-1] // d
-    # own[h, g]: query head h reads the lanes of K/V head g
-    own = (jnp.arange(heads)[:, None] // (heads // groups)
-           == jnp.arange(groups)[None, :]).astype(f32)[None, :, :, None]
-    qb = (q[:, :, None, :] * own).reshape(nslots, heads, groups * d)
-    s = jnp.einsum("shc,stc->sht", qb.astype(op), krows,
-                   preferred_element_type=f32) * f32(scale)
-    s = jnp.where(tmask[:, None, :], s, f32(-1e30))
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("sht,stc->shc", w.astype(op), vrows,
-                   preferred_element_type=f32)
-    o = jnp.sum(o.reshape(nslots, heads, groups, d) * own, axis=2)
-    return o.reshape(nslots, heads * d)
 
 
 def short_conv(u, prev, weight, pos):
@@ -243,15 +210,6 @@ class Lfm2Arch(Architecture):
 
     def validate(self, host_params, max_len, mesh, quant_mode, spec_k=0,
                  prefix_cache=False):
-        if mesh is not None:
-            raise MXNetError(
-                "DecodeLoop: no model mesh over the %s architecture yet — "
-                "its expert layer has no 'expert' mesh axis and no "
-                "exchange (ROADMAP); serve it on one chip" % self.name)
-        if quant_mode == "int8":
-            raise MXNetError(
-                "DecodeLoop: quantize='int8' is not implemented for the %s "
-                "architecture (none and bf16 are)" % self.name)
         if spec_k:
             raise MXNetError(
                 "DecodeLoop: spec_k=%d over the %s architecture — a "
@@ -266,16 +224,7 @@ class Lfm2Arch(Architecture):
                 "cut at, not of the prefix's end, so a shorter implant "
                 "would be wrong (ROADMAP: a snapshot per cached prefix); "
                 "pass prefix_cache=False" % self.name)
-        for name, shape in self.param_shapes().items():
-            if name not in host_params:
-                raise MXNetError(
-                    "DecodeLoop: params missing %r — expected the "
-                    "serving/lfm2.py parameter naming" % name)
-            got = tuple(np.shape(host_params[name]))
-            if got != tuple(shape):
-                raise MXNetError(
-                    "DecodeLoop: %r has shape %s, the %s config gives %s"
-                    % (name, got, self.name, tuple(shape)))
+        validate_share(self, "lfm2", host_params, mesh, quant_mode)
         return self.vocab_size
 
     def compiler_options(self, platform):

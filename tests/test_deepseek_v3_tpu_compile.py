@@ -28,6 +28,12 @@ makes nothing larger than its prefix, and that LFM2's step stays under a
 stated number of device-visible instructions (a traced run's cost is
 quadratic in them, PERF.md PR 34).
 
+From PR 36 the Mellum step at published widths, its first period (three
+sliding-window layers over a RING of 1024 rows and one full layer over
+4096 rows a position, 16 of 64 experts held, 32 slots): both K/V arrays
+pass through without a copy though the ring is written at ``pos % 1024``,
+and each kind's attention is a conditional over ITS OWN ladder.
+
 The topology is described inside a fixture (only one process may load the
 TPU's library; a worker that cannot skips), and this is the one file that
 does so."""
@@ -40,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 import chip_smoke
-from mxnet_tpu.serving import blocks, decode, deepseek_v3, lfm2
+from mxnet_tpu.serving import blocks, decode, deepseek_v3, lfm2, mellum
 
 SLOTS, ROWS = 64, 1024
 ITEM = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
@@ -434,3 +440,87 @@ def test_lfm2_step_stays_under_its_count_of_device_instructions(lfm2_step):
     _, compiled = lfm2_step
     comps, entry = fc._parse_computations(compiled.as_text())
     assert 250 < _device_visible(comps, entry) <= 310
+
+
+# ---------------------------------------------------------------------------
+# a ring beside a per-position cache (PR 36)
+# ---------------------------------------------------------------------------
+
+MELLUM_ROPE = {
+    "full_attention": {"rope_type": "yarn", "rope_theta": 500000,
+                       "factor": 16, "beta_fast": 32, "beta_slow": 1,
+                       "original_max_position_embeddings": 8192,
+                       "attention_factor": 1.2772588722239782},
+    "sliding_attention": {"rope_type": "default", "rope_theta": 500000}}
+#: Mellum2-12B-A2.5B's published widths, its first period of layers
+MELLUM_DEPTH4 = dict(
+    hidden_size=2304, num_attention_heads=32, num_key_value_heads=4,
+    head_dim=128, num_hidden_layers=4, vocab_size=98304,
+    moe_intermediate_size=896, num_experts=16, num_experts_per_tok=8,
+    router_width=64, share_index=0, sliding_window=1024,
+    rms_norm_eps=1e-6, norm_topk_prob=True, tie_word_embeddings=False,
+    layer_types=["sliding_attention"] * 3 + ["full_attention"],
+    mlp_layer_types=["sparse"] * 4, rope_parameters=MELLUM_ROPE)
+MELLUM_SLOTS, MELLUM_ROWS = 32, 4096
+
+
+@pytest.fixture(scope="module")
+def mellum_step(one_chip):
+    arch = mellum.MellumArch(MELLUM_DEPTH4)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {k: s(v, jnp.bfloat16) for k, v in arch.param_shapes().items()}
+    state = {k: s((a.layers, MELLUM_SLOTS, a.depth(MELLUM_ROWS), a.width),
+                  a.dtype)
+             for k, a in arch.slot_state(None, "bf16").items()}
+    state.update(seed=s((MELLUM_SLOTS,), np.uint32),
+                 tok=s((MELLUM_SLOTS,), np.int32))
+    state.update({k: s(v, np.int32) for k, v in arch.counters().items()})
+    feed = [s((MELLUM_SLOTS,), d) for d in (np.int32, np.int32, np.float32,
+                                            np.int32, np.float32, np.uint32,
+                                            np.bool_, np.bool_)]
+    fn = jax.jit(decode._build_decode_fn(arch), donate_argnums=(0,))
+    return state, fn.lower(state, params, *feed).compile(
+        compiler_options=arch.compiler_options("tpu"))
+
+
+def test_mellum_the_ring_and_the_rows_pass_through_without_a_copy(
+        mellum_step):
+    state, compiled = mellum_step
+    assert state["k_win"].shape == (3, MELLUM_SLOTS, 1024, 512)
+    assert state["k"].shape == (1, MELLUM_SLOTS, 4096, 512)
+    ring = MELLUM_SLOTS * 1024 * 512 * 2          # one layer's ring, bf16
+    moved = [i for i in _top_level(compiled)
+             if i[3] in ("copy", "transpose") and i[1] == "bf16"
+             and i[2] >= ring // 4]
+    assert moved == []
+    # no float32 copy of an expert stack
+    big = [i for i in _top_level(compiled)
+           if i[1] == "f32" and i[2] >= 4 * 896 * 2304 * 4]
+    assert [i for i in big if "copy" in i[3] or "convert" in i[3]] == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * (3 * ring + 4 * ring)
+    assert mem.temp_size_in_bytes < 0.1e9
+
+
+def test_mellum_each_kind_attends_a_prefix_by_its_own_ladder(mellum_step):
+    _, compiled = mellum_step
+    ring, rows = blocks.rows_ladder(1024), blocks.rows_ladder(4096)
+    assert (ring, rows) == ((256, 512, 1024), (256, 512, 1024, 2048, 4096))
+    _holds_the_prefix_alone(compiled, ring, MELLUM_SLOTS, 512, 3)
+    _holds_the_prefix_alone(compiled, rows, MELLUM_SLOTS, 512, 1)
+
+
+def test_mellum_step_stays_under_its_count_of_device_instructions(
+        mellum_step):
+    """The harness's gap attribution is gaps x spans (PERF.md PR 34, §7 j):
+    with no fetch ahead the 28-layer step showed 1,591 device events on the
+    chip and its traced run is predicted at 850 s of the driver's 1200 (PR
+    36); with one in flight 2,033 and 1100 s, left alone 3,501, and the
+    run does not end. One period of four layers shows about 300 here."""
+    from mxnet_tpu import flopcheck as fc
+    _, compiled = mellum_step
+    comps, entry = fc._parse_computations(compiled.as_text())
+    assert 250 < _device_visible(comps, entry) <= 320
