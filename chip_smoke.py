@@ -57,6 +57,10 @@ DEPLOY_ATOL = 1e-5
 #: the v5e: all 12 first tokens agree, down to a margin of 0.033, and 9 of
 #: the 12 prompts clear 0.1 (chip run, PR 21)
 DECODE_MARGIN = 0.1
+#: the most instructions that may take one weight matrix of the prefill
+#: program (``weight_reads``): the chip's compiler fetches a matrix in
+#: four pieces (chip run, PR 37); a chunk has 128 positions
+WEIGHT_PIECES = 8
 #: Kimi-K2-Instruct's published widths (``config.json``), cut in depth, in
 #: experts held and in vocabulary as ``benchmark/configs/kimi-k2-ep32.json``
 #: cuts them, and to depth 2 here: the dense layer and one expert layer
@@ -340,6 +344,22 @@ def cache_relayouts(compiled, name, cache_bytes):
     return found, facts
 
 
+def weight_reads(compiled):
+    """``{parameter: instructions of the entry computation that take it}``
+    for every matrix among an executable's parameters, from its own text.
+    A pass that batches its positions takes a weight in ONE product, or in
+    the few pieces the chip's compiler fetches it into fast memory by
+    (quarters: four); one unrolled over its positions takes it once a
+    position."""
+    import re
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    names = re.findall(r"%(params__\w+?weight\w*\.\d+) = \w+\[\d+,\d+\]\S* "
+                       r"parameter\(", entry)
+    return {n: len(re.findall(r"%" + re.escape(n) + r"\b", entry)) - 1
+            for n in names}
+
+
 def decode_leg(meter, context, layers, embed, heads, vocab,
                max_len, slots=8, requests=12, prompt_range=(32, 128),
                max_new=32, margin=DECODE_MARGIN):
@@ -358,17 +378,35 @@ def decode_leg(meter, context, layers, embed, heads, vocab,
 
     loop = serving.DecodeLoop(params, layers, heads, max_len, slots=slots)
     try:
+        cache = int(loop._state["k"].nbytes)
         relaid, step_facts = cache_relayouts(
-            loop._step_c, loop.name + "/step",
-            int(loop._state["k"].nbytes))
+            loop._step_c, loop.name + "/step", cache)
+        # the prefill program holds the same rule, and reads its weights
+        # once a chunk: a window unrolled position by position (the verify
+        # body's form) would read them once a position
+        again, prefill_facts = cache_relayouts(
+            loop._prefill_c, loop.name + "/prefill", cache)
+        relaid += again
+        reads = weight_reads(loop._prefill_c)
+        prefill_facts.update(chunk=loop._chunk, weights=len(reads),
+                             weight_reads_max=max(reads.values()))
         futures = [loop.generate(p, max_new) for p in prompts]
         outs = [f.result(timeout=900.0) for f in futures]
     finally:
         loop.close()
     if relaid:
-        raise AssertionError("the step program re-lays the KV cache out: "
+        raise AssertionError("a decode program re-lays the KV cache out: "
                              "%s" % "; ".join(relaid))
+    if prefill_facts["weights"] < 4 * (layers - 1) + 3 \
+            or prefill_facts["weight_reads_max"] > WEIGHT_PIECES:
+        raise AssertionError("the prefill program does not read its weights "
+                             "once a chunk: %r" % (reads,))
     health = loop.health.report()
+    fed = sum(len(p) - 1 for p in prompts)
+    if health["prompt_positions"] != fed \
+            or not 0 < health["prefill_positions"] <= fed \
+            or not 0 < health["prefill_passes"] < health["decode_steps"]:
+        raise AssertionError("prompts were not fed in chunks: %r" % (health,))
     if not (health["joined"] == health["retired"] == requests):
         raise AssertionError("join/retire mismatch: %r" % (health,))
     if health["errors"] or health["shed"] or loop.dead is not None:
@@ -418,6 +456,10 @@ def decode_leg(meter, context, layers, embed, heads, vocab,
              "slots": slots, "requests": requests, "max_new": max_new,
              "prompt_lens": [len(p) for p in prompts],
              "decode_steps": health["decode_steps"],
+             "prefill_passes": health["prefill_passes"],
+             "prefill_positions": health["prefill_positions"],
+             "prompt_positions": health["prompt_positions"],
+             "prefill_program": prefill_facts,
              "first_token_checked": asserted,
              "first_token_agrees": int(agree), "margins": margins,
              "margin_tolerance": margin, "step_program": step_facts,

@@ -4,8 +4,9 @@
 The loop owns everything a model does not: slots, admission, the feed, the
 AOT step program, sampling, readback, the prefix cache, speculation. A
 model is an :class:`Architecture`: how its parameters are validated, which
-arrays hold a slot's state, and how ONE position per slot goes through its
-layers. The default is :class:`~mxnet_tpu.serving.decode.OptArch`
+arrays hold a slot's state, how ONE position per slot goes through its
+layers, and (where it can) how a CHUNK of one slot's prompt does. The
+default is :class:`~mxnet_tpu.serving.decode.OptArch`
 (``models/transformer.py``: K and V rows);
 :class:`~mxnet_tpu.serving.deepseek_v3.DeepseekV3Arch` keeps latent rows
 and routing counters; :class:`~mxnet_tpu.serving.lfm2.Lfm2Arch` keeps K and
@@ -139,6 +140,21 @@ class Architecture(object):
         and not all ``max_len``. The single-token body runs it once, the speculative
         verify body unrolls it over the window."""
         raise NotImplementedError
+
+    def build_prefill_pass(self, mesh=None):
+        """``prefill_pass(state, params, tokens (C,), slot, pos0, n) ->
+        state``: up to ``C`` prompt positions of ONE slot through the
+        layers as one batched forward (the weights read once a chunk, not
+        once a position), or ``None``: this architecture is fed one
+        position a step. ``state`` holds the arrays of ``slot_state``;
+        the pass writes rows ``pos0 .. pos0 + n - 1`` of slot ``slot`` in
+        place and NOTHING else (rows ``n .. C - 1`` of ``tokens`` are
+        padding, also where ``pos0 + C`` passes the arrays' depth), with
+        causal attention inside the chunk and over the slot's rows ``<
+        pos0`` (a prefix-cache hit starts past 0), at the token pass's
+        precision. No head, no sampler, nothing to read back: the
+        prompt's last token goes through the ordinary step."""
+        return None
 
     def record_counters(self, health, counts, before):
         """Bring ``health`` (:class:`ServingHealth`) up to date from the

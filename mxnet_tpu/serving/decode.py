@@ -15,9 +15,17 @@ dispatch substrate PR 1/PR 4 built for training:
   (state, next_tokens)`` whose buffers are reused in place, exactly like
   the train step's donated parameter state;
 * sequences occupy SLOTS: a new request joins any free slot mid-stream
-  (its prompt is teacher-forced through the same decode body, one token
-  per step, overwriting whatever the retired occupant left in the cache —
-  positions past ``pos`` are masked, so stale rows are unreachable);
+  (its prompt is teacher-forced into the slot's rows, overwriting whatever
+  the retired occupant left in the cache — positions past ``pos`` are
+  masked, so stale rows are unreachable);
+* a prompt goes in by CHUNKS where the architecture supplies a prefill
+  pass (:class:`OptArch`; docs/serving.md "The prefill pass"): a second
+  AOT program ``(state, params, tokens, slot, pos0, n) -> state`` writes
+  up to :data:`PREFILL_CHUNK` positions of one slot as ONE batched
+  forward, at most one pass a step, dispatched ahead of the step with
+  nothing to read back; only the prompt's last token goes through the
+  decode body. Elsewhere (the other architectures, a speculative loop, a
+  model mesh) the prompt rides the decode body one token per step;
 * the host only supplies prompt tokens and reads back the SAMPLED token
   ids (one (slots,) int32 readback per step — smaller than the logits
   readback it replaced);
@@ -103,6 +111,30 @@ def _ln(x, gamma, beta):
     mean = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
     return (x - mean) * jax.lax.rsqrt(var + jnp.float32(1e-5)) * gamma + beta
+
+
+def _qkv(x, params, pre):
+    """Layer ``pre``'s pre-LN and packed q, k, v projection of the rows
+    ``x``: ``(rows, 3 * embed)``, q first."""
+    a = _ln(x, params[pre + "_ln1_gamma"], params[pre + "_ln1_beta"])
+    return a @ params[pre + "_attn_qkv_weight"].T \
+        + params[pre + "_attn_qkv_bias"]
+
+
+def _attn_out(o, params, pre):
+    return o @ params[pre + "_attn_out_weight"].T \
+        + params[pre + "_attn_out_bias"]
+
+
+def _mlp(x, params, pre):
+    """Layer ``pre``'s pre-LN ReLU feed-forward of the rows ``x`` (the
+    caller adds it to the residual stream)."""
+    import jax.numpy as jnp
+    f = _ln(x, params[pre + "_ln2_gamma"], params[pre + "_ln2_beta"])
+    f = jnp.maximum(f @ params[pre + "_ffn_fc1_weight"].T
+                    + params[pre + "_ffn_fc1_bias"], jnp.float32(0.0))
+    return f @ params[pre + "_ffn_fc2_weight"].T \
+        + params[pre + "_ffn_fc2_bias"]
 
 
 def _build_token_pass(num_layers, num_heads, mesh=None):
@@ -208,11 +240,7 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
         for i in range(num_layers):
             pre = "layer%d" % i
             with jax.named_scope("layer/attn"):
-                a = _ln(x, params[pre + "_ln1_gamma"],
-                        params[pre + "_ln1_beta"])
-                qkv = a @ params[pre + "_attn_qkv_weight"].T \
-                    + params[pre + "_attn_qkv_bias"]
-                qkv = qkv.reshape(nslots, 3, embed)
+                qkv = _qkv(x, params, pre).reshape(nslots, 3, embed)
                 q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]   # (slots, H * D)
             with jax.named_scope("cache_write"):
                 ck = ck.at[i, sidx, wpos].set(k)
@@ -225,24 +253,104 @@ def _build_token_pass(num_layers, num_heads, mesh=None):
                     return jnp.sum(heads_spread(w) * vrows, axis=1)
 
                 o = over((ck, cv), i, attend)
-                o = o @ params[pre + "_attn_out_weight"].T \
-                    + params[pre + "_attn_out_bias"]
-                x = edge(x + o)
+                x = edge(x + _attn_out(o, params, pre))
             with jax.named_scope("layer/mlp"):
-                f = _ln(x, params[pre + "_ln2_gamma"],
-                        params[pre + "_ln2_beta"])
-                f = jnp.maximum(
-                    f @ params[pre + "_ffn_fc1_weight"].T
-                    + params[pre + "_ffn_fc1_bias"], jnp.float32(0.0))
-                f = f @ params[pre + "_ffn_fc2_weight"].T \
-                    + params[pre + "_ffn_fc2_bias"]
-                x = edge(x + f)
+                x = edge(x + _mlp(x, params, pre))
         with jax.named_scope("head"):
             x = _ln(x, params["final_ln_gamma"], params["final_ln_beta"])
             logits = x @ params["lm_head_weight"].T + params["lm_head_bias"]
         return ck, cv, logits
 
     return token_pass
+
+
+def _build_prefill_pass(num_layers, num_heads):
+    """Up to ``C`` prompt positions of ONE slot through the layers as one
+    batched forward: the token pass's mathematics over a chunk's rows, so
+    that a layer's weights are read once a chunk and not once a position.
+
+    Layer by layer the chunk's K and V rows go into the slot's rows ``pos0
+    .. pos0 + n - 1`` of the donated arrays, in place, and its queries then
+    attend that slot's rows as the arrays now hold them: query ``c`` the
+    rows ``<= pos0 + c``, which are the rows a prefix-cache hit implanted
+    below ``pos0`` and the chunk's own up to it. The LAST layer stops at
+    its K and V: there is no head, no sampler and nothing to read back,
+    the prompt's last token goes through the ordinary step.
+
+    THE SAME PRECISION as the token pass: the weight products are ``a @
+    W.T`` at the default precision, and the per-head score and mix
+    products run at ``HIGHEST``, float32 products as the token pass's
+    multiply-and-sum makes them, so a chunk rounds nothing to bfloat16
+    that a step does not. For them ONE slot's rows are laid out per head
+    (``rows x width`` float32, a few MB a layer); the donated arrays keep
+    their layout and no copy the size of a cache is made.
+
+    A PADDED CHUNK MOVES NOTHING. Rows ``n .. C - 1`` of ``tokens`` are
+    padding, and a ``dynamic_update_slice`` of all ``C`` rows would clamp
+    its start where ``pos0 + C`` passes the arrays' depth and land on live
+    rows. The write is a scatter of rows instead, padding rows sent out of
+    bounds and DROPPED: rows ``pos0 .. pos0 + n - 1`` of the one slot
+    change and no other row of any slot (on the chip the scatter costs
+    0.7 ms of a 9.1 ms pass over a masked window update, which reads the
+    rows it keeps BEFORE it writes and so makes XLA:CPU copy both caches;
+    PERF.md, PR 37)."""
+    import jax
+    import jax.numpy as jnp
+
+    highest = jax.lax.Precision.HIGHEST
+
+    def prefill_pass(ck, cv, params, tokens, slot, pos0, n):
+        chunk = tokens.shape[0]
+        rows = ck.shape[2]
+        cpos = pos0 + jnp.arange(chunk, dtype=jnp.int32)   # positions
+        with jax.named_scope("embed"):
+            x = params["tok_embed_weight"][tokens] \
+                + params["pos_embed_weight"][
+                    jnp.minimum(cpos, jnp.int32(rows - 1))]
+        embed = x.shape[1]
+        d = embed // num_heads
+        scale = jnp.float32(1.0 / float(np.sqrt(d)))
+        neg = jnp.float32(-1e30)
+        # the rows the chunk writes: its padding goes out of bounds (each
+        # row its own way out: the indices stay sorted and unique)
+        at = jnp.arange(chunk, dtype=jnp.int32)
+        wrows = jnp.where(at < n, cpos, jnp.int32(rows) + at)
+        mask = (jnp.arange(rows, dtype=jnp.int32)[None, :]
+                <= cpos[:, None])[None]          # (1, C, rows)
+
+        def write(cache, i, new):
+            return cache.at[i, slot, wrows].set(
+                new, mode="drop", indices_are_sorted=True,
+                unique_indices=True)
+
+        def per_head(cache, i):     # the slot's rows: (heads, rows, d)
+            mine = jax.lax.dynamic_slice(cache, (i, slot, 0, 0),
+                                         (1, 1, rows, embed))
+            return mine.reshape(rows, num_heads, d).transpose(1, 0, 2)
+
+        for i in range(num_layers):
+            pre = "layer%d" % i
+            with jax.named_scope("layer/attn"):
+                qkv = _qkv(x, params, pre).reshape(chunk, 3, embed)
+                q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]   # (C, H * D)
+            with jax.named_scope("cache_write"):
+                ck = write(ck, i, k)
+                cv = write(cv, i, v)
+            if i + 1 == num_layers:
+                break       # nothing reads the last layer's output
+            with jax.named_scope("layer/attn"):
+                s = jnp.einsum("chd,htd->hct",
+                               q.reshape(chunk, num_heads, d),
+                               per_head(ck, i), precision=highest) * scale
+                w = jax.nn.softmax(jnp.where(mask, s, neg), axis=-1)
+                o = jnp.einsum("hct,htd->chd", w, per_head(cv, i),
+                               precision=highest).reshape(chunk, embed)
+                x = x + _attn_out(o, params, pre)
+            with jax.named_scope("layer/mlp"):
+                x = x + _mlp(x, params, pre)
+        return ck, cv
+
+    return prefill_pass
 
 
 class OptArch(Architecture):
@@ -313,6 +421,18 @@ class OptArch(Architecture):
 
         return token_pass
 
+    def build_prefill_pass(self, mesh=None):
+        if mesh is not None:    # a sharded loop is fed one position a step
+            return None
+        inner = _build_prefill_pass(self.num_layers, self.num_heads)
+
+        def prefill_pass(state, params, tokens, slot, pos0, n):
+            ck, cv = inner(state["k"], state["v"], params, tokens, slot,
+                           pos0, n)
+            return {"k": ck, "v": cv}
+
+        return prefill_pass
+
 
 #: the members of the donated state that are the loop's own, beside the
 #: architecture's: each slot's seed, and the token the decode body last
@@ -357,6 +477,24 @@ def _build_decode_fn(arch, mesh=None):
         return dict(new, seed=seeds, tok=nxt), nxt
 
     return decode_fn
+
+
+def _build_prefill_fn(arch, mesh=None):
+    """The prefill body of an architecture that supplies a prefill pass
+    (``None`` for one that does not): a chunk of one slot's prompt into
+    the donated state, and nothing else. Returns the state alone: the
+    loop has nothing to read back and dispatches it between two steps
+    without waiting for either."""
+    prefill_pass = arch.build_prefill_pass(mesh=mesh)
+    if prefill_pass is None:
+        return None
+
+    def prefill_fn(state, params, tokens, slot, pos0, n):
+        return dict(state, **prefill_pass(_model_state(state),
+                                          arch.load(params), tokens, slot,
+                                          pos0, n))
+
+    return prefill_fn
 
 
 def _build_verify_fn(arch, window, mesh=None):
@@ -484,11 +622,12 @@ class _Slot(object):
     counts the host knows), ``emitted`` when that step's tokens are read
     back, one step later."""
 
-    __slots__ = ("fut", "pending", "pos", "next_token", "emitted", "sent",
-                 "reseed", "producing")
+    __slots__ = ("fut", "seat", "pending", "pos", "next_token", "emitted",
+                 "sent", "reseed", "producing")
 
-    def __init__(self, fut):
+    def __init__(self, fut, seat=0):
         self.fut = fut
+        self.seat = seat                  # the loop's n-th seating
         self.pending = list(fut.prompt)   # prompt tokens still to feed
         self.pos = 0                      # next cache write position
         #: the next input token where the host has it (a prompt's), else
@@ -502,6 +641,22 @@ class _Slot(object):
         self.producing = None             # (key, L): harvest prefix at L
 
 
+#: the positions of one slot's prompt a prefill pass takes (its chunk; the
+#: cache's depth where that is less). A pass reads the weights once
+#: whatever it holds (OPT-1.3B's float32 tree: 6.9 ms at the memory's
+#: rate) and its scores, heads x chunk x rows float32, several times a
+#: layer: over 768 rows it takes 7.9 / 9.1 / 12.8 ms at 64 / 128 / 256
+#: positions against a step's 8.2 (chip runs, PERF.md PR 37). 128 is
+#: within 1% of the best rate where prompts are short (16-48) and takes
+#: the chat mix's median prompt (96) in one pass, its longest (256) in
+#: two. A constant until a cell pays for a rule
+PREFILL_CHUNK = 128
+#: the fewest prompt positions worth a prefill pass. The pass holds every
+#: seated slot up for about one step's time (9.1 ms against 8.2) and saves
+#: its own slot a step a position: a saturated loop breaks even at as many
+#: positions as it has slots (8 in the OPT cells), a request alone at two.
+#: A shorter rest of a prompt rides the steps
+MIN_PREFILL = 8
 #: steps between two readings of an architecture's device counters in a
 #: TRACED run (each reading is a ``loop_counters`` span; never once a step)
 COUNTER_SPAN_STEPS = 32
@@ -658,6 +813,13 @@ class DecodeLoop(object):
             self._programs[pname] = (compiled, structs, donate)
             return compiled
 
+        #: the prefill program, where the architecture supplies a prefill
+        #: pass and the loop feeds prompts through it (not a speculative
+        #: loop, whose window takes ``spec_k + 1`` prompt positions a round
+        #: already; not over a model mesh); else every prompt position
+        #: rides a step
+        self._prefill_c = None
+        self._chunk = 0
         samp = self._sampling_structs(jax)
         live_s = (self._vec_struct(jax, (self.slots,), np.bool_),)
         state_s = self._tree_structs(jax, self._state)
@@ -685,6 +847,16 @@ class DecodeLoop(object):
                 (state_s, params_s) + samp + live_s * arch.wants_live,
                 (0,), arch.compiler_options(jax.default_backend()))
             self._jfn = self._jfns[-1]   # the main decode body
+            prefill = _build_prefill_fn(arch, mesh=self._mesh)
+            if prefill is not None:
+                self._chunk = min(PREFILL_CHUNK, self._rows)
+                scalar_s = self._vec_struct(jax, (), np.int32)
+                self._prefill_c = compile_one(
+                    "prefill[chunk=%d,len=%d]" % (self._chunk, self.max_len),
+                    prefill,
+                    (state_s, params_s,
+                     self._vec_struct(jax, (self._chunk,), np.int32),
+                     scalar_s, scalar_s, scalar_s), (0,))
         if self.prefix_enabled:
             slot_s = self._vec_struct(jax, (), np.int32)
             self._prefix_programs(compile_one, jax, "target", arch,
@@ -709,6 +881,7 @@ class DecodeLoop(object):
         self._closed = False
         self.dead = None
         self._steps = 0   # decode-step ordinal for the host trace
+        self._seated = 0  # requests seated so far: a slot's ``seat``
         #: the step dispatched and not read back yet: ``[its tokens on the
         #: device, [(slot index, _Slot, emits, last)]]``, or None
         self._inflight = None
@@ -1109,7 +1282,8 @@ class DecodeLoop(object):
             except queue.Empty:
                 break
             joined += 1
-            slot = _Slot(fut)
+            self._seated += 1
+            slot = _Slot(fut, self._seated)
             self._slots[i] = slot
             if self.prefix_enabled and fut.prefix_len > 0:
                 key = tuple(fut.prompt[:fut.prefix_len])
@@ -1265,6 +1439,41 @@ class DecodeLoop(object):
                 slot.reseed = False
         return arrs
 
+    def _prefill(self, sp):
+        """At most ONE prefill pass, dispatched ahead of the step: the
+        longest-seated slot with :data:`MIN_PREFILL` prompt positions or
+        more to feed BEFORE its prompt's last has up to ``chunk`` of them
+        written into its rows by the prefill program (``next_token`` and
+        what follows it in ``pending``, never ``pending``'s last: the
+        step feeds that one and samples the first token, as ever). All by
+        count: the pass hands nothing back, so the step behind it is
+        dispatched at once and the run-ahead is as it was. Returns the
+        positions the pass commits (0: none was due), which the step's
+        span and the counters take as prompt positions like any other."""
+        due = [i for i, slot in enumerate(self._slots)
+               if slot is not None and len(slot.pending) >= MIN_PREFILL]
+        if not due:
+            return 0
+        best = min(due, key=lambda i: self._slots[i].seat)
+        slot = self._slots[best]
+        pos0, n = slot.pos, min(self._chunk, len(slot.pending))
+        tokens = np.zeros(self._chunk, np.int32)
+        tokens[0] = slot.next_token
+        tokens[1:n] = slot.pending[:n - 1]
+        dev = self._dev([tokens, np.int32(best), np.int32(pos0),
+                         np.int32(n)])
+        with self._state_lock:
+            self._state = self._prefill_c(self._state, self._params, *dev)
+        del dev     # as in _step_inner: released while the device works
+        slot.pos += n
+        slot.next_token = slot.pending[n - 1]
+        del slot.pending[:n]
+        self.health.record_prefill(n)
+        self._maybe_harvest(best)
+        sp.lap("decode_prefill")
+        sp.set(prefill=[slot.fut.rid, best, pos0, n])
+        return n
+
     def _step_inner(self, sp):
         """One step, one step AHEAD of its readback: step n is fed and
         dispatched, THEN step n-1's tokens are read back and committed,
@@ -1275,11 +1484,14 @@ class DecodeLoop(object):
         is learned a step late (:meth:`_commit`).
 
         ``sp`` is the step's span (or the no-op): each phase ends in a lap
-        of it (docs/observability.md "Span catalogue"): ``decode_gather``,
-        ``decode_h2d``, ``decode_dispatch`` of step n, then
-        ``decode_readback``, ``decode_commit`` of step n-1 (of length 0
-        where no step is in flight: the first after an empty loop)."""
+        of it (docs/observability.md "Span catalogue"):
+        ``decode_prefill`` where a prefill pass went ahead of the step
+        (:meth:`_prefill`), ``decode_gather``, ``decode_h2d``,
+        ``decode_dispatch`` of step n, then ``decode_readback``,
+        ``decode_commit`` of step n-1 (of length 0 where no step is in
+        flight: the first after an empty loop)."""
         from .. import faults as _faults
+        chunked = self._prefill(sp) if self._prefill_c is not None else 0
         a = self._gather_sampling()
         sp.lap("decode_gather")
         _faults.fire("serve.sample")
@@ -1302,7 +1514,8 @@ class DecodeLoop(object):
         rows, prompt = self._schedule()
         self._inflight = [toks, rows]
         del toks
-        self._count_step(sp, a, 0, prompt, ahead=int(before is not None))
+        self._count_step(sp, a, 0, prompt + chunked,
+                         ahead=int(before is not None))
         sp.lap("decode_dispatch")
         self._commit(sp, before)
 

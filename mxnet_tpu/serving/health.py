@@ -29,7 +29,14 @@ class ServingHealth(object):
         self.decode_steps = 0      # continuous-batching decode iterations
         self.tokens_emitted = 0    # tokens decode steps handed to requests
         self.prompt_positions = 0  # cache positions committed that emitted
-        #                            none (a prompt being fed)
+        #                            none (a prompt being fed, by a step or
+        #                            by a prefill pass)
+        self.prefill_passes = 0    # prefill passes dispatched: chunks of
+        #                            one slot's prompt written into its rows
+        #                            between two decode steps
+        self.prefill_positions = 0  # prompt positions those passes
+        #                            committed: over prompt_positions, the
+        #                            share of prompts fed through a chunk
         self.sampled_steps = 0     # decode steps in which some row sampled
         #                            (temperature > 0): the steps that paid
         #                            for the in-graph sampler
@@ -137,6 +144,16 @@ class ServingHealth(object):
             self._parent.record_decode_step(emitted, prompt, sampled, ahead,
                                             rows, allocated)
 
+    def record_prefill(self, positions):
+        """One prefill pass DISPATCHED, which committed ``positions``
+        prompt positions of one slot (the step dispatched behind it counts
+        them among its ``prompt`` positions too)."""
+        with self._lock:
+            self.prefill_passes += 1
+            self.prefill_positions += int(positions)
+        if self._parent is not None:
+            self._parent.record_prefill(positions)
+
     def record_ring_step(self, rows, allocated, wrapped):
         """One decode step DISPATCHED by a loop whose architecture keeps a
         ring: its window layers' attention covered ``rows`` of the
@@ -211,6 +228,8 @@ class ServingHealth(object):
                 "decode_steps": self.decode_steps,
                 "tokens_emitted": self.tokens_emitted,
                 "prompt_positions": self.prompt_positions,
+                "prefill_passes": self.prefill_passes,
+                "prefill_positions": self.prefill_positions,
                 "sampled_steps": self.sampled_steps,
                 "steps_ahead": self.steps_ahead,
                 "cache_rows_read": self.cache_rows_read,
@@ -235,6 +254,7 @@ class ServingHealth(object):
             self.padded = self.expired = self.dropped = 0
             self.shed = self.errors = self.decode_steps = 0
             self.tokens_emitted = self.prompt_positions = 0
+            self.prefill_passes = self.prefill_positions = 0
             self.sampled_steps = self.steps_ahead = 0
             self.cache_rows_read = self.cache_rows_allocated = 0
             self.trash_slot_steps = 0

@@ -60,10 +60,18 @@ def test_train_leg_data_parallel_tiny(meter):
 def test_decode_leg_tiny(meter):
     facts = chip_smoke.decode_leg(
         meter, mx.cpu(0), layers=2, embed=32, heads=2, vocab=64,
-        max_len=48, slots=2, requests=3, prompt_range=(4, 8), max_new=4,
+        max_len=48, slots=2, requests=3, prompt_range=(10, 20), max_new=4,
         margin=0.01)
     assert facts["first_token_checked"] >= 1
     assert facts["decode_steps"] > 0
+    # prompts go in by chunks (PR 37), through a program that holds the
+    # step's rule and reads its weights once
+    assert 0 < facts["prefill_passes"] <= 3
+    assert facts["prefill_positions"] <= facts["prompt_positions"] \
+        == sum(facts["prompt_lens"]) - 3
+    assert facts["prefill_program"]["chunk"] == 48
+    assert facts["prefill_program"]["weight_reads_max"] == 1
+    assert facts["prefill_program"]["layout_bytes_max"] < 2 * 2 * 48 * 32 * 4
     # (layers, slots, rows, heads * head_dim) float32, no cache-sized copy
     assert facts["step_program"]["cache_bytes"] == 2 * 2 * 48 * 32 * 4
     assert facts["step_program"]["layout_bytes_max"] < 2 * 2 * 48 * 32 * 4

@@ -308,7 +308,9 @@ def test_the_verify_program_is_the_parents(window):
 
 def test_the_loop_builds_its_step_from_those_builders():
     """The loop's own state and feed are the frozen program's arguments:
-    K, V, seeds and the fed-back token, seven per-slot arrays, no eighth."""
+    K, V, seeds and the fed-back token, seven per-slot arrays, no eighth
+    (``tests/test_decode_prefill.py`` holds the prefill program to the
+    frozen pass)."""
     params = chip_smoke.lm_params(VOCAB, EMBED, HEADS, LAYERS, ROWS, seed=1)
     loop = decode.DecodeLoop(params, LAYERS, HEADS, ROWS, slots=SLOTS,
                              prefix_cache=False, spec_k=0)
@@ -316,8 +318,13 @@ def test_the_loop_builds_its_step_from_those_builders():
         assert sorted(loop._state) == ["k", "seed", "tok", "v"]
         assert isinstance(loop._arch, decode.OptArch)
         assert not loop._arch.wants_live and not loop._arch.counters()
-        (_, structs, donate), = loop._programs.values()
-        assert len(structs) == 9 and donate == (0,)
+        # beside the step, from PR 37, the prefill program: the same state
+        # and parameters, a chunk of tokens and three scalars
+        assert sorted(n.split("/")[1].split("[")[0]
+                      for n in loop._programs) == ["prefill", "step"]
+        for name, (_, structs, donate) in loop._programs.items():
+            assert donate == (0,)
+            assert len(structs) == (6 if "/prefill[" in name else 9)
         assert loop.counter_totals() == {}
     finally:
         loop.close()

@@ -34,6 +34,11 @@ sliding-window layers over a RING of 1024 rows and one full layer over
 pass through without a copy though the ring is written at ``pos % 1024``,
 and each kind's attention is a conditional over ITS OWN ladder.
 
+From PR 37 OPT's PREFILL program (a chunk of one slot's prompt through
+the layers as one batched forward) at three layers of its published
+widths: no cache-sized copy, K and V donated in place, every weight read
+once a chunk.
+
 The topology is described inside a fixture (only one process may load the
 TPU's library; a worker that cannot skips), and this is the one file that
 does so."""
@@ -292,8 +297,9 @@ def test_lfm2_is_compiled_with_one_fetch_ahead_in_flight(one_chip,
 # attention over the filled rows (PR 35)
 # ---------------------------------------------------------------------------
 
-def _compile_opt(one_chip, layers=2, slots=8, rows=768):
-    """OPT-1.3b's published widths at ``layers`` layers, float32."""
+def _opt_shapes(one_chip, layers, slots, rows):
+    """``(state, params, s)`` of OPT-1.3b's published widths at ``layers``
+    layers, float32, as shapes on the described chip."""
     e, f, v = 2048, 8192, 50272
 
     def s(shape, dtype=np.float32):
@@ -315,10 +321,23 @@ def _compile_opt(one_chip, layers=2, slots=8, rows=768):
     cache = s((layers, slots, rows, e))
     state = {"k": cache, "v": cache, "seed": s((slots,), np.uint32),
              "tok": s((slots,), np.int32)}
+    return state, params, s
+
+
+def _compile_opt(one_chip, layers=2, slots=8, rows=768):
+    state, params, s = _opt_shapes(one_chip, layers, slots, rows)
     feed = [s((slots,), d) for d in (np.int32, np.int32, np.float32,
                                      np.int32, np.float32, np.uint32,
                                      np.bool_)]
     fn = jax.jit(decode._build_decode_fn(decode.OptArch(layers, 32)),
+                 donate_argnums=(0,))
+    return fn.lower(state, params, *feed).compile()
+
+
+def _compile_opt_prefill(one_chip, layers=3, slots=8, rows=768):
+    state, params, s = _opt_shapes(one_chip, layers, slots, rows)
+    feed = [s((decode.PREFILL_CHUNK,), np.int32)] + [s((), np.int32)] * 3
+    fn = jax.jit(decode._build_prefill_fn(decode.OptArch(layers, 32)),
                  donate_argnums=(0,))
     return fn.lower(state, params, *feed).compile()
 
@@ -408,6 +427,29 @@ def test_opt_attends_a_prefix_and_moves_no_cache(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * cache           # donated in place
     assert mem.temp_size_in_bytes < 0.05e9
+
+
+def test_opt_prefill_moves_no_cache_and_reads_its_weights_once(one_chip):
+    """The claimed cells' prefill program (PR 37) at three layers: a chunk
+    of 128 positions of one slot, 8 slots, 768 rows, float32. K and V are
+    written in place, the one slot's rows laid out per head are all that
+    is copied (``rows x width`` float32: 6.3 MB a layer), every weight
+    matrix is an operand of one instruction (of four where the compiler
+    fetches it in quarters), and the head's are not operands at all."""
+    compiled = _compile_opt_prefill(one_chip)
+    cache = 3 * 8 * 768 * 2048 * 4
+    moved = [i for i in _top_level(compiled)
+             if i[3] in ("copy", "transpose") and i[2] > 768 * 2048 * 4]
+    assert moved == []
+    found, facts = chip_smoke.cache_relayouts(compiled, "opt/prefill", cache)
+    assert found == [], facts
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cache           # donated in place
+    assert mem.temp_size_in_bytes < 0.2e9
+    reads = chip_smoke.weight_reads(compiled)
+    assert len(reads) == 4 * 2 + 1 + 2, reads
+    assert max(reads.values()) <= chip_smoke.WEIGHT_PIECES, reads
+    assert not any("lm_head" in name for name in reads)
 
 
 #: what a traced run shows of a step: every instruction outside the fused
