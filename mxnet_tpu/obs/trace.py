@@ -33,6 +33,12 @@ Event model (Chrome trace-event format, the subset Perfetto renders):
   ids with ``M`` thread-name metadata records so Perfetto labels tracks.
 - ``ph="i"`` instant events (:func:`instant`) for point occurrences
   (divergence, rollback, replica death, request submit).
+- ``ph="b"``/``"e"`` async pairs keyed by ``id`` (:func:`async_complete`)
+  for a life that crosses threads and outlasts the spans around it: a
+  batcher request's ``serve_queue``, a decode request's ``decode_request``
+  (the begin event carries the whole record). Each id is its own track;
+  they are no complete events, so :func:`nest_check` and readers of
+  ``ph="X"`` never meet them.
 """
 from __future__ import annotations
 

@@ -554,6 +554,18 @@ def _build_implant_fn():
     return implant_fn
 
 
+def _build_snapshot_fn():
+    """Copies of an architecture's device counters, NOT donating: a traced
+    run enqueues it behind a step and reads the copies a step later, when
+    the arrays it copied are long donated away (``jnp.copy``: an output
+    that IS its input would be handed back as the same buffer)."""
+    def snapshot_fn(counters):
+        import jax.numpy as jnp
+        return {k: jnp.copy(v) for k, v in counters.items()}
+
+    return snapshot_fn
+
+
 class GenerateFuture(Settleable):
     """Handle for one in-flight sequence; :meth:`result` blocks. Rides
     the batcher's :class:`~mxnet_tpu.serving.batcher.Settleable` protocol
@@ -561,7 +573,8 @@ class GenerateFuture(Settleable):
     so open-loop clients can drive ``generate`` exactly like ``infer``."""
 
     __slots__ = ("prompt", "max_new", "_loop", "rid", "temperature",
-                 "top_k", "top_p", "seed", "prefix_len", "token_times")
+                 "top_k", "top_p", "seed", "prefix_len", "token_times",
+                 "t_submit", "outcome")
 
     def __init__(self, loop, prompt, max_new, temperature=0.0, top_k=0,
                  top_p=1.0, seed=None, prefix_len=0, on_done=None):
@@ -583,10 +596,17 @@ class GenerateFuture(Settleable):
         self.prefix_len = int(prefix_len)
         #: ``time.perf_counter()`` of the step that emitted each token, in
         #: order (one clock read a step; the tokens of one speculative
-        #: round share theirs). ``token_times[0]`` less the time of
-        #: submission is the time to first token, the differences are the
-        #: token gaps; the loop thread appends, so read it once done
+        #: round share theirs). ``token_times[0]`` less :attr:`t_submit` is
+        #: the time to first token, the differences are the token gaps;
+        #: the loop thread appends, so read it once done. The loop's
+        #: ``decode_request`` record carries the same as ``token_us``
         self.token_times = []
+        #: ``time.perf_counter()`` of the submission
+        self.t_submit = time.perf_counter()
+        #: how the LOOP ended the request, once it has: ``done``, ``eos``,
+        #: ``shed`` (the loop died) or ``failed`` (closed unserved): the
+        #: ``outcome`` of its one ``decode_request`` record
+        self.outcome = None
 
     @property
     def tokens(self):
@@ -623,11 +643,21 @@ class _Slot(object):
     back, one step later."""
 
     __slots__ = ("fut", "seat", "pending", "pos", "next_token", "emitted",
-                 "sent", "reseed", "producing")
+                 "sent", "reseed", "producing", "t_seat", "steps", "prefill",
+                 "prefix_hit")
 
-    def __init__(self, fut, seat=0):
+    def __init__(self, fut, seat=0, step=0):
         self.fut = fut
         self.seat = seat                  # the loop's n-th seating
+        # for the request's one record (``DecodeLoop._request_done``)
+        self.t_seat = time.perf_counter()
+        #: ids of the ``decode_step`` spans that first list the request,
+        #: that commit its prompt's last position (0: worked out from
+        #: ``sent`` when it leaves) and that emit its last token (where it
+        #: leaves its slot, or the step whose ``eos`` is read a step late)
+        self.steps = [step, 0, 0]
+        self.prefill = [0, 0]             # prefill passes, their positions
+        self.prefix_hit = 0               # rows a prefix hit implanted
         self.pending = list(fut.prompt)   # prompt tokens still to feed
         self.pos = 0                      # next cache write position
         #: the next input token where the host has it (a prompt's), else
@@ -857,6 +887,15 @@ class DecodeLoop(object):
                     (state_s, params_s,
                      self._vec_struct(jax, (self._chunk,), np.int32),
                      scalar_s, scalar_s, scalar_s), (0,))
+        #: a traced run's copy of the device counters (see
+        #: :meth:`_snapshot_counters`), built with the others: a compile
+        #: inside a measured window is failed work
+        self._counter_snap = None      # (step, the copies) not yet read
+        if self._counter_names:
+            self._snapshot_c = compile_one(
+                "counters[%s]" % ",".join(self._counter_names),
+                _build_snapshot_fn(),
+                ({k: state_s[k] for k in self._counter_names},), ())
         if self.prefix_enabled:
             slot_s = self._vec_struct(jax, (), np.int32)
             self._prefix_programs(compile_one, jax, "target", arch,
@@ -1250,20 +1289,24 @@ class DecodeLoop(object):
     # ------------------------------------------------------------------
     def _shed(self, exc):
         shed = 0
+        outcome = "failed" if self.dead is None else "shed"
         for i, slot in enumerate(self._slots):
             if slot is not None:
+                self._request_done(slot.fut, outcome, i, slot)
                 slot.fut.fail(exc)
                 self._slots[i] = None
                 shed += 1
         # and the requests whose last step is in flight: they left their
         # slots when it was dispatched, and their tokens will not be read
         rec, self._inflight = self._inflight, None
-        for _, slot, _, last in rec[1] if rec is not None else ():
-            if last and slot.fut.fail(exc):
-                shed += 1
+        for i, slot, _, last in rec[1] if rec is not None else ():
+            if last and not slot.fut.done():
+                self._request_done(slot.fut, outcome, i, slot)
+                shed += slot.fut.fail(exc)
         while True:
             try:
                 fut = self._join_q.get_nowait()
+                self._request_done(fut, outcome)
                 fut.fail(exc)
                 shed += 1
             except queue.Empty:
@@ -1283,7 +1326,7 @@ class DecodeLoop(object):
                 break
             joined += 1
             self._seated += 1
-            slot = _Slot(fut, self._seated)
+            slot = _Slot(fut, self._seated, self._steps + 1)
             self._slots[i] = slot
             if self.prefix_enabled and fut.prefix_len > 0:
                 key = tuple(fut.prompt[:fut.prefix_len])
@@ -1294,12 +1337,12 @@ class DecodeLoop(object):
                     slot.pos = entry["len"]
                     slot.pending = list(fut.prompt[entry["len"]:])
                     slot.next_token = slot.pending.pop(0)
+                    slot.prefix_hit = entry["len"]
                     self.health.record_prefix_hit()
                     _obs.instant("decode_prefix_hit", req=fut.rid, slot=i,
                                  plen=entry["len"])
                 else:
                     slot.producing = (key, fut.prefix_len)
-            _obs.instant("decode_join", req=fut.rid, slot=i)
             self.health.record_join()
         return joined
 
@@ -1351,9 +1394,8 @@ class DecodeLoop(object):
                         # every slot was dispatched for the last time:
                         # nothing to run ahead of, so settle that step
                         self._drain()
-                        continue
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                    else:
+                        self._idle()
                     continue
                 act = _faults.fire("serve.decode_die")
                 if act == "die":
@@ -1370,6 +1412,20 @@ class DecodeLoop(object):
             _flight.dump("decode loop died: %r" % (e,),
                          extra={"health": self.health.report()})
             return
+
+    def _idle(self):
+        """The empty loop's wait: no slot is seated, no step is in flight.
+        ONE ``loop_idle`` span a stretch, from the poll that found the loop
+        so until someone waits to be seated (or the loop is closed),
+        however many polls of 50 ms that takes: the device's idle in it is
+        the traffic's, and a trace says so (docs/observability.md). It
+        ends before the ``_admit`` that seats the newcomer begins."""
+        t0 = time.perf_counter()
+        while not self._closed and self._join_q.empty():
+            self._wake.wait(timeout=0.05)
+            self._wake.clear()
+        _obs.complete("loop_idle", time.perf_counter() - t0,
+                      step=self._steps)
 
     def _step(self):
         self._steps += 1
@@ -1401,17 +1457,50 @@ class DecodeLoop(object):
         if cpu is None:
             self._span_sent = False
             return
-        # for the trace file only: once a trace the step program's table
-        # of instruction -> scope, and every COUNTER_SPAN_STEPS steps the
-        # architecture's device counters (never once a step)
+        # for the trace file only: once a trace the programs' names and
+        # the step's table of instruction -> scope, and every
+        # COUNTER_SPAN_STEPS steps the architecture's device counters
+        # (never once a step): copied behind step n, read in span n + 1
         if not self._span_sent:
             self._span_sent = True
             self._program_span()
-        if self._counter_names and self._steps % COUNTER_SPAN_STEPS == 0:
-            t0 = time.perf_counter()
-            counts = {k: v.tolist() for k, v in self.counter_totals().items()}
-            _obs.complete("loop_counters", time.perf_counter() - t0,
-                          step=self._steps, **counts)
+        if self._counter_names and self._steps % COUNTER_SPAN_STEPS < 2:
+            if self._steps % COUNTER_SPAN_STEPS:
+                self._deliver_counters()
+            else:
+                self._snapshot_counters()
+
+    def _snapshot_counters(self):
+        """Enqueue a copy of the device counters behind the step just
+        dispatched and start its way to the host; nothing waits.
+        ``counter_totals()`` here would: ``np.asarray`` of the state's own
+        arrays returns when the step in flight has, and the next step is
+        then gathered, put and dispatched with the device idle (a gap of
+        4-6 ms every 32 steps in every cell with counters, most of the
+        idle a traced run recorded there; PERF.md, PR 38). The copies are
+        the snapshot program's own outputs: the next dispatch donates the
+        state's arrays and leaves them alone."""
+        self._deliver_counters()
+        with self._state_lock:
+            snap = self._snapshot_c({k: self._state[k]
+                                     for k in self._counter_names})
+        for v in snap.values():
+            v.copy_to_host_async()
+        self._counter_snap = (self._steps, snap)
+
+    def _deliver_counters(self):
+        """The ``loop_counters`` span of the snapshot taken behind step n,
+        emitted where step n's tokens have been read back (span n + 1, or
+        the drain): the copies are on the host by then, or a few
+        microseconds away while the device runs step n + 1. A snapshot
+        whose turn passed (tracing went off in between) is dropped."""
+        snap, self._counter_snap = self._counter_snap, None
+        if snap is None or snap[0] < self._steps - 1:
+            return
+        t0 = time.perf_counter()
+        counts = {k: np.asarray(v).tolist() for k, v in snap[1].items()}
+        _obs.complete("loop_counters", time.perf_counter() - t0,
+                      step=snap[0], **counts)
 
     def _gather_sampling(self):
         """Host-side per-slot dispatch arrays (and consume reseed marks)."""
@@ -1468,6 +1557,8 @@ class DecodeLoop(object):
         slot.pos += n
         slot.next_token = slot.pending[n - 1]
         del slot.pending[:n]
+        slot.prefill[0] += 1
+        slot.prefill[1] += n
         self.health.record_prefill(n)
         self._maybe_harvest(best)
         sp.lap("decode_prefill")
@@ -1543,6 +1634,7 @@ class DecodeLoop(object):
             rows.append((i, slot, emits, last))
             if last:
                 self._slots[i] = None
+                slot.steps[2] = self._steps
             else:
                 self._maybe_harvest(i)
         return rows, prompt
@@ -1589,6 +1681,7 @@ class DecodeLoop(object):
                 self._slots[i] = None
                 slot.pos -= 1
                 slot.sent -= 1
+                slot.steps[2] = self._steps - 1   # the step read back here
         self.health.record_tokens(emitted, trash)
         for i, slot in leaving:
             self._settle(i, slot)
@@ -1602,6 +1695,7 @@ class DecodeLoop(object):
         self._commit(_obs.NOOP, rec)
         _obs.complete("loop_drain", time.perf_counter() - t0,
                       step=self._steps)
+        self._deliver_counters()
 
     def _step_spec(self, sp):
         """One draft-K-then-verify round: K+1 cheap draft passes chain
@@ -1671,6 +1765,8 @@ class DecodeLoop(object):
                 else:
                     tok = int(s[i, j])
                     slot.emitted.append(tok)
+                    if not slot.sent:   # the round that ends the prompt
+                        slot.steps[1] = self._steps
                     slot.sent += 1
                     slot.fut.token_times.append(now)
                     emitted += 1
@@ -1735,14 +1831,54 @@ class DecodeLoop(object):
     def _retire(self, i):
         slot = self._slots[i]
         self._slots[i] = None
+        slot.steps[2] = self._steps
         self._settle(i, slot)
 
     def _settle(self, i, slot):
         """Hand a request that left slot ``i`` its tokens."""
         self.health.record_retire()
+        eos = self.eos_id is not None and slot.emitted[-1:] == [self.eos_id]
+        self._request_done(slot.fut, "eos" if eos else "done", i, slot)
         slot.fut.fulfill(list(slot.emitted))
-        _obs.instant("decode_retire", req=slot.fut.rid, slot=i,
-                     emitted=len(slot.emitted))
+
+    def _request_done(self, fut, outcome, i=-1, slot=None):
+        """The ONE record of a request's life, where the loop ends it
+        (``slot`` ``None``: it was never seated), and the operator's
+        latency counters from the same stamps. Once a request: the step
+        stamps nothing for it that it did not stamp before.
+
+        The record is an async pair ``decode_request`` keyed by the
+        request's id (docs/observability.md "Span catalogue" has the
+        arguments): its own track in Perfetto, in the flight recorder's
+        ring, and no complete span, so no reader of the loop thread's
+        spans meets one that lasts seconds. Times are microseconds from
+        ``submit``, the submission on ``time.perf_counter()``: the clock
+        of ``token_times``."""
+        if fut.outcome is not None:
+            return
+        fut.outcome = outcome
+        t0 = fut.t_submit
+        token_us = [int(round((t - t0) * 1e6)) for t in fut.token_times]
+        seat_us = -1 if slot is None else int(round((slot.t_seat - t0) * 1e6))
+        if token_us:
+            self.health.record_request_latency(seat_us, token_us)
+        if not _obs.active():
+            return
+        steps, prefill, hit = [0, 0, 0], [0, 0], 0
+        if slot is not None:
+            first, done, last = slot.steps
+            # a request that left by the schedule emitted a token in every
+            # step from its prompt's last position to its last
+            steps = [first if first <= self._steps else 0,
+                     done or (last - slot.sent + 1 if last and slot.sent
+                              else 0), last]
+            prefill, hit = list(slot.prefill), slot.prefix_hit
+        _obs.async_complete(
+            "decode_request", time.perf_counter() - t0, id=fut.rid,
+            req=fut.rid, slot=i, prompt_len=len(fut.prompt),
+            emitted=len(token_us), outcome=outcome, submit=t0,
+            seat_us=seat_us, token_us=token_us, steps=steps,
+            prefill=prefill, prefix_hit=hit)
 
     # ------------------------------------------------------------------
     def counter_totals(self):
@@ -1812,10 +1948,14 @@ class DecodeLoop(object):
         except Exception as e:   # an executable that cannot surface HLO
             logging.warning("%s: no scope table (%r)", self.name, e)
             return
+        # the module a prefill pass runs as, by name, where the loop has
+        # one (no scope table of its own until a reader wants one)
+        pass_args = {} if self._prefill_c is None else {
+            "prefill_program": "jit_prefill_fn", "prefill_chunk": self._chunk}
         _obs.complete("loop_program", time.perf_counter() - t0,
                       program="jit_verify_fn" if self.spec_k
                       else "jit_decode_fn", scopes=scopes,
-                      state=self.state_arrays())
+                      state=self.state_arrays(), **pass_args)
 
     # ------------------------------------------------------------------
     def memory_report(self, top=8):

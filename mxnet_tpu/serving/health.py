@@ -62,6 +62,20 @@ class ServingHealth(object):
         self.trash_slot_steps = 0  # slot-steps dispatched for a request
         #                            whose eos was learned a step late:
         #                            their tokens were dropped
+        self.first_tokens = 0      # decode requests that got a first token:
+        #                            the denominator of the two means below
+        self.first_token_us_sum = 0   # submission -> first token on the
+        #                            host, microseconds, summed over them
+        self.queue_wait_us_sum = 0    # submission -> seated in a slot, of
+        #                            the same requests
+        self.token_gaps = 0        # gaps between consecutive tokens of one
+        #                            request (its tokens less one)
+        self.token_gap_us_sum = 0  # their lengths summed: over token_gaps,
+        #                            the mean time a client waits for the
+        #                            next token
+        self.token_gap_us_max = 0  # the longest of them so far (a mark, not
+        #                            a sum: a step behind a co-rider's
+        #                            prefill pass, a stall)
         self.joined = 0            # sequences that entered a decode slot
         self.retired = 0           # sequences that left a decode slot
         self.requeued = 0          # requests moved off a dead/draining
@@ -175,6 +189,27 @@ class ServingHealth(object):
         if self._parent is not None:
             self._parent.record_tokens(emitted, trash)
 
+    def record_request_latency(self, seat_us, token_us):
+        """A decode request that leaves with at least one token, from the
+        loop's own stamps (the ``decode_request`` record's ``seat_us`` and
+        ``token_us``, microseconds from its submission): its time to first
+        token, its queue wait, and the gaps between its tokens. Once a
+        request, where the record is emitted; the counts move under one
+        lock."""
+        longest = max([b - a for a, b in zip(token_us, token_us[1:])],
+                      default=0)
+        health = self
+        while health is not None:     # the gaps are worked out once
+            with health._lock:
+                health.first_tokens += 1
+                health.first_token_us_sum += int(token_us[0])
+                health.queue_wait_us_sum += int(seat_us)
+                health.token_gaps += len(token_us) - 1
+                health.token_gap_us_sum += int(token_us[-1] - token_us[0])
+                health.token_gap_us_max = max(health.token_gap_us_max,
+                                              longest)
+            health = health._parent
+
     def record_join(self):
         self._bump("joined")
 
@@ -235,6 +270,12 @@ class ServingHealth(object):
                 "cache_rows_read": self.cache_rows_read,
                 "cache_rows_allocated": self.cache_rows_allocated,
                 "trash_slot_steps": self.trash_slot_steps, **ring,
+                "first_tokens": self.first_tokens,
+                "first_token_us_sum": self.first_token_us_sum,
+                "queue_wait_us_sum": self.queue_wait_us_sum,
+                "token_gaps": self.token_gaps,
+                "token_gap_us_sum": self.token_gap_us_sum,
+                "token_gap_us_max": self.token_gap_us_max,
                 "joined": self.joined,
                 "retired": self.retired, "requeued": self.requeued,
                 "prefix_hits": self.prefix_hits,
@@ -260,6 +301,10 @@ class ServingHealth(object):
             self.trash_slot_steps = 0
             self.ring_rows_read = self.ring_rows_allocated = 0
             self.ring_wrapped_slot_steps = 0
+            self.first_tokens = self.first_token_us_sum = 0
+            self.queue_wait_us_sum = 0
+            self.token_gaps = self.token_gap_us_sum = 0
+            self.token_gap_us_max = 0
             self.joined = self.retired = self.requeued = 0
             self.prefix_hits = self.prefix_prefills = 0
             self.spec_rounds = self.spec_drafted = self.spec_accepted = 0

@@ -77,7 +77,7 @@ def test_the_cell_is_what_the_issue_named():
         "slot_occupancy", "prompt_step_share", "decode_gap_feed_ms",
         "decode_gap_dispatch_ms", "decode_gap_readback_ms",
         "decode_gap_commit_ms", "decode_gap_covered", "emitted_tok_per_s",
-        "moe_here_share", "moe_load_max_over_mean",
+        "token_gap_p99_ms", "moe_here_share", "moe_load_max_over_mean",
         "conv_layer_roofline", "gqa_layer_roofline",
         "decode_step_roofline.lfm2", "decode_mfu.lfm2"}
     # NOT moe_layer_roofline: the compiler fetches this model's 50 MB expert

@@ -86,7 +86,7 @@ def test_the_cell_is_what_the_issue_named():
         "slot_occupancy", "prompt_step_share", "decode_gap_feed_ms",
         "decode_gap_dispatch_ms", "decode_gap_readback_ms",
         "decode_gap_commit_ms", "decode_gap_covered", "emitted_tok_per_s",
-        "moe_here_share", "moe_load_max_over_mean",
+        "token_gap_p99_ms", "moe_here_share", "moe_load_max_over_mean",
         "decode_step_roofline.mellum2", "decode_mfu.mellum2",
         "ring_wrapped_share", "ring_rows_share"}
     # a share of a roofline is listed only where it read under 100% on the

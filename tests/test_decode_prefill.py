@@ -141,8 +141,8 @@ def test_an_architecture_is_fed_one_position_a_step_unless_it_says_how():
 def test_the_other_architectures_are_fed_one_position_a_step(tests,
                                                               reference, why):
     """Each is a PR of its own (``why``): ``build_prefill_pass`` is
-    ``None``, so the tiny loop of the architecture's own tests compiles the
-    step program alone, with the arguments it had, and a prompt longer
+    ``None``, so the tiny loop of the architecture's own tests compiles no
+    prefill program and the step with the arguments it had, and a prompt longer
     than any ``MIN_PREFILL`` takes a step a position, as before PR 37 (the
     step's lowered text was compared with the parent's once, sha for sha:
     PERF.md, PR 37)."""
@@ -156,10 +156,14 @@ def test_the_other_architectures_are_fed_one_position_a_step(tests,
     try:
         assert loop._arch.build_prefill_pass() is None
         assert loop._prefill_c is None
-        assert list(loop._programs) == ["%s/step[slots=%d,len=%d]" % (
-            loop.name, mod.SLOTS, mod.MAX_LEN)]
-        (_, structs, donate), = loop._programs.values()
+        # beside the step, from PR 38, the non-donating copy of the device
+        # counters that a traced run reads them through
+        assert list(loop._programs) == [
+            "%s/step[slots=%d,len=%d]" % (loop.name, mod.SLOTS, mod.MAX_LEN),
+            "%s/counters[moe_served,moe_routed]" % loop.name]
+        (_, structs, donate), (_, _, kept) = loop._programs.values()
         assert len(structs) == 9 + loop._arch.wants_live and donate == (0,)
+        assert kept == ()
         out = loop.generate(prompt, 5).result(timeout=120)
         health = loop.health.report()
     finally:
