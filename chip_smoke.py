@@ -473,9 +473,15 @@ def latent_decode_leg(meter, config, max_len, slots=8, requests=12,
     """``DecodeLoop`` over the DeepSeek-V3 block (latent attention, a share
     of a routed-expert layer) in bfloat16, more requests than slots: its
     compiled step program must not re-lay the latent cache out, and its
-    routing counters must account for every position it processed."""
+    routing counters must account for every position it processed. From
+    PR 39 its prompts go in by PACKED prefill passes (rows of several
+    slots behind one read of the weights): the pass's executable holds the
+    same rule against the cache, the passes must have engaged, and the
+    last expert layer counts no row of a pass (the pass stops there at
+    its latent row)."""
     import jax.numpy as jnp
     from mxnet_tpu import serving
+    from mxnet_tpu.serving.decode import MIN_PREFILL
 
     snap = meter.snapshot()
     arch = serving.DeepseekV3Arch(config)
@@ -496,17 +502,21 @@ def latent_decode_leg(meter, config, max_len, slots=8, requests=12,
     loop = serving.DecodeLoop(params, max_len=max_len, slots=slots, arch=arch,
                               quantize="bf16", prefix_cache=False, spec_k=0)
     try:
+        cache = int(loop._state["latent"].nbytes)
         relaid, step_facts = cache_relayouts(
-            loop._step_c, loop.name + "/step",
-            int(loop._state["latent"].nbytes))
+            loop._step_c, loop.name + "/step", cache)
+        again, prefill_facts = cache_relayouts(
+            loop._prefill_c, loop.name + "/prefill", cache)
+        relaid += again
+        prefill_facts.update(rows=loop._chunk)
         futures = [loop.generate(p, max_new) for p in prompts]
         outs = [f.result(timeout=900.0) for f in futures]
         health = loop.health.report()
     finally:
         loop.close()
     if relaid:
-        raise AssertionError("the step program re-lays the latent cache "
-                             "out: %s" % "; ".join(relaid))
+        raise AssertionError("the step or the prefill program re-lays the "
+                             "latent cache out: %s" % "; ".join(relaid))
     if not (health["joined"] == health["retired"] == requests) \
             or health["errors"] or health["shed"] or loop.dead is not None:
         raise AssertionError("decode loop unhealthy: %r dead=%r"
@@ -515,7 +525,16 @@ def latent_decode_leg(meter, config, max_len, slots=8, requests=12,
         if len(out) != max_new or not all(0 <= t < vocab for t in out):
             raise AssertionError("bad generation: %r" % (out,))
     positions = sum(len(p) for p in prompts) + requests * (max_new - 1)
-    routed = len(arch.moe_layers) * arch.num_experts_per_tok * positions
+    passed = health["prefill_positions"]
+    due = sum(len(p) - 1 for p in prompts if len(p) > MIN_PREFILL)
+    if not (bool(passed) == bool(due) and passed <= due
+            and health["prefill_passes"] <= health["prefill_slots"]):
+        raise AssertionError("the prompts did not go in by passes: %r"
+                             % {k: health[k] for k in (
+                                 "prefill_passes", "prefill_slots",
+                                 "prefill_positions", "prompt_positions")})
+    routed = arch.num_experts_per_tok * (len(arch.moe_layers) * positions
+                                         - passed)
     if health["moe_pairs_routed"] != routed \
             or not 0 <= health["moe_pairs_here"] <= routed:
         raise AssertionError("routing counters: %d routed, %d here, %d "
@@ -531,7 +550,10 @@ def latent_decode_leg(meter, config, max_len, slots=8, requests=12,
              "decode_steps": health["decode_steps"],
              "moe_pairs_routed": health["moe_pairs_routed"],
              "moe_pairs_here": health["moe_pairs_here"],
-             "step_program": step_facts}
+             "prefill_passes": health["prefill_passes"],
+             "prefill_slots": health["prefill_slots"],
+             "prefill_positions": passed,
+             "step_program": step_facts, "prefill_program": prefill_facts}
     return _report("latent", facts, meter, snap)
 
 

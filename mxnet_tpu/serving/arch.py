@@ -81,6 +81,9 @@ class Architecture(object):
     #: which slots carry a request. Only an architecture that COUNTS what
     #: it processes needs it
     wants_live = False
+    #: the prefill pass takes rows of SEVERAL slots, each naming its slot
+    #: and position (see :meth:`build_prefill_pass`); else one slot a pass
+    packed_prefill = False
 
     def validate(self, host_params, max_len, mesh, quant_mode, spec_k=0,
                  prefix_cache=False):
@@ -153,7 +156,20 @@ class Architecture(object):
         causal attention inside the chunk and over the slot's rows ``<
         pos0`` (a prefix-cache hit starts past 0), at the token pass's
         precision. No head, no sampler, nothing to read back: the
-        prompt's last token goes through the ordinary step."""
+        prompt's last token goes through the ordinary step.
+
+        Where :attr:`packed_prefill` is true the pass is PACKED:
+        ``prefill_pass(state, params, tokens (R,), slot (R,), pos (R,), n)
+        -> state``, row ``r < n`` position ``pos[r]`` of slot ``slot[r]``
+        (the rows of one slot consecutive and ascending, several slots a
+        pass), rows ``n .. R - 1`` padding that moves nothing. It writes
+        the named rows and nothing else, each row attending ITS slot's
+        rows ``<= pos[r]`` as the arrays hold them once the pass's own
+        rows are in; an architecture with counters counts the ``n`` live
+        rows as the token pass would have. The loop fills such a pass from
+        every slot that is due and holds it back until it pays
+        (``decode.PASS_PAYS``): in a wide loop ONE read of the weights then
+        carries the prompts of several slots."""
         return None
 
     def record_counters(self, health, counts, before):

@@ -230,7 +230,7 @@ def rows_covered(ladder, top):
     return ladder[int(filled_rung(top, ladder, np))]
 
 
-def over_filled_rows(pos, rows):
+def over_filled_rows(pos, rows, slot=None):
     """For one token pass over caches ``rows`` deep: ``over(caches, layer,
     attend)``, which gives ``attend(mask, *[c[layer, :, :R] for c in
     caches])`` with ``R`` the smallest rung of :func:`rows_ladder` above
@@ -241,17 +241,26 @@ def over_filled_rows(pos, rows):
     ``R`` rows leave memory. The ``caches`` (each ``(layers, slots, rows,
     width)``) go into the branches whole, as operands that are only read:
     no copy of a layer's rows is made to hand it over. ``attend`` is traced
-    inside the call, so it may close over the layer's own values."""
+    inside the call, so it may close over the layer's own values.
+
+    With ``slot`` (a packed prefill pass: row ``r`` is position ``pos[r]``
+    of slot ``slot[r]``, several rows a slot) each row is handed ITS slot's
+    rows, ``c[layer, slot, :R]``: a gather of ``len(slot) x R`` rows a
+    layer, never a copy the size of a cache."""
     import jax
     import jax.numpy as jnp
     ladder = rows_ladder(rows)
     rung = filled_rung(jnp.max(pos), ladder, jnp)
 
+    def prefix(c, layer, depth):
+        rows = c[layer, :, :depth]
+        return rows if slot is None else rows[slot]
+
     def over(caches, layer, attend):
         def branch(depth):
             def run(pos, *cs):
                 mask = jnp.arange(depth)[None, :] <= pos[:, None]
-                return attend(mask, *[c[layer, :, :depth] for c in cs])
+                return attend(mask, *[prefix(c, layer, depth) for c in cs])
             return run
 
         return jax.lax.switch(rung, [branch(r) for r in ladder], pos,

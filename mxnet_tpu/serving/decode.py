@@ -19,13 +19,18 @@ dispatch substrate PR 1/PR 4 built for training:
   the retired occupant left in the cache — positions past ``pos`` are
   masked, so stale rows are unreachable);
 * a prompt goes in by CHUNKS where the architecture supplies a prefill
-  pass (:class:`OptArch`; docs/serving.md "The prefill pass"): a second
-  AOT program ``(state, params, tokens, slot, pos0, n) -> state`` writes
-  up to :data:`PREFILL_CHUNK` positions of one slot as ONE batched
-  forward, at most one pass a step, dispatched ahead of the step with
-  nothing to read back; only the prompt's last token goes through the
-  decode body. Elsewhere (the other architectures, a speculative loop, a
-  model mesh) the prompt rides the decode body one token per step;
+  pass (docs/serving.md "The prefill pass"): a second AOT program
+  ``(state, params, tokens, slot, pos, n) -> state`` writes prompt
+  positions as ONE batched forward, at most one pass a step, dispatched
+  ahead of the step with nothing to read back; only the prompt's last
+  token goes through the decode body. :class:`OptArch`'s pass takes up to
+  :data:`PREFILL_CHUNK` positions of ONE slot; a PACKED pass
+  (:class:`~mxnet_tpu.serving.deepseek_v3.DeepseekV3Arch`) takes up to
+  :data:`PACKED_ROWS` rows that each name their slot and position, filled
+  from every slot that is due and held back until it carries enough to
+  pay for its read of the weights (:data:`PASS_PAYS`). Elsewhere (the
+  other architectures, a speculative loop, a model mesh) the prompt rides
+  the decode body one token per step;
 * the host only supplies prompt tokens and reads back the SAMPLED token
   ids (one (slots,) int32 readback per step — smaller than the logits
   readback it replaced);
@@ -671,8 +676,9 @@ class _Slot(object):
         self.producing = None             # (key, L): harvest prefix at L
 
 
-#: the positions of one slot's prompt a prefill pass takes (its chunk; the
-#: cache's depth where that is less). A pass reads the weights once
+#: ONE-SLOT PASS (:class:`OptArch`'s): the positions of one slot's prompt
+#: a prefill pass takes (its chunk; the cache's depth where that is less).
+#: A pass reads the weights once
 #: whatever it holds (OPT-1.3B's float32 tree: 6.9 ms at the memory's
 #: rate) and its scores, heads x chunk x rows float32, several times a
 #: layer: over 768 rows it takes 7.9 / 9.1 / 12.8 ms at 64 / 128 / 256
@@ -681,12 +687,43 @@ class _Slot(object):
 #: the chat mix's median prompt (96) in one pass, its longest (256) in
 #: two. A constant until a cell pays for a rule
 PREFILL_CHUNK = 128
-#: the fewest prompt positions worth a prefill pass. The pass holds every
-#: seated slot up for about one step's time (9.1 ms against 8.2) and saves
-#: its own slot a step a position: a saturated loop breaks even at as many
-#: positions as it has slots (8 in the OPT cells), a request alone at two.
-#: A shorter rest of a prompt rides the steps
+#: BOTH PASSES: the fewest prompt positions of a slot worth a prefill pass
+#: (a slot with fewer left before its prompt's last is not due). The pass
+#: holds every seated slot up for about one step's time (9.1 ms against
+#: 8.2) and saves its own slot a step a position: a saturated loop breaks
+#: even at as many positions as it has slots (8 in the OPT cells), a
+#: request alone at two. A shorter rest of a prompt rides the steps. It is
+#: the ONE-SLOT pass's whole rule (a due slot gets its pass at once), and
+#: the floor of the packed pass's (:data:`PASS_PAYS`)
 MIN_PREFILL = 8
+#: PACKED PASS (an architecture whose ``packed_prefill`` is true): the rows
+#: of one pass, each a prompt position of a slot it names (the cache's
+#: depth where that is less). The pass reads the weights once whatever it
+#: holds, so its rows ride free up to the chip's ridge: 197 TFLOP/s over
+#: 819 GB/s = 240 FLOP a byte = 240 rows of bfloat16 weights (2 FLOP a
+#: weight a row, 2 bytes a weight); past it the products, not the read,
+#: set the pass's time, and every padding row costs what a live one does.
+#: 256 is the power of two at the ridge. Kimi-K2's pass over rows under
+#: 256 deep takes 12.6 / 16.1 / 23.8 ms at 128 / 256 / 384 rows against a
+#: step's 14.5 (chip runs, PERF.md PR 39: at 256 its dense held-expert
+#: products run at 81% of the bf16 peak and are half the pass); two joins
+#: of the wide cells (about 122 positions) fit 128 and a third rides 256
+#: free of a second read, and end to end 256 read 1.7% over 128
+PACKED_ROWS = 256
+#: PACKED PASS: a pass is dispatched when it would carry this many times
+#: the GENERATING slots' number of positions (never fewer than
+#: :data:`MIN_PREFILL`), or is full. A pass holds every generating slot up
+#: for its own time, ``g x pass / step`` tokens not emitted, and frees one
+#: slot-step a position it carries, a token emitted later: it breaks even
+#: at ``g x pass / step`` positions, about ``g`` (a pass takes a step's
+#: time or a little more). At break-even nothing is gained, and waiting
+#: costs nothing: the due slots ride the steps one token at a time
+#: meanwhile, exactly as without a pass. So the loop holds the pass back
+#: until it carries TWICE that: half its positions are then profit, and at
+#: 64 slots it waits for the second join (one join brings 31-127
+#: positions, 2 x 60 are wanted). A request alone has no generating slot
+#: beside it and fires at :data:`MIN_PREFILL`
+PASS_PAYS = 2
 #: steps between two readings of an architecture's device counters in a
 #: TRACED run (each reading is a ``loop_counters`` span; never once a step)
 COUNTER_SPAN_STEPS = 32
@@ -879,14 +916,16 @@ class DecodeLoop(object):
             self._jfn = self._jfns[-1]   # the main decode body
             prefill = _build_prefill_fn(arch, mesh=self._mesh)
             if prefill is not None:
-                self._chunk = min(PREFILL_CHUNK, self._rows)
+                self._chunk = min(PACKED_ROWS if arch.packed_prefill
+                                  else PREFILL_CHUNK, self._rows)
                 scalar_s = self._vec_struct(jax, (), np.int32)
+                rows_s = self._vec_struct(jax, (self._chunk,), np.int32)
+                # a packed pass's rows each name their slot and position
+                where_s = rows_s if arch.packed_prefill else scalar_s
                 self._prefill_c = compile_one(
                     "prefill[chunk=%d,len=%d]" % (self._chunk, self.max_len),
-                    prefill,
-                    (state_s, params_s,
-                     self._vec_struct(jax, (self._chunk,), np.int32),
-                     scalar_s, scalar_s, scalar_s), (0,))
+                    prefill, (state_s, params_s, rows_s, where_s, where_s,
+                              scalar_s), (0,))
         #: a traced run's copy of the device counters (see
         #: :meth:`_snapshot_counters`), built with the others: a compile
         #: inside a measured window is failed work
@@ -1529,41 +1568,70 @@ class DecodeLoop(object):
         return arrs
 
     def _prefill(self, sp):
-        """At most ONE prefill pass, dispatched ahead of the step: the
-        longest-seated slot with :data:`MIN_PREFILL` prompt positions or
-        more to feed BEFORE its prompt's last has up to ``chunk`` of them
-        written into its rows by the prefill program (``next_token`` and
-        what follows it in ``pending``, never ``pending``'s last: the
-        step feeds that one and samples the first token, as ever). All by
-        count: the pass hands nothing back, so the step behind it is
-        dispatched at once and the run-ahead is as it was. Returns the
-        positions the pass commits (0: none was due), which the step's
-        span and the counters take as prompt positions like any other."""
-        due = [i for i, slot in enumerate(self._slots)
-               if slot is not None and len(slot.pending) >= MIN_PREFILL]
-        if not due:
+        """At most ONE prefill pass, dispatched ahead of the step. A slot
+        is DUE with :data:`MIN_PREFILL` prompt positions or more to feed
+        BEFORE its prompt's last; the pass writes ``next_token`` and what
+        follows it in ``pending`` into the slot's rows, never ``pending``'s
+        last: the step feeds that one and samples the first token, as ever.
+
+        A ONE-SLOT pass takes up to ``chunk`` positions of the longest
+        seated due slot, at once. A PACKED pass is filled from every due
+        slot, longest seated first, until it is full (a slot whose rest
+        does not fit gives what fits), and is dispatched only if it is
+        full or carries :data:`PASS_PAYS` times the generating slots'
+        number of positions; otherwise nothing is dispatched and the due
+        slots ride the step, as without a pass, so none can starve.
+
+        All by count: the pass hands nothing back, so the step behind it
+        is dispatched at once and the run-ahead is as it was. Returns the
+        positions the pass commits (0: none was dispatched), which the
+        step's span and the counters take as prompt positions like any
+        other."""
+        packed = self._arch.packed_prefill
+        due = sorted((slot.seat, i) for i, slot in enumerate(self._slots)
+                     if slot is not None and len(slot.pending) >= MIN_PREFILL)
+        room, take = self._chunk, []
+        for _, i in due if packed else due[:1]:
+            n = min(room, len(self._slots[i].pending))
+            take.append((i, n))
+            room -= n
+            if not room:
+                break
+        if not take:
             return 0
-        best = min(due, key=lambda i: self._slots[i].seat)
-        slot = self._slots[best]
-        pos0, n = slot.pos, min(self._chunk, len(slot.pending))
+        total = self._chunk - room
+        if packed and room and total < max(MIN_PREFILL, PASS_PAYS * sum(
+                1 for s in self._slots if s is not None and not s.pending)):
+            return 0    # held back: it does not pay for its read yet
         tokens = np.zeros(self._chunk, np.int32)
-        tokens[0] = slot.next_token
-        tokens[1:n] = slot.pending[:n - 1]
-        dev = self._dev([tokens, np.int32(best), np.int32(pos0),
-                         np.int32(n)])
+        where = np.zeros((2, self._chunk), np.int32)    # slot, position a row
+        at, entries = 0, []
+        for i, n in take:
+            slot = self._slots[i]
+            tokens[at] = slot.next_token
+            tokens[at + 1:at + n] = slot.pending[:n - 1]
+            where[0, at:at + n] = i
+            where[1, at:at + n] = np.arange(slot.pos, slot.pos + n)
+            entries.append([slot.fut.rid, i, slot.pos, n])
+            at += n
+        dev = self._dev([tokens] + ([where[0], where[1]] if packed else
+                                    [where[0, 0], where[1, 0]])
+                        + [np.int32(total)])
         with self._state_lock:
             self._state = self._prefill_c(self._state, self._params, *dev)
         del dev     # as in _step_inner: released while the device works
-        slot.pos += n
-        slot.next_token = slot.pending[n - 1]
-        del slot.pending[:n]
-        slot.prefill[0] += 1
-        slot.prefill[1] += n
-        self.health.record_prefill(n)
-        self._maybe_harvest(best)
+        for i, n in take:
+            slot = self._slots[i]
+            slot.pos += n
+            slot.next_token = slot.pending[n - 1]
+            del slot.pending[:n]
+            slot.prefill[0] += 1
+            slot.prefill[1] += n
+            self._maybe_harvest(i)
+        self.health.record_prefill(total, len(take))
         sp.lap("decode_prefill")
-        sp.set(prefill=[slot.fut.rid, best, pos0, n])
-        return n
+        sp.set(prefill=entries)
+        return total
 
     def _step_inner(self, sp):
         """One step, one step AHEAD of its readback: step n is fed and

@@ -127,6 +127,7 @@ class DeepseekV3Arch(Architecture):
 
     name = "deepseek_v3"
     wants_live = True
+    packed_prefill = True
 
     def __init__(self, config):
         missing = [k for k in _KEYS + ("rms_norm_eps", "rope_theta",
@@ -242,6 +243,66 @@ class DeepseekV3Arch(Architecture):
     def record_counters(self, health, counts, before):
         record_moe(health, counts, before)
 
+    # -- what the token pass and the prefill pass are both made of -------------
+    def _layer_parts(self):
+        """``(angles, queries, latent_row, feed_forward)``: a layer's
+        mathematics over any number of rows, one position each. The token
+        pass runs them over a row a slot, the prefill pass over the rows of
+        several slots' prompts: ONE text for both, so they cannot drift."""
+        import jax
+        import jax.numpy as jnp
+        f32 = jnp.float32
+        nope, lora = self.qk_nope_head_dim, self.kv_lora_rank
+        heads, eps = self.num_heads, self.eps
+        inv_freq = np.asarray(self.inv_freq, np.float32)
+        moe_index = {i: m for m, i in enumerate(self.moe_layers)}
+
+        def angles(pos):
+            angle = pos.astype(f32)[:, None] * inv_freq[None, :]
+            return (jnp.cos(angle) * f32(self.rope_scale),
+                    jnp.sin(angle) * f32(self.rope_scale))
+
+        def queries(a, p, cos, sin):
+            q = linear(rms_norm(linear(a, p("attn_q_a_weight")),
+                                p("attn_q_a_norm_gamma"), eps),
+                       p("attn_q_b_weight"))
+            q = q.reshape(a.shape[0], heads, -1)
+            return q, rope(q[..., nope:], cos[:, None], sin[:, None])
+
+        def latent_row(a, p, cos, sin, width):
+            kva = linear(a, p("attn_kv_a_weight"))
+            return _pad_lanes(jnp.concatenate(
+                [rms_norm(kva[:, :lora], p("attn_kv_a_norm_gamma"), eps),
+                 rope(kva[:, lora:], cos, sin)], axis=-1), width)
+
+        def feed_forward(x, p, i, live, nlive, counts):
+            if i not in moe_index:
+                with jax.named_scope("layer/mlp"):
+                    f = rms_norm(x, p("ffn_norm_gamma"), eps)
+                    return x + swiglu(f, p("ffn_gate_weight"),
+                                      p("ffn_up_weight"),
+                                      p("ffn_down_weight")), counts
+            f, y, counts = routed_share(x, p, self.share, live, nlive,
+                                        counts, moe_index[i])
+            with jax.named_scope("layer/moe/shared"):
+                y = y + swiglu(f, p("shared_gate_weight"),
+                               p("shared_up_weight"),
+                               p("shared_down_weight"))
+            return x + y, counts
+
+        return angles, queries, latent_row, feed_forward
+
+    def _attend(self, q, q_pe, p):
+        """A layer's absorbed attention as :func:`.blocks.over_filled_rows`
+        takes it: ``attend(mask, rows)`` for the queries ``q`` (rows, heads,
+        nope + rope; the rope part is read from ``q_pe``, rotated)."""
+        nope = self.qk_nope_head_dim
+        return lambda mask, rows: mla_absorbed(
+            q[..., :nope], q_pe, rows,
+            p("attn_kv_b_weight").reshape(self.num_heads, -1,
+                                          self.kv_lora_rank),
+            mask, self.softmax_scale, nope)
+
     # -- one position per slot through every layer -----------------------------
     def build_token_pass(self, mesh=None):
         import jax
@@ -249,10 +310,8 @@ class DeepseekV3Arch(Architecture):
         if mesh is not None:
             self.slot_partition()
         f32 = jnp.float32
-        nope, lora = self.qk_nope_head_dim, self.kv_lora_rank
-        heads, eps = self.num_heads, self.eps
-        inv_freq = np.asarray(self.inv_freq, np.float32)
-        moe_index = {i: m for m, i in enumerate(self.moe_layers)}
+        eps = self.eps
+        angles, queries, latent_row, feed_forward = self._layer_parts()
 
         def token_pass(state, params, tokens, pos, live):
             lat = state["latent"]
@@ -261,9 +320,7 @@ class DeepseekV3Arch(Architecture):
             sidx = jnp.arange(nslots)
             with jax.named_scope("embed"):
                 x = params["tok_embed_weight"][tokens].astype(f32)
-                angle = wpos.astype(f32)[:, None] * inv_freq[None, :]
-                cos = jnp.cos(angle) * f32(self.rope_scale)
-                sin = jnp.sin(angle) * f32(self.rope_scale)
+                cos, sin = angles(wpos)
             over = over_filled_rows(pos, rows)
             counts = (state.get("moe_served"), state.get("moe_routed"))
             nlive = jnp.sum(live.astype(jnp.int32))
@@ -274,41 +331,14 @@ class DeepseekV3Arch(Architecture):
                     return params[pre + name]
                 with jax.named_scope("layer/mla"):
                     a = rms_norm(x, p("attn_norm_gamma"), eps)
-                    q = linear(rms_norm(linear(a, p("attn_q_a_weight")),
-                                        p("attn_q_a_norm_gamma"), eps),
-                               p("attn_q_b_weight"))
-                    q = q.reshape(nslots, heads, -1)
-                    q_pe = rope(q[..., nope:], cos[:, None], sin[:, None])
-                    kva = linear(a, p("attn_kv_a_weight"))
-                    row = _pad_lanes(jnp.concatenate(
-                        [rms_norm(kva[:, :lora], p("attn_kv_a_norm_gamma"),
-                                  eps),
-                         rope(kva[:, lora:], cos, sin)], axis=-1),
-                        lat.shape[-1])
+                    q, q_pe = queries(a, p, cos, sin)
+                    row = latent_row(a, p, cos, sin, lat.shape[-1])
                 with jax.named_scope("cache_write"):
                     lat = lat.at[i, sidx, wpos].set(row.astype(lat.dtype))
                 with jax.named_scope("layer/mla"):
-                    o = over(
-                        (lat,), i,
-                        lambda mask, rows_i: mla_absorbed(
-                            q[..., :nope], q_pe, rows_i,
-                            p("attn_kv_b_weight").reshape(heads, -1, lora),
-                            mask, self.softmax_scale, nope))
+                    o = over((lat,), i, self._attend(q, q_pe, p))
                     x = x + linear(o, p("attn_out_weight"))
-                if i not in moe_index:
-                    with jax.named_scope("layer/mlp"):
-                        f = rms_norm(x, p("ffn_norm_gamma"), eps)
-                        x = x + swiglu(f, p("ffn_gate_weight"),
-                                       p("ffn_up_weight"),
-                                       p("ffn_down_weight"))
-                    continue
-                f, y, counts = routed_share(x, p, self.share, live, nlive,
-                                            counts, moe_index[i])
-                with jax.named_scope("layer/moe/shared"):
-                    y = y + swiglu(f, p("shared_gate_weight"),
-                                   p("shared_up_weight"),
-                                   p("shared_down_weight"))
-                x = x + y
+                x, counts = feed_forward(x, p, i, live, nlive, counts)
             with jax.named_scope("head"):
                 logits = linear(rms_norm(x, params["final_norm_gamma"], eps),
                                 params["lm_head_weight"])
@@ -318,3 +348,67 @@ class DeepseekV3Arch(Architecture):
             return out, logits
 
         return token_pass
+
+    # -- the prompts of several slots behind one read of the weights -----------
+    def build_prefill_pass(self, mesh=None):
+        """The packed pass (:attr:`packed_prefill`): the token pass's own
+        mathematics over ``R`` rows, each a position of a slot it NAMES.
+
+        Layer by layer every live row's latent row is scattered to
+        ``(layer, slot[r], pos[r])`` of the donated array, in place, the
+        padding rows sent out of bounds and DROPPED; then each row's
+        absorbed attention runs over ITS slot's rows ``<= pos[r]`` as the
+        array now holds them: the rows a prefix hit implanted, the rows
+        that rode a step while the pass was held back, and the rows its
+        sibling rows wrote in this layer. The rows reach their queries by
+        a gather of ``R x rung x width`` a layer, the rung that of
+        :func:`.blocks.rows_ladder` above the pass's deepest position. The
+        LAST layer stops at its latent row: no head, no sampler, nothing
+        to read back. The pass's positions count into ``moe_served`` and
+        ``moe_routed`` as they did when they rode a step."""
+        import jax
+        import jax.numpy as jnp
+        if mesh is not None:    # a sharded loop is fed one position a step
+            return None
+        f32 = jnp.float32
+        eps = self.eps
+        angles, queries, latent_row, feed_forward = self._layer_parts()
+
+        def prefill_pass(state, params, tokens, slot, pos, n):
+            lat = state["latent"]
+            rows = lat.shape[2]
+            at = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+            live = at < n
+            # a padding row attends row 0 of slot 0 and writes nowhere
+            # (each its own way out of bounds: the indices stay unique)
+            slot = jnp.where(live, slot, 0)
+            pos = jnp.where(live, pos, 0)
+            wpos = jnp.where(live, pos, jnp.int32(rows) + at)
+            with jax.named_scope("embed"):
+                x = params["tok_embed_weight"][tokens].astype(f32)
+                cos, sin = angles(pos)
+            over = over_filled_rows(pos, rows, slot)
+            counts = (state.get("moe_served"), state.get("moe_routed"))
+            for i in range(self.num_layers):
+                def p(name, pre="layer%d_" % i):
+                    return params[pre + name]
+                with jax.named_scope("layer/mla"):
+                    a = rms_norm(x, p("attn_norm_gamma"), eps)
+                    row = latent_row(a, p, cos, sin, lat.shape[-1])
+                with jax.named_scope("cache_write"):
+                    lat = lat.at[i, slot, wpos].set(
+                        row.astype(lat.dtype), mode="drop",
+                        unique_indices=True)
+                if i + 1 == self.num_layers:
+                    break       # nothing reads the last layer's output
+                with jax.named_scope("layer/mla"):
+                    q, q_pe = queries(a, p, cos, sin)
+                    o = over((lat,), i, self._attend(q, q_pe, p))
+                    x = x + linear(o, p("attn_out_weight"))
+                x, counts = feed_forward(x, p, i, live, n, counts)
+            out = {"latent": lat}
+            if counts[0] is not None:
+                out.update(moe_served=counts[0], moe_routed=counts[1])
+            return out
+
+        return prefill_pass

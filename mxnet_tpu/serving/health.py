@@ -32,8 +32,12 @@ class ServingHealth(object):
         #                            none (a prompt being fed, by a step or
         #                            by a prefill pass)
         self.prefill_passes = 0    # prefill passes dispatched: chunks of
-        #                            one slot's prompt written into its rows
+        #                            prompts written into their slots' rows
         #                            between two decode steps
+        self.prefill_slots = 0     # slots those passes carried, summed:
+        #                            over prefill_passes, how many slots'
+        #                            prompts met behind one read of the
+        #                            weights (1 for a one-slot pass)
         self.prefill_positions = 0  # prompt positions those passes
         #                            committed: over prompt_positions, the
         #                            share of prompts fed through a chunk
@@ -158,15 +162,16 @@ class ServingHealth(object):
             self._parent.record_decode_step(emitted, prompt, sampled, ahead,
                                             rows, allocated)
 
-    def record_prefill(self, positions):
+    def record_prefill(self, positions, slots=1):
         """One prefill pass DISPATCHED, which committed ``positions``
-        prompt positions of one slot (the step dispatched behind it counts
-        them among its ``prompt`` positions too)."""
+        prompt positions of ``slots`` slots (the step dispatched behind it
+        counts them among its ``prompt`` positions too)."""
         with self._lock:
             self.prefill_passes += 1
             self.prefill_positions += int(positions)
+            self.prefill_slots += int(slots)
         if self._parent is not None:
-            self._parent.record_prefill(positions)
+            self._parent.record_prefill(positions, slots)
 
     def record_ring_step(self, rows, allocated, wrapped):
         """One decode step DISPATCHED by a loop whose architecture keeps a
@@ -265,6 +270,7 @@ class ServingHealth(object):
                 "prompt_positions": self.prompt_positions,
                 "prefill_passes": self.prefill_passes,
                 "prefill_positions": self.prefill_positions,
+                "prefill_slots": self.prefill_slots,
                 "sampled_steps": self.sampled_steps,
                 "steps_ahead": self.steps_ahead,
                 "cache_rows_read": self.cache_rows_read,
@@ -296,6 +302,7 @@ class ServingHealth(object):
             self.shed = self.errors = self.decode_steps = 0
             self.tokens_emitted = self.prompt_positions = 0
             self.prefill_passes = self.prefill_positions = 0
+            self.prefill_slots = 0
             self.sampled_steps = self.steps_ahead = 0
             self.cache_rows_read = self.cache_rows_allocated = 0
             self.trash_slot_steps = 0

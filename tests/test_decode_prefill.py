@@ -132,16 +132,15 @@ def test_an_architecture_is_fed_one_position_a_step_unless_it_says_how():
 
 
 @pytest.mark.parametrize("tests, reference, why", [
-    ("test_deepseek_v3_decode", "kimi-k2-ep32",
-     "a latent cache wants the non-absorbed form for a chunk"),
     ("test_lfm2_arch", "lfm2-24b-a2b-ep8",
      "a conv state wants a chunk-wide convolution that leaves its rows"),
     ("test_mellum_arch", "mellum2-12b-a2.5b-ep4",
      "a ring wants a chunk that wraps")])
 def test_the_other_architectures_are_fed_one_position_a_step(tests,
                                                               reference, why):
-    """Each is a PR of its own (``why``): ``build_prefill_pass`` is
-    ``None``, so the tiny loop of the architecture's own tests compiles no
+    """Each is a PR of its own (``why``; Kimi's latent cache has a PACKED
+    pass from PR 39, ``tests/test_decode_prefill_packed.py``):
+    ``build_prefill_pass`` is ``None``, so the tiny loop of the architecture's own tests compiles no
     prefill program and the step with the arguments it had, and a prompt longer
     than any ``MIN_PREFILL`` takes a step a position, as before PR 37 (the
     step's lowered text was compared with the parent's once, sha for sha:
@@ -299,8 +298,7 @@ def test_the_edges_are_fed_as_the_rule_says(chunked, one_token):
     futs, steps, health = chunked
     fed = {f.rid: [] for f in futs}
     for st in steps:
-        if "prefill" in st["args"]:
-            rid, _, pos0, n = st["args"]["prefill"]
+        for rid, _, pos0, n in st["args"].get("prefill", ()):
             fed[rid].append((pos0, n))
     by_len = {len(f.prompt): fed[f.rid] for f in futs}
     assert by_len[1] == by_len[2] == by_len[M] == []
@@ -318,6 +316,9 @@ def test_the_edges_are_fed_as_the_rule_says(chunked, one_token):
         n for c in fed.values() for _, n in c)
     assert one_token[2]["prefill_passes"] == 0
     assert one_token[2]["prefill_positions"] == 0
+    # a one-slot pass carries one slot: the span lists one entry a pass
+    assert health["prefill_slots"] == health["prefill_passes"]
+    assert all(len(st["args"].get("prefill", [0])) == 1 for st in steps)
     assert not any("prefill" in st["args"] for st in one_token[1])
 
 
@@ -359,7 +360,7 @@ def test_a_fixed_schedule_counts_as_computed(lm):
                    "tokens_emitted": 5, "steps_ahead": 11}
     rid = futs[0].rid
     assert [st["args"].get("prefill") for st in steps] \
-        == [[rid, 0, 0, 16], [rid, 0, 17, 16]] + [None] * 10
+        == [[[rid, 0, 0, 16]], [[rid, 0, 17, 16]]] + [None] * 10
     assert [st["args"]["pos"] for st in steps[:4]] == [[0], [17], [34], [35]]
     assert [st["args"]["n"] for st in steps] == [[17], [17]] + [[1]] * 10
     assert [st["args"]["emit"] for st in steps] == [[0]] * 7 + [[1]] * 5
@@ -377,16 +378,18 @@ def test_one_pass_a_step_for_the_longest_seated(lm):
     meanwhile (so their chunks start past 0)."""
     reqs = [(_prompt(20, 7), 2), (_prompt(20, 8), 2), (_prompt(20, 9), 2)]
     futs, steps, h = _traced(lm[0], reqs)
-    passes = [st["args"]["prefill"] for st in steps
+    passes = [st["args"]["prefill"][0] for st in steps
               if "prefill" in st["args"]]
     seated = {}     # rid -> the first step that listed it
     for st in steps:
         for rid in st["args"]["reqs"]:
             seated.setdefault(rid, st["args"]["step"])
     assert len(passes) == h["prefill_passes"] == 3
-    by_step = {st["args"]["step"]: st["args"]["prefill"] for st in steps
+    by_step = {st["args"]["step"]: st["args"]["prefill"][0] for st in steps
                if "prefill" in st["args"]}
-    assert len(by_step) == 3            # never two in one step
+    assert len(by_step) == 3            # never two in one step,
+    assert all(len(st["args"].get("prefill", [0])) == 1 for st in steps)
+    assert h["prefill_slots"] == 3      # nor two slots in one pass
     order = [p[0] for p in passes]
     assert order == sorted(order, key=lambda rid: (seated[rid], rid))
     for step, (rid, _, pos0, n) in by_step.items():
@@ -430,7 +433,7 @@ def test_a_prefix_hit_is_followed_by_a_chunk_and_a_producer_harvested_after_one(
             h = loop.health.report()
     finally:
         obs_trace.stop()
-    passes = [e["args"]["prefill"] for e in obs_trace.events()
+    passes = [e["args"]["prefill"][0] for e in obs_trace.events()
               if e["ph"] == "X" and e["name"] == "decode_step"
               and "prefill" in e["args"]]
     obs_trace.clear()

@@ -61,7 +61,8 @@ def lm_params():
 @pytest.fixture(scope="module")
 def kimi():
     """The tiny Kimi-K2 loop of ``tests/test_deepseek_v3_decode.py``: an
-    architecture with device counters and no prefill pass."""
+    architecture with device counters (and, from PR 39, a packed prefill
+    pass that none of the short prompts here is due for)."""
     import test_deepseek_v3_decode as mod
     import test_lfm2_arch
     return mod, test_lfm2_arch._load("kimi-k2-ep32").make_params(mod.TINY, 7)
@@ -155,8 +156,7 @@ def _held_to_its_spans(rec, evs, left_by_itself=True):
             == rec["emitted"]
     else:
         assert (prompt_done, last) == (0, 0)
-    passes = [a["prefill"] for a in mine
-              if a.get("prefill", [None])[0] == rid]
+    passes = [p for a in mine for p in a.get("prefill", ()) if p[0] == rid]
     assert rec["prefill"] == [len(passes), sum(p[3] for p in passes)]
     return mine
 
@@ -546,7 +546,10 @@ def test_loop_counters_are_the_totals_as_of_their_step(kimi, monkeypatch):
     # the scope table of the trace is still the step's, once
     (prog,) = [e for e in evs if e["name"] == "loop_program"]
     assert prog["args"]["program"] == "jit_decode_fn"
-    assert "prefill_program" not in prog["args"]
+    # and, from PR 39, the packed pass's beside it (no prompt here is long
+    # enough to be due: the counts above are the steps' alone)
+    assert prog["args"]["prefill_program"] == "jit_prefill_fn"
+    assert loop.health.report()["prefill_passes"] == 0
 
 
 def test_loop_program_names_the_pass_beside_the_step(plain_run):
@@ -561,35 +564,57 @@ def test_loop_program_names_the_pass_beside_the_step(plain_run):
         *loop._programs[name][1]).as_text()
 
 
-#: sha256 of the lowered text of the step and pass programs as PR 37 left
+#: sha256 of the lowered text of the step and pass programs as PR 38 left
 #: them (``chip_smoke.lm_params(48, 128, 2, 2, 64, seed=3)`` at 2 slots and
-#: 64 rows; the tiny Kimi loop): this PR's tracing is all on the host. A
-#: PR that changes a program on purpose refreshes its line
+#: 64 rows; the tiny loops of the three other architectures' own tests):
+#: PR 39's packed pass shares its layers' text with Kimi's token pass and
+#: adds an argument to ``blocks.over_filled_rows``, which every step runs.
+#: A PR that changes a program on purpose refreshes its line
 PARENTS_TEXT = {
-    "opt.step":
+    "opt": {
+        "step":
         "3296fd79f95aa5a66b4f6c71af51427e3936b939addb2e60c7b72d99c7b0ce2f",
-    "opt.prefill":
-        "8157de028112d85d8e9467edec3d6fa58ca8eb28d28e409b90fd21ae13acb057",
-    "kimi.step":
-        "cbe382bd78699426f7cd4b2427ede4c09ca5ee307a872b0fcf9b5125772592d3",
+        "prefill":
+        "8157de028112d85d8e9467edec3d6fa58ca8eb28d28e409b90fd21ae13acb057"},
+    "kimi": {
+        "step":
+        "cbe382bd78699426f7cd4b2427ede4c09ca5ee307a872b0fcf9b5125772592d3"},
+    "lfm2": {
+        "step":
+        "1d3c6c770b19927bf9dfda6019e37bd2a37be11772a8c012f39130df86ba9d36"},
+    "mellum": {
+        "step":
+        "f440e12d9a430e8b2a942f7e67c21764005dd6bc18d639cca67d44fe3d6587c1"},
 }
+#: the tiny loop of each architecture's own tests, and its reference
+TINY_LOOPS = {"kimi": ("test_deepseek_v3_decode", "kimi-k2-ep32"),
+              "lfm2": ("test_lfm2_arch", "lfm2-24b-a2b-ep8"),
+              "mellum": ("test_mellum_arch", "mellum2-12b-a2.5b-ep4")}
 
 
-def test_the_step_and_the_pass_lower_to_the_parents_text(kimi):
+@pytest.mark.parametrize("tag", sorted(PARENTS_TEXT))
+def test_the_step_and_the_pass_lower_to_the_parents_text(tag):
+    import importlib
     import chip_smoke
-    mod, params = kimi
-    p = chip_smoke.lm_params(48, 128, 2, 2, 64, seed=3)
+    import test_lfm2_arch
+    if tag == "opt":
+        loop = serving.DecodeLoop(
+            chip_smoke.lm_params(48, 128, 2, 2, 64, seed=3), 2, 2,
+            max_len=64, slots=2, prefix_cache=False, spec_k=0)
+    else:
+        mod = importlib.import_module(TINY_LOOPS[tag][0])
+        loop = mod._loop(test_lfm2_arch._load(TINY_LOOPS[tag][1])
+                         .make_params(mod.TINY, 7))
     got = {}
-    for tag, loop in (("opt", serving.DecodeLoop(
-            p, 2, 2, max_len=64, slots=2, prefix_cache=False, spec_k=0)),
-            ("kimi", mod._loop(params))):
-        try:
-            for jfn, (name, (_, structs, _)) in zip(loop._jfns,
-                                                    loop._programs.items()):
-                kind = name.split("/")[1].split("[")[0]
-                if kind in ("step", "prefill"):
-                    got["%s.%s" % (tag, kind)] = hashlib.sha256(
-                        jfn.lower(*structs).as_text().encode()).hexdigest()
-        finally:
-            loop.close()
-    assert got == PARENTS_TEXT
+    try:
+        for jfn, (name, (_, structs, _)) in zip(loop._jfns,
+                                                loop._programs.items()):
+            kind = name.split("/")[1].split("[")[0]
+            if kind in ("step", "prefill"):
+                got[kind] = hashlib.sha256(
+                    jfn.lower(*structs).as_text().encode()).hexdigest()
+    finally:
+        loop.close()
+    # Kimi's pass is new with PR 39: no parent's text to hold it to
+    assert sorted(got) == ["prefill"] * (tag in ("opt", "kimi")) + ["step"]
+    assert {k: got[k] for k in PARENTS_TEXT[tag]} == PARENTS_TEXT[tag]

@@ -677,10 +677,16 @@ def test_chip_smokes_latent_leg_tiny(capsys):
     meter = chip_smoke.CompileMeter(None)
     cfg = {k: v for k, v in TINY.items() if k in chip_smoke.KIMI_K2_DEPTH2}
     facts = chip_smoke.latent_decode_leg(meter, cfg, max_len=48, slots=3,
-                                         requests=5, prompt_range=(2, 6),
+                                         requests=5, prompt_range=(6, 14),
                                          max_new=4)
     assert facts["decode_steps"] > 0
     assert facts["step_program"]["cache_bytes"] == 3 * 3 * 48 * 128 * 2
+    # some prompts are long enough to be due: they went in by packed passes
+    assert 0 < facts["prefill_passes"] <= facts["prefill_slots"]
+    assert facts["prefill_positions"] >= 8 * facts["prefill_passes"] \
+        or facts["prefill_slots"] > facts["prefill_passes"]
+    assert facts["prefill_program"]["rows"] == 48
+    assert facts["prefill_program"]["cache_bytes"] == 3 * 3 * 48 * 128 * 2
     assert facts["moe_pairs_routed"] > facts["moe_pairs_here"] >= 0
     assert set(chip_smoke.KIMI_K2_DEPTH2) <= set(KIMI) | {"rope_scaling"}
     assert all(KIMI[k] == v for k, v in chip_smoke.KIMI_K2_DEPTH2.items()

@@ -39,6 +39,12 @@ the layers as one batched forward) at three layers of its published
 widths: no cache-sized copy, K and V donated in place, every weight read
 once a chunk.
 
+From PR 39 Kimi's PACKED prefill program (256 rows that each name their
+slot and position, the dense layer and one expert layer): each row's
+slot's rows reach it by a gather of ``rows x rung x 640`` a branch, the
+latent cache is written in place and never copied, no float32 copy of a
+weight is made, and the temporaries stay far under the chip's spare room.
+
 The topology is described inside a fixture (only one process may load the
 TPU's library; a worker that cannot skips), and this is the one file that
 does so."""
@@ -450,6 +456,63 @@ def test_opt_prefill_moves_no_cache_and_reads_its_weights_once(one_chip):
     assert len(reads) == 4 * 2 + 1 + 2, reads
     assert max(reads.values()) <= chip_smoke.WEIGHT_PIECES, reads
     assert not any("lm_head" in name for name in reads)
+
+
+@pytest.fixture(scope="module")
+def kimi_prefill(one_chip):
+    arch = deepseek_v3.DeepseekV3Arch(chip_smoke.KIMI_K2_DEPTH2)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {k: s(v, jnp.bfloat16) for k, v in arch.param_shapes().items()}
+    state = {"latent": s((arch.num_layers, SLOTS, ROWS, arch.latent_width),
+                         jnp.bfloat16),
+             "seed": s((SLOTS,), np.uint32), "tok": s((SLOTS,), np.int32)}
+    state.update({k: s(v, np.int32) for k, v in arch.counters().items()})
+    rows = s((decode.PACKED_ROWS,), np.int32)
+    fn = jax.jit(decode._build_prefill_fn(arch), donate_argnums=(0,))
+    return arch, fn.lower(state, params, rows, rows, rows,
+                          s((), np.int32)).compile()
+
+
+def test_kimi_packed_prefill_gathers_rows_and_moves_no_cache(kimi_prefill):
+    """The claimed cell's prefill program (PR 39) at two layers: 256 rows
+    over 64 slots and 1024 latent rows, bfloat16. The cache is donated and
+    written in place; what is made of it is, a branch, the rows of each
+    pass row's own slot up to the rung (``256 x rung x 640`` at the most,
+    by pieces of whole lanes), never a copy of the array; no float32 copy
+    of an expert's matrix is made; the last layer's experts, its
+    attention's later matrices and the head are no operands at all."""
+    arch, compiled = kimi_prefill
+    R = decode.PACKED_ROWS
+    cache = 2 * SLOTS * ROWS * arch.latent_width * 2
+    top = _top_level(compiled)
+    moved = [i for i in top if i[3] in ("copy", "transpose")
+             and i[2] >= cache // 4]
+    assert moved == []
+    found, facts = chip_smoke.cache_relayouts(compiled, "kimi/prefill", cache)
+    assert found == [], facts
+    # the gathers stand inside fusions: every one of cache rows by its dims
+    gathered = [[int(d) for d in m.group(1).split(",")] for m in re.finditer(
+        r" = bf16\[(\d+,\d+,\d+)\]\S* gather\(", compiled.as_text())]
+    # (the compiler cuts a rung's gather into pieces of rows or of lanes)
+    assert gathered and all(
+        g[0] == R and g[1] <= ROWS and g[2] <= arch.latent_width
+        for g in gathered), gathered
+    assert sum(g[1] * g[2] for g in gathered) \
+        == sum(blocks.rows_ladder(ROWS)) * arch.latent_width
+    big = [i for i in top if i[1] == "f32" and i[2] >= 4 * 2048 * 7168]
+    assert [i for i in big if "copy" in i[3] or "convert" in i[3]] == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache               # donated in place
+    assert mem.temp_size_in_bytes < 0.8e9
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    for name in ("lm_head_weight", "layer1_experts_down_weight",
+                 "layer1_attn_out_weight", "layer1_router_weight"):
+        assert "params__%s__" % name not in entry, name
+    assert "params__layer1_attn_kv_a_weight__" in entry
 
 
 #: what a traced run shows of a step: every instruction outside the fused
