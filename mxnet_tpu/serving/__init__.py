@@ -17,7 +17,9 @@ Three layers over the standalone :class:`~mxnet_tpu.predictor.Predictor`:
   expert layer, :class:`Lfm2Arch` keeps K and V rows over its attention
   layers and a two-row conv state over the others, :class:`MellumArch` a
   ring of K and V rows over its sliding-window layers beside K and V rows a
-  position over its full ones) is donated device state
+  position over its full ones, :class:`LagunaArch` the same state under
+  gated heads of two counts, partial rotary positions and a shared expert)
+  is donated device state
   stepped by one compiled decode
   body; sequences join and leave mid-stream. The
   production decode path layers four separate legs on top,
@@ -52,6 +54,7 @@ from .decode import DecodeLoop, GenerateFuture, OptArch
 from .deepseek_v3 import DeepseekV3Arch
 from .lfm2 import Lfm2Arch
 from .mellum import MellumArch
+from .laguna import LagunaArch
 from .fleet import FleetRouter, FleetRequest, CLASSES as FLEET_CLASSES
 from .quantize import (QUANT_MODES, check_quality, quality_report,
                        quantize_tree, tree_bytes)
@@ -59,6 +62,7 @@ from .quantize import (QUANT_MODES, check_quality, quality_report,
 __all__ = [
     "ServingEngine", "Batcher", "DecodeLoop", "GenerateFuture",
     "Architecture", "OptArch", "DeepseekV3Arch", "Lfm2Arch", "MellumArch",
+    "LagunaArch",
     "FleetRouter", "FleetRequest", "FLEET_CLASSES",
     "ServingHealth", "SERVING_HEALTH", "default_buckets",
     "ServingError", "ServingDeadlineError", "ServingOverloadedError",

@@ -100,12 +100,18 @@ def yarn_inv_freq(dim, theta, scaling=None):
 
 
 def rope_half(x, cos, sin):
-    """Rotate the pairs ``(x[i], x[i + d/2])`` of the minor dimension by
-    the angles whose cos and sin are given per pair (``rotate_half``)."""
+    """Rotate the pairs ``(x[i], x[i + r/2])`` of the leading ``r`` lanes
+    of the minor dimension by the angles whose cos and sin are given per
+    pair (``rotate_half``), ``r`` twice their number: the whole minor
+    dimension, or its leading part under a ``partial_rotary_factor``, the
+    lanes past ``r`` passed through as they are."""
     import jax.numpy as jnp
-    half = x.shape[-1] // 2
-    a, b = x[..., :half], x[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    half = cos.shape[-1]
+    a, b = x[..., :half], x[..., half:2 * half]
+    turned = [a * cos - b * sin, b * cos + a * sin]
+    if 2 * half < x.shape[-1]:
+        turned.append(x[..., 2 * half:])
+    return jnp.concatenate(turned, axis=-1)
 
 
 def gqa_attention(q, krows, vrows, tmask, scale):
@@ -306,6 +312,28 @@ def routed_share(x, p, share, live, nlive, counts, m):
         y = held_experts(f, dense, p("experts_gate_weight"),
                          p("experts_up_weight"), p("experts_down_weight"))
     return f, y, (served, routed)
+
+
+def feed_forward(x, p, share, live, nlive, counts, m, shared=False):
+    """A layer's feed-forward and the residual over it: ``(x + ..., counts)``.
+    Where ``m`` is None a dense SwiGLU (``ffn_{gate,up,down}_weight``) over
+    ``RMSNorm(x; ffn_norm)`` under ``layer/mlp``; else expert layer ``m``'s
+    share (:func:`routed_share`) and, with ``shared``, the shared expert
+    (``shared_{gate,up,down}_weight``, ungated, unscaled) over the same
+    normed input under ``layer/moe/shared``: every chip computes it alike,
+    so it is in every share's partial sum once."""
+    import jax
+    if m is None:
+        with jax.named_scope("layer/mlp"):
+            f = rms_norm(x, p("ffn_norm_gamma"), share.norm_eps)
+            return x + swiglu(f, p("ffn_gate_weight"), p("ffn_up_weight"),
+                              p("ffn_down_weight")), counts
+    f, y, counts = routed_share(x, p, share, live, nlive, counts, m)
+    if shared:
+        with jax.named_scope("layer/moe/shared"):
+            y = y + swiglu(f, p("shared_gate_weight"), p("shared_up_weight"),
+                           p("shared_down_weight"))
+    return x + y, counts
 
 
 def validate_share(arch, module, host_params, mesh, quant_mode):

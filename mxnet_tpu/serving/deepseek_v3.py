@@ -59,6 +59,7 @@ from .blocks import (ExpertShare, held_experts, held_weights,  # noqa: F401
                      linear, moe_counters, over_filled_rows, record_moe,
                      rms_norm, route, routed_share, swiglu, validate_share,
                      yarn_inv_freq)
+from .blocks import feed_forward as layer_feed_forward
 
 _KEYS = ("hidden_size", "num_attention_heads", "q_lora_rank", "kv_lora_rank",
          "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
@@ -276,19 +277,8 @@ class DeepseekV3Arch(Architecture):
                  rope(kva[:, lora:], cos, sin)], axis=-1), width)
 
         def feed_forward(x, p, i, live, nlive, counts):
-            if i not in moe_index:
-                with jax.named_scope("layer/mlp"):
-                    f = rms_norm(x, p("ffn_norm_gamma"), eps)
-                    return x + swiglu(f, p("ffn_gate_weight"),
-                                      p("ffn_up_weight"),
-                                      p("ffn_down_weight")), counts
-            f, y, counts = routed_share(x, p, self.share, live, nlive,
-                                        counts, moe_index[i])
-            with jax.named_scope("layer/moe/shared"):
-                y = y + swiglu(f, p("shared_gate_weight"),
-                               p("shared_up_weight"),
-                               p("shared_down_weight"))
-            return x + y, counts
+            return layer_feed_forward(x, p, self.share, live, nlive, counts,
+                                      moe_index.get(i), shared=True)
 
         return angles, queries, latent_row, feed_forward
 

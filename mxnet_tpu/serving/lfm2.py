@@ -69,9 +69,9 @@ import numpy as np
 
 from ..base import MXNetError
 from .arch import PER_POSITION, Architecture, SlotArray
-from .blocks import (ExpertShare, gqa_attention, linear, moe_counters,
-                     over_filled_rows, record_moe, rms_norm, rope_half,
-                     routed_share, swiglu, validate_share)
+from .blocks import (ExpertShare, feed_forward, gqa_attention, linear,
+                     moe_counters, over_filled_rows, record_moe, rms_norm,
+                     rope_half, validate_share)
 
 _KEYS = ("hidden_size", "num_attention_heads", "num_key_value_heads",
          "num_hidden_layers", "vocab_size", "intermediate_size",
@@ -337,16 +337,8 @@ class Lfm2Arch(Architecture):
                                  lambda mask, kr, vr: gqa_attention(
                                      q, kr, vr, mask, scale))
                         x = x + linear(o, p("attn_out_weight"))
-                if i not in moe_index:
-                    with jax.named_scope("layer/mlp"):
-                        f = rms_norm(x, p("ffn_norm_gamma"), eps)
-                        x = x + swiglu(f, p("ffn_gate_weight"),
-                                       p("ffn_up_weight"),
-                                       p("ffn_down_weight"))
-                    continue
-                _, y, counts = routed_share(x, p, self.share, live, nlive,
-                                            counts, moe_index[i])
-                x = x + y
+                x, counts = feed_forward(x, p, self.share, live, nlive,
+                                         counts, moe_index.get(i))
             with jax.named_scope("head"):
                 logits = linear(
                     rms_norm(x, params["final_norm_gamma"], eps),

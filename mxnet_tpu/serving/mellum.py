@@ -74,9 +74,9 @@ import numpy as np
 
 from ..base import MXNetError
 from .arch import PER_POSITION, Architecture, SlotArray
-from .blocks import (ExpertShare, gqa_attention, linear, moe_counters,
-                     over_filled_rows, record_moe, rms_norm, rope_half,
-                     routed_share, validate_share, yarn_inv_freq)
+from .blocks import (ExpertShare, feed_forward, gqa_attention, linear,
+                     moe_counters, over_filled_rows, record_moe, rms_norm,
+                     rope_half, validate_share, yarn_inv_freq)
 
 _KEYS = ("hidden_size", "num_attention_heads", "num_key_value_heads",
          "head_dim", "num_hidden_layers", "vocab_size",
@@ -90,66 +90,128 @@ def ring_row(pos, window):
     return pos % window
 
 
+def head_gate(o, a, weight):
+    """The per-head output gate: head ``h`` of ``o`` (slots, heads *
+    head_dim) times ``sigmoid(a . weight[h])``, ``a`` the layer's normed
+    input and ``weight`` (heads, hidden) stored; the gate float32."""
+    import jax
+    g = jax.nn.sigmoid(linear(a, weight))
+    nslots, heads = g.shape
+    return (o.reshape(nslots, heads, -1) * g[:, :, None]).reshape(
+        nslots, -1)
+
+
 def rotary_table(head_dim, rope):
-    """``(inv_freq float64 (head_dim / 2,), factor)`` of one kind's entry
-    of ``rope_parameters``: plain frequencies and 1 for ``default``, YaRN's
-    blend and its ``attention_factor`` (cos and sin are multiplied by it)
-    for ``yarn``."""
+    """``(inv_freq float64 (r / 2,), factor)`` of one kind's entry of
+    ``rope_parameters``, over the leading ``r = head_dim *
+    partial_rotary_factor`` lanes of a head (all of them where the entry
+    gives no factor): plain frequencies and 1 for ``default``, YaRN's blend
+    over those ``r`` lanes and its ``attention_factor`` (cos and sin are
+    multiplied by it) for ``yarn``."""
     kind = rope.get("rope_type", "default")
+    dim = int(round(head_dim * float(rope.get("partial_rotary_factor", 1))))
+    if dim % 2 or not 0 < dim <= head_dim:
+        raise MXNetError("MellumArch: partial_rotary_factor %r of a head of "
+                         "%d gives no whole pairs"
+                         % (rope["partial_rotary_factor"], head_dim))
     if kind == "default":
-        return yarn_inv_freq(head_dim, rope["rope_theta"]), 1.0
+        return yarn_inv_freq(dim, rope["rope_theta"]), 1.0
     if kind != "yarn":
         raise MXNetError("MellumArch: rope_type %r is not implemented "
                          "(default and yarn are)" % kind)
     factor = rope.get("attention_factor")
     if factor is None:
         factor = 0.1 * math.log(float(rope["factor"])) + 1.0
-    return yarn_inv_freq(head_dim, rope["rope_theta"], rope), float(factor)
+    return yarn_inv_freq(dim, rope["rope_theta"], rope), float(factor)
 
 
 class MellumArch(Architecture):
     """The Mellum block from its ``config.json`` keys, with two of this
     repo's: ``router_width`` (the experts the router ranks; default
     ``num_experts``: nothing cut) and ``share_index`` (which ``num_experts``
-    of them are held here; default 0)."""
+    of them are held here; default 0).
+
+    The token pass also runs :class:`~.laguna.LagunaArch`. Keys that
+    Mellum's config does not carry, whose absence gives Mellum's program:
+    ``num_attention_heads_per_layer`` (``heads``: query heads by layer),
+    ``gating: per-head`` (``gated``: a sigmoid gate on each head's
+    output), ``mlp_only_layers`` and ``dense`` entries of
+    ``mlp_layer_types`` (``dense_layers``: a SwiGLU of
+    ``intermediate_size``; refused unless :attr:`mlp_types` names them),
+    ``shared_expert_intermediate_size`` (``shared_width``: a shared expert
+    beside the routed ones), ``moe_routed_scaling_factor`` (the share's
+    ``scaling``) and a ``rope_parameters`` entry's
+    ``partial_rotary_factor``. Per-layer lists longer than
+    ``num_hidden_layers`` are a pipeline stage's: the first
+    ``num_hidden_layers`` of them are run."""
 
     name = "mellum"
     wants_live = True
+    #: what ``mlp_layer_types`` may name: Mellum routes every layer
+    mlp_types = ("sparse",)
 
     def __init__(self, config):
+        who = type(self).__name__
         missing = [k for k in _KEYS + ("layer_types", "rms_norm_eps",
                                        "rope_parameters")
                    if k not in config]
         if missing:
-            raise MXNetError("MellumArch: config lacks %s"
-                             % ", ".join(missing))
+            raise MXNetError("%s: config lacks %s"
+                             % (who, ", ".join(missing)))
         for k in _KEYS:
             setattr(self, k, int(config[k]))
-        self.layer_types = tuple(config["layer_types"])
+        depth = self.num_hidden_layers
+        self.layer_types = tuple(config["layer_types"][:depth])
         bad = sorted(set(self.layer_types) - set(LAYER_TYPES))
-        if bad or len(self.layer_types) != self.num_hidden_layers:
+        if bad or len(self.layer_types) != depth:
             raise MXNetError(
-                "MellumArch: layer_types must name %d layers of %s (got %d, "
-                "unknown: %s)" % (self.num_hidden_layers,
-                                  " or ".join(LAYER_TYPES),
+                "%s: layer_types must name %d layers of %s (got %d, "
+                "unknown: %s)" % (who, depth, " or ".join(LAYER_TYPES),
                                   len(self.layer_types), bad or "none"))
-        dense = sorted(set(config.get("mlp_layer_types", ())) - {"sparse"})
-        if dense or config.get("attention_bias", False) \
+        only = set(config.get("mlp_only_layers") or ())
+        mlp = tuple("dense" if i in only else t for i, t in enumerate(
+            config.get("mlp_layer_types") or ("sparse",) * depth))[:depth]
+        dense = sorted(set(mlp) - set(self.mlp_types))
+        if dense or len(mlp) != depth or config.get("attention_bias", False) \
                 or config.get("hidden_act", "silu") != "silu" \
                 or not config.get("use_sliding_window", True):
             raise MXNetError(
-                "MellumArch: mlp_layer_types other than sparse (%s), "
+                "%s: mlp_layer_types other than %s (%s), "
                 "attention_bias, an activation other than silu and "
                 "use_sliding_window false are not implemented (the "
-                "published model has none of them)" % (dense or "none"))
-        self.num_layers = self.num_hidden_layers
-        self.num_heads = self.num_attention_heads
-        if self.num_heads % self.num_key_value_heads or self.head_dim % 2 \
-                or self.sliding_window < 1:
+                "published model has none of them)"
+                % (who, " or ".join(self.mlp_types), dense or "none"))
+        gating = config.get("gating", False)
+        kinds = set(config.get("gating_types") or ("per_head",))
+        if gating not in (False, True, "per-head") or kinds != {"per_head"} \
+                or float(config.get("moe_router_logit_softcapping") or 0) \
+                or config.get("moe_apply_router_weight_on_input", False) \
+                or int(config.get("decoder_sparse_step", 1)) != 1:
             raise MXNetError(
-                "MellumArch: %d heads over %d K/V heads of %d and a window "
-                "of %d do not divide" % (
-                    self.num_heads, self.num_key_value_heads, self.head_dim,
+                "%s: gating other than per-head (%r, %s), a router logit "
+                "soft cap, the router's weight on the input and a "
+                "decoder_sparse_step other than 1 are not implemented (the "
+                "published model has none of them)"
+                % (who, gating, sorted(kinds)))
+        self.gated = gating in (True, "per-head")
+        self.shared_width = int(config.get("shared_expert_intermediate_size",
+                                           0))
+        self.dense_layers = [i for i, t in enumerate(mlp) if t == "dense"]
+        self.intermediate_size = int(config.get("intermediate_size", 0))
+        self.expert_layers = [i for i, t in enumerate(mlp) if t != "dense"]
+        self.num_layers = depth
+        self.num_heads = self.num_attention_heads
+        self.heads = tuple(int(h) for h in (
+            config.get("num_attention_heads_per_layer")
+            or (self.num_heads,) * depth)[:depth])
+        if len(self.heads) != depth or self.head_dim % 2 \
+                or self.sliding_window < 1 \
+                or any(h % self.num_key_value_heads for h in self.heads):
+            raise MXNetError(
+                "%s: %s heads over %d K/V heads of %d and a window of %d "
+                "do not divide" % (
+                    who, "/".join(str(h) for h in sorted(set(self.heads))),
+                    self.num_key_value_heads, self.head_dim,
                     self.sliding_window))
         self.kv_width = self.num_key_value_heads * self.head_dim
         self.eps = float(config["rms_norm_eps"])
@@ -160,22 +222,23 @@ class MellumArch(Architecture):
         first = self.share_index * self.num_experts
         if first + self.num_experts > self.router_width:
             raise MXNetError(
-                "MellumArch: share %d of %d held experts lies outside the "
-                "router's %d" % (self.share_index, self.num_experts,
+                "%s: share %d of %d held experts lies outside the "
+                "router's %d" % (who, self.share_index, self.num_experts,
                                  self.router_width))
         # the convention's code divides the chosen probabilities by their
-        # plain sum: no epsilon, no scaling
+        # plain sum: no epsilon; scaling 1 unless the config says otherwise
         self.share = ExpertShare(
-            self.num_experts_per_tok, 1.0,
+            self.num_experts_per_tok,
+            float(config.get("moe_routed_scaling_factor", 1.0)),
             bool(config.get("norm_topk_prob", True)), 0.0, first,
             self.num_experts, self.eps, "softmax")
         rope = config["rope_parameters"]
         lacking = [t for t in set(self.layer_types) if t not in rope]
         if lacking:
-            raise MXNetError("MellumArch: rope_parameters lacks %s"
-                             % ", ".join(sorted(lacking)))
-        #: per kind: (inverse frequencies float64, what cos and sin are
-        #: multiplied by)
+            raise MXNetError("%s: rope_parameters lacks %s"
+                             % (who, ", ".join(sorted(lacking))))
+        #: per kind: (inverse frequencies float64 over the kind's rotary
+        #: lanes, what cos and sin are multiplied by)
         self.rotary = {t: rotary_table(self.head_dim, rope[t])
                        for t in set(self.layer_types)}
         self.window_layers = [i for i, t in enumerate(self.layer_types)
@@ -191,20 +254,33 @@ class MellumArch(Architecture):
                "final_norm_gamma": (e,)}
         if not self.tied:
             out["lm_head_weight"] = (self.vocab_size, e)
-        for i in range(self.num_layers):
+        for i, heads in enumerate(self.heads):
             pre = "layer%d_" % i
             out.update({pre + "op_norm_gamma": (e,),
                         pre + "ffn_norm_gamma": (e,),
-                        pre + "attn_q_weight": (self.num_heads * hd, e),
+                        pre + "attn_q_weight": (heads * hd, e),
                         pre + "attn_k_weight": (self.kv_width, e),
                         pre + "attn_v_weight": (self.kv_width, e),
-                        pre + "attn_out_weight": (e, self.num_heads * hd),
+                        pre + "attn_out_weight": (e, heads * hd),
                         pre + "attn_q_norm_gamma": (hd,),
-                        pre + "attn_k_norm_gamma": (hd,),
-                        pre + "router_weight": (self.router_width, e),
+                        pre + "attn_k_norm_gamma": (hd,)})
+            if self.gated:
+                out[pre + "attn_gate_weight"] = (heads, e)
+            if i in self.dense_layers:
+                w = self.intermediate_size
+                out.update({pre + "ffn_gate_weight": (w, e),
+                            pre + "ffn_up_weight": (w, e),
+                            pre + "ffn_down_weight": (e, w)})
+                continue
+            out.update({pre + "router_weight": (self.router_width, e),
                         pre + "experts_gate_weight": (n, f, e),
                         pre + "experts_up_weight": (n, f, e),
                         pre + "experts_down_weight": (n, e, f)})
+            if self.shared_width:
+                w = self.shared_width
+                out.update({pre + "shared_gate_weight": (w, e),
+                            pre + "shared_up_weight": (w, e),
+                            pre + "shared_down_weight": (e, w)})
         return out
 
     def validate(self, host_params, max_len, mesh, quant_mode, spec_k=0,
@@ -264,7 +340,7 @@ class MellumArch(Architecture):
         return out
 
     def counters(self):
-        return moe_counters(self.num_layers, self.num_experts)
+        return moe_counters(len(self.expert_layers), self.num_experts)
 
     def load(self, params):
         return params      # as stored: no float32 copy (int8 was refused)
@@ -279,8 +355,7 @@ class MellumArch(Architecture):
         if mesh is not None:
             self.slot_partition()
         f32 = jnp.float32
-        heads, groups, hd = (self.num_heads, self.num_key_value_heads,
-                             self.head_dim)
+        groups, hd = self.num_key_value_heads, self.head_dim
         eps, window, scale = self.eps, self.sliding_window, hd ** -0.5
         names = {"sliding_attention": ("k_win", "v_win", "window"),
                  "full_attention": ("k", "v", "full")}
@@ -288,6 +363,7 @@ class MellumArch(Architecture):
                {i: n for n, i in enumerate(self.window_layers)},
                "full_attention":
                {i: n for n, i in enumerate(self.full_layers)}}
+        moe_index = {i: m for m, i in enumerate(self.expert_layers)}
 
         def token_pass(state, params, tokens, pos, live):
             state = dict(state)
@@ -319,7 +395,7 @@ class MellumArch(Architecture):
             for i, kind in enumerate(self.layer_types):
                 def p(name, pre="layer%d_" % i):
                     return params[pre + name]
-                n = own[kind][i]
+                n, heads = own[kind][i], self.heads[i]
                 kname, vname, tag = names[kind]
                 wrow, cos, sin, over = kinds[kind]
                 with jax.named_scope("layer/attn/" + tag):
@@ -340,10 +416,12 @@ class MellumArch(Architecture):
                     o = over((ck, cv), n,
                              lambda mask, kr, vr: gqa_attention(
                                  q, kr, vr, mask, scale))
+                    if self.gated:
+                        o = head_gate(o, a, p("attn_gate_weight"))
                     x = x + linear(o, p("attn_out_weight"))
-                _, y, counts = routed_share(x, p, self.share, live, nlive,
-                                            counts, i)
-                x = x + y
+                x, counts = feed_forward(x, p, self.share, live, nlive,
+                                         counts, moe_index.get(i),
+                                         shared=bool(self.shared_width))
             with jax.named_scope("head"):
                 logits = linear(
                     rms_norm(x, params["final_norm_gamma"], eps),

@@ -247,9 +247,24 @@ def _harness_tests():
     "test_the_readers_on_the_recorded_trace",
     "test_the_readers_return_nothing_where_there_is_nothing",
     "test_the_new_cell_and_metrics_are_declared"])
-def test_the_harness_tests_of_the_cells_files(name):
+def test_the_harness_tests_of_the_cells_files(name, tmp_path, monkeypatch):
     mod = _harness_tests()
     fn = getattr(mod, name)
+    if name == "test_the_new_cell_and_metrics_are_declared":
+        # it holds the cell's own metrics to list the cell ALONE, which a
+        # later cell that reads the same metric appends itself to: it runs
+        # over BENCHMARK.json as it stood when this cell was the last, the
+        # later cells taken off every list, nothing else changed
+        bench = _json("BENCHMARK.json")
+        names = [w["name"] for w in bench["workloads"]]
+        later = set(names[names.index(CELL) + 1:])
+        for m in bench["end_to_end"] + bench["per_layer"]:
+            if "workloads" in m:
+                m["workloads"] = [w for w in m["workloads"]
+                                  if w not in later]
+        with open(os.path.join(str(tmp_path), "BENCHMARK.json"), "w") as f:
+            json.dump(bench, f)
+        monkeypatch.setattr(mod, "REPO", str(tmp_path))
     wants = fn.__code__.co_varnames[:fn.__code__.co_argcount]
     have = {"cfg": mod.load_cfg(), "ref": mod.load_ref()}
     if "ctx" in wants:

@@ -201,3 +201,25 @@ def test_the_request_records_metrics_stay_declared_for_their_cells():
     for name in _readers.NEW:
         assert os.path.isfile(os.path.join(ROOT, "benchmark", "metrics",
                                            name + ".py"))
+
+
+# That test holds the prefill readers' three metrics to be the LAST of
+# ``per_layer``; a later cell's own metrics are appended after them. It
+# runs over BENCHMARK.json as it stood when they were the last: the
+# metrics appended since taken off the end, nothing else changed.
+_declared = getattr(
+    _passes, "test_the_new_metrics_are_declared_with_their_cells_and_readers")
+
+
+def test_the_new_metrics_are_declared_with_their_cells_and_readers(
+        tmp_path, monkeypatch):
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    names = [m["name"] for m in bench["per_layer"]]
+    bench["per_layer"] = bench["per_layer"][
+        :names.index("prefill_device_share.kimi") + 1]
+    with open(os.path.join(str(tmp_path), "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    os.symlink(os.path.join(ROOT, "benchmark"), str(tmp_path / "benchmark"))
+    monkeypatch.setattr(_passes, "BENCH", str(tmp_path / "benchmark"))
+    _declared()
