@@ -113,9 +113,9 @@ class Architecture(object):
         return {}
 
     def compiler_options(self, platform):
-        """Options this architecture's step program is compiled with on
-        ``platform`` (``jax.default_backend()``): ``{}`` leaves the
-        compiler to itself, as every architecture but one does."""
+        """Options this architecture's step program and prefill program are
+        compiled with on ``platform`` (``jax.default_backend()``): ``{}``
+        leaves the compiler to itself, as most architectures do."""
         return {}
 
     def slot_partition(self):

@@ -716,7 +716,10 @@ PACKED_ROWS = 256
 #: for its own time, ``g x pass / step`` tokens not emitted, and frees one
 #: slot-step a position it carries, a token emitted later: it breaks even
 #: at ``g x pass / step`` positions, about ``g`` (a pass takes a step's
-#: time or a little more). At break-even nothing is gained, and waiting
+#: time or a little more: Kimi-K2's 1.1 steps; LFM2-24B-A2B's 1.7, whose
+#: 40 layers of dense held experts at 256 rows are bound by their
+#: products, so its passes clear break-even by less: chip runs on one
+#: v5e, PERF.md §6). At break-even nothing is gained, and waiting
 #: costs nothing: the due slots ride the steps one token at a time
 #: meanwhile, exactly as without a pass. So the loop holds the pass back
 #: until it carries TWICE that: half its positions are then profit, and at
@@ -925,7 +928,8 @@ class DecodeLoop(object):
                 self._prefill_c = compile_one(
                     "prefill[chunk=%d,len=%d]" % (self._chunk, self.max_len),
                     prefill, (state_s, params_s, rows_s, where_s, where_s,
-                              scalar_s), (0,))
+                              scalar_s), (0,),
+                    arch.compiler_options(jax.default_backend()))
         #: a traced run's copy of the device counters (see
         #: :meth:`_snapshot_counters`), built with the others: a compile
         #: inside a measured window is failed work

@@ -65,6 +65,8 @@ same) how many the router ranks, ``share_index`` which are here.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from ..base import MXNetError
@@ -101,6 +103,43 @@ def short_conv(u, prev, weight, pos):
     return acc
 
 
+def segment_conv(u, state, weight, slot, pos):
+    """:func:`short_conv` for the rows of a packed prefill pass: ``u``
+    (rows, hidden) float32 is ``u_t`` of row ``r``, position ``pos[r]`` of
+    slot ``slot[r]``, the rows of one slot consecutive and ascending (a
+    SEGMENT); ``state`` (slots, K - 1, hidden) as stored holds each slot's
+    ``u`` of the positions before its segment, newest first. Tap ``j``
+    reads row ``r - j`` where that row is in ``r``'s segment, else the
+    slot's stored row ``j - k - 1`` (``k`` rows of the segment lie before
+    ``r``), and counts where ``pos - j >= 0``, as the step's does. A row
+    that reaches back reads ``u`` rounded to the stored dtype, as the step
+    reads it from the state; the current position's tap stays float32.
+
+    Returns ``(c, window)``: the convolution (rows, hidden) float32 and
+    each row's last ``K - 1`` positions newest first (rows, K - 1, hidden)
+    as the state would hold them once that row is in, the stored rows
+    shifted behind a segment shorter than that: a segment's LAST row's
+    window is the slot's new state."""
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    w = weight.astype(f32)
+    k = w.shape[1]
+    stored = u.astype(state.dtype).astype(f32)
+    acc = w[:, k - 1][None, :] * u
+    window, back = [stored], jnp.zeros(u.shape[0], jnp.int32)
+    for j in range(1, k):
+        mine = jnp.concatenate([jnp.zeros(j, bool), slot[j:] == slot[:-j]])
+        src = jnp.where(mine[:, None],
+                        jnp.concatenate([jnp.zeros((j,) + u.shape[1:], f32),
+                                         stored[:-j]]),
+                        state[slot, j - 1 - back].astype(f32))
+        tap = (pos >= j).astype(f32)[:, None]
+        acc = acc + w[:, k - 1 - j][None, :] * src * tap
+        window.append(src)
+        back = back + mine.astype(jnp.int32)
+    return acc, jnp.stack(window[:k - 1], axis=1)
+
+
 class Lfm2Arch(Architecture):
     """The LFM2 block from its ``config.json`` keys, with two of this
     repo's: ``router_width`` (the experts the router ranks; default
@@ -109,6 +148,7 @@ class Lfm2Arch(Architecture):
 
     name = "lfm2"
     wants_live = True
+    packed_prefill = True
 
     def __init__(self, config):
         missing = [k for k in _KEYS + ("layer_types", "norm_eps",
@@ -262,6 +302,55 @@ class Lfm2Arch(Architecture):
     def record_counters(self, health, counts, before):
         record_moe(health, counts, before)
 
+    # -- what the token pass and the prefill pass are both made of -------------
+    def _layer_parts(self):
+        """``(angles, param, gated_input, qkv)``: a layer's mathematics over
+        any number of rows, one position each. The token pass runs them over
+        a row a slot, the prefill pass over the rows of several slots'
+        prompts: ONE text for both, so they cannot drift."""
+        import jax.numpy as jnp
+        f32 = jnp.float32
+        heads, groups, hd = (self.num_heads, self.num_key_value_heads,
+                             self.head_dim)
+        eps = self.eps
+        inv_freq = np.asarray(self.inv_freq, np.float32)
+
+        def angles(pos):
+            angle = pos.astype(f32)[:, None] * inv_freq[None, :]
+            return jnp.cos(angle)[:, None], jnp.sin(angle)[:, None]
+
+        def param(params, i):
+            def p(name, pre="" if i is None else "layer%d_" % i):
+                if name == "router_bias" and not self.use_expert_bias:
+                    return jnp.zeros((self.router_width,), f32)
+                return params[pre + name]
+            return p
+
+        def gated_input(x, p):
+            """``(c, u)``: the conv operator's output gate and its gated
+            input ``u = B * z``."""
+            a = rms_norm(x, p("op_norm_gamma"), eps)
+            bcz = linear(a, p("conv_in_weight"))
+            b, c, z = jnp.split(bcz, 3, axis=-1)
+            return c, b * z
+
+        def qkv(x, p, cos, sin):
+            nrows = x.shape[0]
+            a = rms_norm(x, p("op_norm_gamma"), eps)
+            q = rms_norm(linear(a, p("attn_q_weight")).reshape(
+                nrows, heads, hd), p("attn_q_norm_gamma"), eps)
+            k = rms_norm(linear(a, p("attn_k_weight")).reshape(
+                nrows, groups, hd), p("attn_k_norm_gamma"), eps)
+            q, k = rope_half(q, cos, sin), rope_half(k, cos, sin)
+            return q, k, linear(a, p("attn_v_weight"))
+
+        return angles, param, gated_input, qkv
+
+    def _own(self):
+        """``{layer: its index among its kind's layers}``."""
+        return dict([(i, n) for n, i in enumerate(self.conv_layers)]
+                    + [(i, n) for n, i in enumerate(self.attn_layers)])
+
     # -- one position per slot through every layer -----------------------------
     def build_token_pass(self, mesh=None):
         import jax
@@ -269,15 +358,11 @@ class Lfm2Arch(Architecture):
         if mesh is not None:
             self.slot_partition()
         f32 = jnp.float32
-        heads, groups, hd = (self.num_heads, self.num_key_value_heads,
-                             self.head_dim)
         eps, taps = self.eps, self.conv_L_cache - 1
-        inv_freq = np.asarray(self.inv_freq, np.float32)
-        scale = hd ** -0.5
-        own = {"conv": {i: n for n, i in enumerate(self.conv_layers)},
-               "full_attention": {i: n for n, i
-                                  in enumerate(self.attn_layers)}}
+        scale = self.head_dim ** -0.5
+        own = self._own()
         moe_index = {i: m for m, i in enumerate(self.moe_layers)}
+        angles, param, gated_input, qkv = self._layer_parts()
 
         def token_pass(state, params, tokens, pos, live):
             ck, cv, conv = state.get("k"), state.get("v"), state.get("conv")
@@ -289,25 +374,17 @@ class Lfm2Arch(Architecture):
                 rows = ck.shape[2]
                 wpos = jnp.minimum(pos, jnp.int32(rows - 1))
                 with jax.named_scope("embed"):
-                    angle = wpos.astype(f32)[:, None] * inv_freq[None, :]
-                    cos, sin = jnp.cos(angle)[:, None], jnp.sin(angle)[:, None]
+                    cos, sin = angles(wpos)
                 over = over_filled_rows(pos, rows)
             counts = (state.get("moe_served"), state.get("moe_routed"))
             nlive = jnp.sum(live.astype(jnp.int32))
             # the scope names are what a device trace is searched for: the
             # same in every layer, so they sum by kind
             for i, kind in enumerate(self.layer_types):
-                def p(name, pre="layer%d_" % i):
-                    if name == "router_bias" and not self.use_expert_bias:
-                        return jnp.zeros((self.router_width,), f32)
-                    return params[pre + name]
-                n = own[kind][i]
+                p, n = param(params, i), own[i]
                 if kind == "conv":
                     with jax.named_scope("layer/conv"):
-                        a = rms_norm(x, p("op_norm_gamma"), eps)
-                        bcz = linear(a, p("conv_in_weight"))
-                        b, c, z = jnp.split(bcz, 3, axis=-1)
-                        u = b * z
+                        c, u = gated_input(x, p)
                         c = c * short_conv(u, conv[n][:, :taps],
                                            p("conv_weight"), pos)
                     with jax.named_scope("cache_write/conv"):
@@ -321,13 +398,7 @@ class Lfm2Arch(Architecture):
                         x = x + linear(c, p("conv_out_weight"))
                 else:
                     with jax.named_scope("layer/attn"):
-                        a = rms_norm(x, p("op_norm_gamma"), eps)
-                        q = rms_norm(linear(a, p("attn_q_weight")).reshape(
-                            nslots, heads, hd), p("attn_q_norm_gamma"), eps)
-                        k = rms_norm(linear(a, p("attn_k_weight")).reshape(
-                            nslots, groups, hd), p("attn_k_norm_gamma"), eps)
-                        q, k = rope_half(q, cos, sin), rope_half(k, cos, sin)
-                        v = linear(a, p("attn_v_weight"))
+                        q, k, v = qkv(x, p, cos, sin)
                     with jax.named_scope("cache_write/kv"):
                         ck = ck.at[n, sidx, wpos].set(
                             k.reshape(nslots, -1).astype(ck.dtype))
@@ -344,11 +415,141 @@ class Lfm2Arch(Architecture):
                     rms_norm(x, params["final_norm_gamma"], eps),
                     params["tok_embed_weight" if self.tied
                            else "lm_head_weight"])
-            out = {name: arr for name, arr in (("k", ck), ("v", cv),
-                                               ("conv", conv))
-                   if arr is not None}
-            if counts[0] is not None:
-                out.update(moe_served=counts[0], moe_routed=counts[1])
-            return out, logits
+            return self._state_out(ck, cv, conv, counts), logits
 
         return token_pass
+
+    @staticmethod
+    def _state_out(ck, cv, conv, counts):
+        out = {name: arr for name, arr in (("k", ck), ("v", cv),
+                                           ("conv", conv))
+               if arr is not None}
+        if counts[0] is not None:
+            out.update(moe_served=counts[0], moe_routed=counts[1])
+        return out
+
+    # -- the prompts of several slots behind one read of the weights -----------
+    def build_prefill_pass(self, mesh=None):
+        """The packed pass (:attr:`packed_prefill`): the token pass's own
+        mathematics over ``R`` rows, each a position of a slot it NAMES.
+
+        A conv layer runs :func:`segment_conv` over the rows: each slot's
+        segment reads its own earlier rows and, before them, the slot's
+        stored state, and the state is written ONCE a slot, from the
+        segment's last row (its last ``conv_L_cache - 1`` positions, the
+        stored rows shifted behind a shorter segment). An attention layer
+        scatters each live row's K and V to ``(layer, slot[r], pos[r])``,
+        then each row attends ITS slot's rows ``<= pos[r]`` as the arrays
+        now hold them, through :func:`.blocks.over_filled_rows` at the rung
+        above the pass's deepest position. Padding rows and the rows that
+        end no segment write out of bounds and are DROPPED. The LAST layer
+        stops at its state: no head, no sampler, nothing to read back. The
+        pass's positions count into ``moe_served`` and ``moe_routed`` as
+        they did when they rode a step (the last layer's experts run for
+        none of them)."""
+        import jax
+        import jax.numpy as jnp
+        if mesh is not None:    # a sharded loop is fed one position a step
+            return None
+        f32 = jnp.float32
+        taps = self.conv_L_cache - 1
+        scale = self.head_dim ** -0.5
+        own = self._own()
+        moe_index = {i: m for m, i in enumerate(self.moe_layers)}
+        angles, param, gated_input, qkv = self._layer_parts()
+
+        def weights(params, i):
+            pre = "layer%d_" % i
+            return {k[len(pre):]: v for k, v in params.items()
+                    if k.startswith(pre)}
+
+        # a layer's parts are traced once a KIND of layer, its weights and
+        # its expert layer's index arguments (the compiler inlines the
+        # calls): the pass's tracing and lowering are set-up time
+        @jax.jit
+        def conv_layer(x, w, stored, slot, pos, live, n, counts, m):
+            p = param(w, None)
+            with jax.named_scope("layer/conv"):
+                c, u = gated_input(x, p)
+                mix, window = segment_conv(u, stored, p("conv_weight"), slot,
+                                           pos)
+                x = x + linear(c * mix, p("conv_out_weight"))
+            x, counts = feed_forward(x, p, self.share, live, n, counts, m)
+            return x, window, counts
+
+        @jax.jit
+        def attn_in(x, w, cos, sin):
+            with jax.named_scope("layer/attn"):
+                return qkv(x, param(w, None), cos, sin)
+
+        @jax.jit
+        def attn_rows(q, mask, kr, vr):
+            return gqa_attention(q, kr, vr, mask, scale)
+
+        @jax.jit
+        def attn_out(x, o, w, live, n, counts, m):
+            p = param(w, None)
+            with jax.named_scope("layer/attn"):
+                x = x + linear(o, p("attn_out_weight"))
+            return feed_forward(x, p, self.share, live, n, counts, m)
+
+        def prefill_pass(state, params, tokens, slot, pos, n):
+            ck, cv, conv = state.get("k"), state.get("v"), state.get("conv")
+            nrows = tokens.shape[0]
+            at = jnp.arange(nrows, dtype=jnp.int32)
+            live = at < n
+            # a padding row reads slot 0 at position 0 and writes nowhere
+            # (each its own way out of bounds: the indices stay unique)
+            slot = jnp.where(live, slot, 0)
+            pos = jnp.where(live, pos, 0)
+            with jax.named_scope("embed"):
+                x = params["tok_embed_weight"][tokens].astype(f32)
+            if ck is not None:
+                rows = ck.shape[2]
+                wpos = jnp.where(live, pos, jnp.int32(rows) + at)
+                with jax.named_scope("embed"):
+                    cos, sin = angles(pos)
+                over = over_filled_rows(pos, rows, slot)
+            if conv is not None:
+                # a slot's state is written from its segment's last row
+                last = live & ((at + 1 >= n) | (jnp.roll(slot, -1) != slot))
+                wslot = jnp.where(last, slot, jnp.int32(conv.shape[1]) + at)
+            counts = (state.get("moe_served"), state.get("moe_routed"))
+            for i, kind in enumerate(self.layer_types):
+                w, m = weights(params, i), own[i]
+                mi = moe_index.get(i)
+                mi = None if mi is None else jnp.int32(mi)
+                final = i + 1 == self.num_layers
+                if kind == "conv":
+                    if final:
+                        p = param(w, None)
+                        with jax.named_scope("layer/conv"):
+                            c, u = gated_input(x, p)
+                            _, window = segment_conv(
+                                u, conv[m][:, :taps], p("conv_weight"), slot,
+                                pos)
+                    else:
+                        x, window, counts = conv_layer(
+                            x, w, conv[m][:, :taps], slot, pos, live, n,
+                            counts, mi)
+                    with jax.named_scope("cache_write/conv"):
+                        conv = conv.at[m, wslot, :taps].set(
+                            window.astype(conv.dtype), mode="drop",
+                            unique_indices=True)
+                    continue
+                q, k, v = attn_in(x, w, cos, sin)
+                with jax.named_scope("cache_write/kv"):
+                    ck = ck.at[m, slot, wpos].set(
+                        k.reshape(nrows, -1).astype(ck.dtype), mode="drop",
+                        unique_indices=True)
+                    cv = cv.at[m, slot, wpos].set(
+                        v.astype(cv.dtype), mode="drop",
+                        unique_indices=True)
+                if final:
+                    break
+                with jax.named_scope("layer/attn"):
+                    o = over((ck, cv), m, functools.partial(attn_rows, q))
+                x, counts = attn_out(x, o, w, live, n, counts, mi)
+            return self._state_out(ck, cv, conv, counts)
+
+        return prefill_pass

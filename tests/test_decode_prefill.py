@@ -139,12 +139,16 @@ def test_an_architecture_is_fed_one_position_a_step_unless_it_says_how():
 def test_the_other_architectures_are_fed_one_position_a_step(tests,
                                                               reference, why):
     """Each is a PR of its own (``why``; Kimi's latent cache has a PACKED
-    pass from PR 39, ``tests/test_decode_prefill_packed.py``):
-    ``build_prefill_pass`` is ``None``, so the tiny loop of the architecture's own tests compiles no
-    prefill program and the step with the arguments it had, and a prompt longer
-    than any ``MIN_PREFILL`` takes a step a position, as before PR 37 (the
-    step's lowered text was compared with the parent's once, sha for sha:
-    PERF.md, PR 37)."""
+    pass from PR 39, and LFM2's conv state a packed pass too, its chunk-wide
+    convolution leaving each slot's last rows,
+    ``tests/test_decode_prefill_packed.py``): where ``build_prefill_pass``
+    is ``None`` the tiny loop of the architecture's own tests compiles no
+    prefill program and the step with the arguments it had, and a prompt
+    longer than any ``MIN_PREFILL`` takes a step a position, as before PR
+    37 (the step's lowered text is held to the parent's,
+    ``tests/test_decode_request.py``). LFM2's loop compiles its packed
+    pass beside the step with the step's arguments unchanged, and the same
+    prompt goes in by one pass."""
     import importlib
     import test_lfm2_arch
     mod = importlib.import_module(tests)
@@ -152,15 +156,20 @@ def test_the_other_architectures_are_fed_one_position_a_step(tests,
     prompt = [int(t) for t in np.random.RandomState(37).randint(1, 90, 21)]
     assert len(prompt) - 1 >= 2 * decode.MIN_PREFILL
     loop = mod._loop(params)
+    packed = loop._arch.packed_prefill
+    assert packed == (tests == "test_lfm2_arch")
     try:
-        assert loop._arch.build_prefill_pass() is None
-        assert loop._prefill_c is None
+        assert (loop._arch.build_prefill_pass() is None) != packed
+        assert (loop._prefill_c is None) != packed
         # beside the step, from PR 38, the non-donating copy of the device
         # counters that a traced run reads them through
         assert list(loop._programs) == [
-            "%s/step[slots=%d,len=%d]" % (loop.name, mod.SLOTS, mod.MAX_LEN),
+            "%s/step[slots=%d,len=%d]" % (loop.name, mod.SLOTS, mod.MAX_LEN)
+        ] + ["%s/prefill[chunk=%d,len=%d]" % (loop.name, mod.MAX_LEN,
+                                              mod.MAX_LEN)] * packed + [
             "%s/counters[moe_served,moe_routed]" % loop.name]
-        (_, structs, donate), (_, _, kept) = loop._programs.values()
+        programs = list(loop._programs.values())
+        (_, structs, donate), (_, _, kept) = programs[0], programs[-1]
         assert len(structs) == 9 + loop._arch.wants_live and donate == (0,)
         assert kept == ()
         out = loop.generate(prompt, 5).result(timeout=120)
@@ -168,9 +177,11 @@ def test_the_other_architectures_are_fed_one_position_a_step(tests,
     finally:
         loop.close()
     assert len(out) == 5
-    assert health["decode_steps"] == len(prompt) + 5 - 1
-    assert health["prompt_positions"] == len(prompt) - 1
-    assert health["prefill_passes"] == health["prefill_positions"] == 0
+    fed = len(prompt) - 1
+    assert health["prompt_positions"] == fed
+    assert health["decode_steps"] == len(prompt) + 5 - 1 - fed * packed
+    assert health["prefill_passes"] == int(packed)
+    assert health["prefill_positions"] == fed * packed
 
 
 # ---------------------------------------------------------------------------

@@ -3,21 +3,27 @@
 position, the loop fills it from every slot that is due and holds it back
 until it carries enough positions to pay for its read of the weights.
 Held here, on the tiny Kimi-K2 configuration of
-``tests/test_deepseek_v3_decode.py`` in float32 and in bfloat16:
+``tests/test_deepseek_v3_decode.py`` and on the tiny LFM2 one
+of ``tests/test_lfm2_arch.py`` (``Lfm2Arch``'s pass: a chunk-wide short
+convolution beside grouped-query attention; its cases are named
+``lfm2_..``), in float32 and in bfloat16:
 
 * the PROGRAM against the token pass fed one position a step: a pass over
   1, 2 and 3 slots at mixed starts (0, over rows that were there, a second
   pass behind a first) leaves the named rows one-token feeding's and every
-  other row of every slot bit for bit as it was; padding moves nothing,
-  also where ``pos + R`` passes the cache's depth; the routing counters
-  count the pass's rows as the steps counted them, in every expert layer
-  the pass runs (the LAST layer stops at its latent row, so its experts
-  serve no row of a pass and count none);
+  other row of every slot bit for bit as it was; LFM2's conv state of each
+  named slot is the state one-token feeding leaves, for segments of 1, 2,
+  3 and more positions, over a state a predecessor left (the tap mask
+  hides it) and behind positions that rode steps or an earlier pass;
+  padding moves nothing, also where ``pos + R`` passes the cache's depth;
+  the routing counters count the pass's rows as the steps counted them, in
+  every expert layer the pass runs (the LAST layer stops at its state, so
+  its experts serve no row of a pass and count none);
 * the RULE by count, with no device: when a pass is held back and when it
   fires, what it carries, what it leaves of each slot;
-* the LOOP: the tokens of one-token feeding, greedy and sampled; the
-  counters and the spans; ``eos_id``, the prefix cache, speculation, a
-  model mesh.
+* the LOOP: the tokens of one-token feeding, greedy and sampled (both
+  architectures); the counters and the spans; ``eos_id``, the prefix
+  cache, speculation, a model mesh.
 """
 import contextlib
 import functools
@@ -42,15 +48,35 @@ from test_deepseek_v3_decode import MAX_LEN, SLOTS, TINY
 R = 32          # rows of a pass in the program tests
 NEVER = 10 ** 9
 DTYPES = ["float32", "bfloat16"]
+#: the loop tests' cases: Kimi's by its dtype alone, LFM2's beside them
+VARIANTS = DTYPES + ["lfm2_" + d for d in DTYPES]
+#: those whose packing loop emits one-token feeding's tokens to the token.
+#: LFM2's bfloat16 pass rounds rows a bfloat16 unit apart from the step's
+#: where XLA:CPU sums a product of 24 rows in another order than one of 3
+#: (its float32 rows differ in the sixth figure), and its tiny model's
+#: large weights carry that to a greedy near-tie once in 40 tokens: judged
+#: against the reference instead
+TOKEN_EXACT = DTYPES + ["lfm2_float32"]
+
+
+def _variant(name):
+    """``(model, the rest)`` of a case or variant named ``[lfm2_]..``."""
+    if name.startswith("lfm2_"):
+        return "lfm2", name[len("lfm2_"):]
+    return "kimi", name
 
 
 @functools.lru_cache(maxsize=None)
-def _model(dtype):
+def _model(dtype, model="kimi"):
     """``(arch, params on the device)`` of the tiny configuration."""
-    cfg = dict(TINY, dtype=dtype)
-    params = test_lfm2_arch._load("kimi-k2-ep32").make_params(cfg, 7)
-    return serving.DeepseekV3Arch(cfg), \
-        {k: jnp.asarray(v) for k, v in params.items()}
+    if model == "lfm2":
+        cfg = dict(test_lfm2_arch.TINY, dtype=dtype)
+        make, arch = "lfm2-24b-a2b-ep8", serving.Lfm2Arch(cfg)
+    else:
+        cfg = dict(TINY, dtype=dtype)
+        make, arch = "kimi-k2-ep32", serving.DeepseekV3Arch(cfg)
+    params = test_lfm2_arch._load(make).make_params(cfg, 7)
+    return arch, {k: jnp.asarray(v) for k, v in params.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -58,7 +84,7 @@ def _model(dtype):
 # ---------------------------------------------------------------------------
 
 #: name -> (rows of the cache, [pass, ...]), a pass ``[(slot, pos0, n)]`` in
-#: the order its rows lie in
+#: the order its rows lie in; an ``lfm2_`` case runs the LFM2 model
 CASES = {
     "one_slot_from_0": (MAX_LEN, [[(1, 0, 20)]]),
     "two_slots_one_over_rows_that_were_there":
@@ -69,6 +95,21 @@ CASES = {
     "pos_plus_R_passes_the_depth": (MAX_LEN, [[(2, 40, 8)]]),
     "all_padding": (MAX_LEN, [[]]),
     "across_a_rung_of_the_ladder": (400, [[(1, 200, 16), (0, 3, 9)]]),
+    # segments of 1, 2, 3 and more positions from 0, over the state a
+    # predecessor left, then each a segment of 1 or 2 behind its own
+    "lfm2_segments_of_1_2_3_and_more_then_short_ones":
+        (MAX_LEN, [[(0, 0, 1), (1, 0, 2), (2, 0, 3)],
+                   [(2, 3, 1), (0, 1, 2), (1, 2, 1)],
+                   [(1, 3, 7), (2, 4, 2)]]),
+    "lfm2_segments_of_1_2_3_over_rows_that_rode_steps":
+        (MAX_LEN, [[(0, 5, 1), (1, 9, 2), (2, 4, 3)]]),
+    "lfm2_three_slots_full_then_a_second_pass":
+        (MAX_LEN, [[(0, 0, 10), (1, 0, 12), (2, 0, 10)],
+                   [(1, 12, 9), (0, 10, 5)]]),
+    "lfm2_pos_plus_R_passes_the_depth": (MAX_LEN, [[(2, 40, 8)]]),
+    "lfm2_all_padding": (MAX_LEN, [[]]),
+    "lfm2_across_a_rung_of_the_ladder":
+        (400, [[(1, 200, 16), (0, 3, 9)]]),
 }
 
 
@@ -76,11 +117,18 @@ def _base_state(arch, rows, dtype):
     """Slots whose every row holds SOMETHING (what an earlier occupant, a
     prefix hit or the steps left there), the surplus lanes zero."""
     rs = np.random.RandomState(11)
-    lat = np.zeros((arch.num_layers, SLOTS, rows, arch.latent_width),
-                   np.float32)
-    lat[..., :arch.latent] = rs.randn(arch.num_layers, SLOTS, rows,
-                                      arch.latent) * 0.5
-    state = {"latent": jnp.asarray(lat).astype(dtype)}
+    if isinstance(arch, serving.Lfm2Arch):
+        quant = "bf16" if dtype == "bfloat16" else "none"
+        state = {k: jnp.asarray(rs.randn(a.layers, SLOTS, a.depth(rows),
+                                         a.width).astype(np.float32) * 0.5)
+                 .astype(dtype)
+                 for k, a in sorted(arch.slot_state(None, quant).items())}
+    else:
+        lat = np.zeros((arch.num_layers, SLOTS, rows, arch.latent_width),
+                       np.float32)
+        lat[..., :arch.latent] = rs.randn(arch.num_layers, SLOTS, rows,
+                                          arch.latent) * 0.5
+        state = {"latent": jnp.asarray(lat).astype(dtype)}
     state.update({k: jnp.asarray(rs.randint(0, 9, s).astype(np.int32))
                   for k, s in arch.counters().items()})
     return state
@@ -99,10 +147,17 @@ def _pass_args(group, seed):
     return tokens, slot, pos, at
 
 
+#: state arrays a slot keeps a fixed number of rows of, stepped once a
+#: position whatever the position (LFM2's conv state)
+STEPPED = ("conv",)
+
+
 def _one_position_a_step(arch, params, state, group, tokens, rows):
     """The token pass over the pass's positions, every slot one position a
     step as a loop feeds them; a slot with nothing (more) to feed is not
-    live and parked on a row the pass does not name."""
+    live and parked on a row the pass does not name. A step shifts the
+    conv state of EVERY slot, so each slot's is kept as its own last
+    position left it (a slot the pass does not name: as it was)."""
     step = jax.jit(arch.build_token_pass())
     todo, at = {}, 0
     for s, pos0, n in group:
@@ -111,6 +166,7 @@ def _one_position_a_step(arch, params, state, group, tokens, rows):
     named = {s: set(range(p, p + len(t))) for s, (p, t) in todo.items()}
     park = [max(set(range(rows)) - named.get(s, set()))
             for s in range(SLOTS)]
+    kept = {k: np.asarray(state[k]).copy() for k in STEPPED if k in state}
     for t in range(max([len(v[1]) for v in todo.values()] or [0])):
         tok = np.zeros(SLOTS, np.int32)
         pos = np.asarray(park, np.int32)
@@ -119,7 +175,11 @@ def _one_position_a_step(arch, params, state, group, tokens, rows):
             if t < len(toks):
                 tok[s], pos[s], live[s] = toks[t], pos0 + t, True
         state, _ = step(state, params, tok, pos, live)
-    return state
+        for k in kept:
+            for s, (_, toks) in todo.items():
+                if t == len(toks) - 1:
+                    kept[k][:, s] = np.asarray(state[k][:, s])
+    return dict(state, **{k: jnp.asarray(v) for k, v in kept.items()})
 
 
 @functools.lru_cache(maxsize=None)
@@ -127,7 +187,7 @@ def _ran(dtype, case):
     """Each pass of ``case`` by the packed pass and by one-token feeding,
     both from the state the pass before left: ``[(group, before, by the
     pass, by the steps)]``."""
-    arch, params = _model(dtype)
+    arch, params = _model(dtype, _variant(case)[0])
     rows, groups = CASES[case]
     prefill = jax.jit(decode._build_prefill_fn(arch))
     state = dict(_base_state(arch, rows, dtype),
@@ -144,11 +204,20 @@ def _ran(dtype, case):
     return out
 
 
-def _named(group, shape):
+def _named(group, shape, name="latent"):
+    """Where the pass writes array ``name`` (layers, slots, rows): the rows
+    it names; of a state stepped once a position, all of a named slot."""
     mine = np.zeros(shape, bool)
     for s, pos0, n in group:
-        mine[:, s, pos0:pos0 + n] = True
+        if name in STEPPED:
+            mine[:, s] = True
+        else:
+            mine[:, s, pos0:pos0 + n] = True
     return mine
+
+
+def _arrays(arch):
+    return sorted(arch.slot_state(None, "none"))
 
 
 def _close(got, want, dtype):
@@ -161,14 +230,15 @@ def _close(got, want, dtype):
 @pytest.mark.parametrize("case", sorted(CASES))
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_a_packed_pass_writes_the_rows_of_one_token_feeding(dtype, case):
-    arch, params = _model(dtype)
+    arch, params = _model(dtype, _variant(case)[0])
     rows = CASES[case][0]
     for group, _, new, ref in _ran(dtype, case):
-        mine = _named(group, new["latent"].shape[:3])
-        if not mine.any():
+        if not group:
             continue
-        _close(np.asarray(new["latent"].astype(np.float32))[mine],
-               np.asarray(ref["latent"].astype(np.float32))[mine], dtype)
+        for name in _arrays(arch):
+            mine = _named(group, new[name].shape[:3], name)
+            _close(np.asarray(new[name].astype(np.float32))[mine],
+                   np.asarray(ref[name].astype(np.float32))[mine], dtype)
         # and the step behind the pass reads what one-token feeding reads:
         # every slot's next position over either state
         step = jax.jit(arch.build_token_pass())
@@ -188,18 +258,20 @@ def test_a_packed_pass_moves_nothing_but_the_rows_it_names(dtype, case):
     """Not a slot's other rows, not a slot the pass does not name, not the
     loop's own members: padding rows (which name slot 7 at position 10^6
     here) are dropped, also where ``pos + R`` passes the depth."""
+    arch = _model(dtype, _variant(case)[0])[0]
     for group, before, new, _ in _ran(dtype, case):
         assert sorted(new) == sorted(before)
-        got, was = np.asarray(new["latent"]), np.asarray(before["latent"])
-        kept = ~_named(group, got.shape[:3])
-        np.testing.assert_array_equal(got[kept], was[kept])
+        for name in _arrays(arch):
+            got, was = np.asarray(new[name]), np.asarray(before[name])
+            kept = ~_named(group, got.shape[:3], name)
+            np.testing.assert_array_equal(got[kept], was[kept])
         for name in ("seed", "tok"):
             np.testing.assert_array_equal(np.asarray(new[name]),
                                           np.asarray(before[name]))
         # the surplus lanes of the rows it wrote stay zero
-        arch = _model(dtype)[0]
-        assert not np.asarray(new["latent"][..., arch.latent:]
-                              .astype(np.float32)).any()
+        if "latent" in new:
+            assert not np.asarray(new["latent"][..., arch.latent:]
+                                  .astype(np.float32)).any()
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -207,9 +279,10 @@ def test_a_packed_pass_moves_nothing_but_the_rows_it_names(dtype, case):
 def test_a_packed_pass_counts_its_rows_as_the_steps_counted_them(dtype,
                                                                  case):
     """``moe_served`` / ``moe_routed`` by either feeding, in every expert
-    layer the pass runs; its last layer stops at its latent row, so the
-    last expert layer serves and counts none of a pass's rows."""
-    top_k = TINY["num_experts_per_tok"]
+    layer the pass runs; its last layer stops at its state, so the last
+    expert layer serves and counts none of a pass's rows."""
+    arch = _model(dtype, _variant(case)[0])[0]
+    top_k = arch.share.top_k
     for group, before, new, ref in _ran(dtype, case):
         n = sum(g[2] for g in group)
         for name in ("moe_served", "moe_routed"):
@@ -217,7 +290,7 @@ def test_a_packed_pass_counts_its_rows_as_the_steps_counted_them(dtype,
             np.testing.assert_array_equal(got[:-1], want[:-1])
             np.testing.assert_array_equal(got[-1], was[-1])
         grew = np.asarray(new["moe_routed"]) - np.asarray(before["moe_routed"])
-        assert grew.tolist() == [top_k * n, 0]
+        assert grew.tolist() == [top_k * n] * (len(grew) - 1) + [0]
 
 
 def test_the_rung_is_the_pass_deepest_positions():
@@ -274,17 +347,52 @@ def test_the_last_layer_stops_at_its_latent_row():
                            "tok"]
 
 
+def test_the_last_layer_of_lfm2_stops_at_its_conv_state():
+    """LFM2's last layer is a conv layer: the pass writes its state and
+    reads neither its output projection nor its feed-forward, nor the
+    head's norm."""
+    arch, params = _model("float32", "lfm2")
+    assert arch.layer_types[-1] == "conv"
+    state = dict(_base_state(arch, MAX_LEN, "float32"),
+                 seed=jnp.zeros(SLOTS, np.uint32),
+                 tok=jnp.zeros(SLOTS, np.int32))
+    fn = decode._build_prefill_fn(arch)
+    args = (jnp.zeros(R, np.int32),) * 3 + (jnp.int32(4),)
+    jaxpr = jax.make_jaxpr(fn)(state, params, *args)
+    used = {str(v) for eqn in jaxpr.eqns for v in eqn.invars}
+    flat, _ = jax.tree_util.tree_flatten_with_path((state, params))
+    unused = {jax.tree_util.keystr(path) for (path, _), var in
+              zip(flat, jaxpr.jaxpr.invars) if str(var) not in used}
+    last = "layer%d_" % (arch.num_layers - 1)
+    assert {"[1]['final_norm_gamma']", "[1]['%sconv_out_weight']" % last,
+            "[1]['%sffn_norm_gamma']" % last, "[1]['%srouter_weight']" % last,
+            "[1]['%sexperts_down_weight']" % last} <= unused
+    assert not {"[1]['%sconv_in_weight']" % last,
+                "[1]['%sconv_weight']" % last} & unused
+    out = jax.eval_shape(fn, state, params, *args)
+    assert sorted(out) == ["conv", "k", "moe_routed", "moe_served", "seed",
+                           "tok", "v"]
+
+
 def test_which_architectures_pack_and_what_the_constants_are():
+    import test_laguna_arch
+    import test_mellum_arch
     from mxnet_tpu.serving.arch import Architecture
     assert not Architecture.packed_prefill
     assert not decode.OptArch.packed_prefill
     assert serving.DeepseekV3Arch.packed_prefill
-    assert not serving.Lfm2Arch.packed_prefill
+    assert serving.Lfm2Arch.packed_prefill
     assert not serving.MellumArch.packed_prefill
-    arch = _model("float32")[0]
-    assert arch.build_prefill_pass() is not None
-    # a sharded loop keeps one-token feeding: no sharded pass
-    assert arch.build_prefill_pass(mesh=object()) is None
+    assert not serving.LagunaArch.packed_prefill
+    for arch in (_model("float32")[0], _model("float32", "lfm2")[0]):
+        assert arch.build_prefill_pass() is not None
+        # a sharded loop keeps one-token feeding: no sharded pass
+        assert arch.build_prefill_pass(mesh=object()) is None
+    # the ring's two architectures are fed one position a step
+    assert serving.MellumArch(test_mellum_arch.TINY).build_prefill_pass() \
+        is None
+    assert serving.LagunaArch(test_laguna_arch.TINY).build_prefill_pass() \
+        is None
     assert (decode.PACKED_ROWS, decode.PASS_PAYS, decode.PREFILL_CHUNK,
             decode.MIN_PREFILL) == (256, 2, 128, 8)
 
@@ -463,10 +571,11 @@ SAMPLED = dict(temperature=0.9, top_k=12, top_p=0.9, seed=42)
 
 
 @contextlib.contextmanager
-def _loop(dtype="float32", min_prefill=decode.MIN_PREFILL, **kw):
+def _loop(dtype="float32", min_prefill=decode.MIN_PREFILL, model="kimi",
+          **kw):
     """The tiny loop with passes of 24 rows, closed on the way out;
     ``min_prefill=NEVER`` feeds it one position a step."""
-    arch, params = _model(dtype)
+    arch, params = _model(dtype, model)
     kw.setdefault("prefix_cache", False)
     kw.setdefault("spec_k", 0)
     if dtype == "bfloat16":
@@ -494,14 +603,16 @@ def _all_at_once(loop, requests, **kw):
 
 
 @functools.lru_cache(maxsize=None)
-def _served(dtype, min_prefill, sampled=False):
-    """``REQUESTS`` to their end under the tracer: ``(tokens, decode_step
-    events in order, health, device counters)``."""
+def _served(variant, min_prefill, sampled=False):
+    """``REQUESTS`` to their end under the tracer, ``variant`` one of
+    :data:`VARIANTS`: ``(tokens, decode_step events in order, health,
+    device counters)``."""
+    model, dtype = _variant(variant)
     obs_trace.stop()
     obs_trace.clear()
     obs_trace.start()
     try:
-        with _loop(dtype, min_prefill) as loop:
+        with _loop(dtype, min_prefill, model) as loop:
             futs = _all_at_once(loop, REQUESTS,
                                 **(SAMPLED if sampled else {}))
             outs = [f.result(timeout=120) for f in futs]
@@ -517,10 +628,10 @@ def _served(dtype, min_prefill, sampled=False):
 
 @pytest.mark.parametrize("sampled", [False, True],
                          ids=["greedy", "sampled"])
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_a_packing_loop_emits_one_token_feedings_tokens(dtype, sampled):
-    packed = _served(dtype, decode.MIN_PREFILL, sampled)
-    plain = _served(dtype, NEVER, sampled)
+@pytest.mark.parametrize("variant", TOKEN_EXACT)
+def test_a_packing_loop_emits_one_token_feedings_tokens(variant, sampled):
+    packed = _served(variant, decode.MIN_PREFILL, sampled)
+    plain = _served(variant, NEVER, sampled)
     assert packed[0] == plain[0]
     assert [len(o) for o in packed[0]] == [n for _, n in REQUESTS]
     assert len({tuple(o) for o in packed[0]}) == len(REQUESTS)
@@ -529,18 +640,41 @@ def test_a_packing_loop_emits_one_token_feedings_tokens(dtype, sampled):
 
 
 def test_greedy_tokens_are_the_references_full_forward():
-    ref = test_lfm2_arch._load("kimi-k2-ep32")
-    params = ref.make_params(dict(TINY, dtype="float32"), 7)
-    for (prompt, _), out in zip(REQUESTS, _served("float32",
-                                                  decode.MIN_PREFILL)[0]):
+    for variant, make, cfg in (
+            ("float32", "kimi-k2-ep32", TINY),
+            ("lfm2_float32", "lfm2-24b-a2b-ep8", test_lfm2_arch.TINY)):
+        ref = test_lfm2_arch._load(make)
+        params = ref.make_params(dict(cfg, dtype="float32"), 7)
+        for (prompt, _), out in zip(REQUESTS, _served(
+                variant, decode.MIN_PREFILL)[0]):
+            seq = jnp.asarray((prompt + out)[:-1], jnp.int32)
+            logits = np.asarray(ref.forward(params, seq, cfg, "float32"))
+            assert out == logits[len(prompt) - 1:].argmax(-1).tolist()
+
+
+def test_a_packing_lfm2_loop_in_bfloat16_serves_within_rounding():
+    """Every served token's logit lies within bfloat16 rounding of the
+    float32 reference's best (``tests/test_lfm2_arch.py``'s measure: the
+    one-token loop reads 0 here), and the passes did carry prompts."""
+    ref = test_lfm2_arch._load("lfm2-24b-a2b-ep8")
+    cfg = dict(test_lfm2_arch.TINY, dtype="bfloat16")
+    params = ref.make_params(cfg, 7)
+    outs, _, health, _, _ = _served("lfm2_bfloat16", decode.MIN_PREFILL)
+    assert health["prefill_passes"] > 0
+    gaps = []
+    for (prompt, new), out in zip(REQUESTS, outs):
+        assert len(out) == new
         seq = jnp.asarray((prompt + out)[:-1], jnp.int32)
-        logits = np.asarray(ref.forward(params, seq, TINY, "float32"))
-        assert out == logits[len(prompt) - 1:].argmax(-1).tolist()
+        logits = np.asarray(ref.forward(params, seq, cfg,
+                                        "float32"))[len(prompt) - 1:]
+        gaps.append(logits.max(-1) - logits[np.arange(new), out])
+    gap = np.concatenate(gaps)
+    assert gap.max() < 0.15 and np.mean(gap * gap) < 1e-3
 
 
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_the_counters_equal_the_spans_sums(dtype):
-    outs, steps, h, _, rids = _served(dtype, decode.MIN_PREFILL)
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_the_counters_equal_the_spans_sums(variant):
+    outs, steps, h, _, rids = _served(variant, decode.MIN_PREFILL)
     passes = [st["args"]["prefill"] for st in steps
               if "prefill" in st["args"]]
     assert h["prefill_passes"] == len(passes)
@@ -581,16 +715,18 @@ def test_the_routing_counters_by_either_feeding():
     """Equal in every expert layer the pass runs; the last expert layer
     lacks the pass's positions, ``top_k`` pairs each (its experts do not
     run for them: nothing reads the last layer's output of a prompt
-    position)."""
-    packed = _served("float32", decode.MIN_PREFILL)
-    plain = _served("float32", NEVER)
-    top_k = TINY["num_experts_per_tok"]
-    a, b = packed[3], plain[3]
-    np.testing.assert_array_equal(a["moe_served"][:-1], b["moe_served"][:-1])
-    assert a["moe_routed"][:-1].tolist() == b["moe_routed"][:-1].tolist()
-    assert int(b["moe_routed"][-1]) - int(a["moe_routed"][-1]) \
-        == top_k * packed[2]["prefill_positions"] > 0
-    assert (a["moe_served"][-1] <= b["moe_served"][-1]).all()
+    position). Both architectures."""
+    for variant in ("float32", "lfm2_float32"):
+        packed = _served(variant, decode.MIN_PREFILL)
+        plain = _served(variant, NEVER)
+        top_k = _model("float32", _variant(variant)[0])[0].share.top_k
+        a, b = packed[3], plain[3]
+        np.testing.assert_array_equal(a["moe_served"][:-1],
+                                      b["moe_served"][:-1])
+        assert a["moe_routed"][:-1].tolist() == b["moe_routed"][:-1].tolist()
+        assert int(b["moe_routed"][-1]) - int(a["moe_routed"][-1]) \
+            == top_k * packed[2]["prefill_positions"] > 0
+        assert (a["moe_served"][-1] <= b["moe_served"][-1]).all()
 
 
 def test_eos_ends_a_packed_request_where_it_ended_before():

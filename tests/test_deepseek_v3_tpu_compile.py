@@ -44,6 +44,10 @@ slot and position, the dense layer and one expert layer): each row's
 slot's rows reach it by a gather of ``rows x rung x 640`` a branch, the
 latent cache is written in place and never copied, no float32 copy of a
 weight is made, and the temporaries stay far under the chip's spare room.
+LFM2's packed prefill program at the same six layers: K, V and
+the conv state written in place and never copied, the rows gathered a
+branch, the last layer stopped at its state, and the step's count of
+device instructions under its one fetch ahead in flight.
 
 The Laguna step at published widths, its first five layers (the dense
 full layer, three window layers over a RING of 512 rows, a full expert
@@ -523,6 +527,67 @@ def test_kimi_packed_prefill_gathers_rows_and_moves_no_cache(kimi_prefill):
                  "layer1_attn_out_weight", "layer1_router_weight"):
         assert "params__%s__" % name not in entry, name
     assert "params__layer1_attn_kv_a_weight__" in entry
+
+
+@pytest.fixture(scope="module")
+def lfm2_prefill(one_chip):
+    """LFM2's PACKED prefill program at the six layers of ``LFM2_DEPTH6``,
+    compiled with the architecture's options as the loop compiles it."""
+    arch = lfm2.Lfm2Arch(LFM2_DEPTH6)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {k: s(v, jnp.bfloat16) for k, v in arch.param_shapes().items()}
+    state = {k: s((a.layers, SLOTS, a.depth(ROWS), a.width), a.dtype)
+             for k, a in arch.slot_state(None, "bf16").items()}
+    state.update(seed=s((SLOTS,), np.uint32), tok=s((SLOTS,), np.int32))
+    state.update({k: s(v, np.int32) for k, v in arch.counters().items()})
+    rows = s((decode.PACKED_ROWS,), np.int32)
+    fn = jax.jit(decode._build_prefill_fn(arch), donate_argnums=(0,))
+    return fn.lower(state, params, rows, rows, rows, s((), np.int32)).compile(
+        compiler_options=arch.compiler_options("tpu"))
+
+
+def test_lfm2_packed_prefill_gathers_rows_and_moves_no_state(lfm2_prefill):
+    """LFM2's packed prefill program at six layers: 256 rows over 64
+    slots, K and V 1024 rows deep, the two-row conv state, bfloat16. K, V
+    and the conv state are donated and written in place, never copied;
+    each row's slot's K and V rows reach it by a gather of ``256 x rung x
+    512`` a branch; the last layer (a conv layer) stops at its state: its
+    output projection, its experts and the head's norm are no operands;
+    with one fetch ahead in flight it shows the step's count of device
+    instructions (a traced run's cost, §7 j)."""
+    from mxnet_tpu import flopcheck as fc
+    compiled = lfm2_prefill
+    R = decode.PACKED_ROWS
+    cache = SLOTS * ROWS * 512 * 2                    # one layer's K, bf16
+    top = _top_level(compiled)
+    # (the held experts' float32 products, 8 x 256 rows x 2048, are copied
+    # whole: 16 MB, as Kimi's are)
+    assert [i for i in top if i[3] in ("copy", "transpose")
+            and i[1] == "bf16" and i[2] >= cache // 4] == []
+    assert _state_copies(compiled, (5, SLOTS, 2, 2048)) == []
+    gathered = [[int(d) for d in m.group(1).split(",")] for m in re.finditer(
+        r" = bf16\[(\d+,\d+,\d+)\]\S* gather\(", compiled.as_text())]
+    assert gathered and all(g[0] == R and g[1] <= ROWS and g[2] == 512
+                            for g in gathered), gathered
+    # K and V, each at every rung (the compiler may cut one in pieces)
+    assert sum(g[1] for g in gathered) == 2 * sum(blocks.rows_ladder(ROWS))
+    big = [i for i in top if i[1] == "f32" and i[2] >= 4 * 1536 * 2048 * 4]
+    assert [i for i in big if "copy" in i[3] or "convert" in i[3]] == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cache + 5 * SLOTS * 2 * 2048 * 2
+    assert mem.temp_size_in_bytes < 0.5e9
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    for name in ("final_norm_gamma", "layer5_conv_out_weight",
+                 "layer5_router_weight", "layer5_experts_down_weight"):
+        assert "params__%s__" % name not in entry, name
+    assert "params__layer5_conv_in_weight__" in entry
+    comps, top_entry = fc._parse_computations(text)
+    assert 250 < _device_visible(comps, top_entry) <= 320
+    assert 0 < _fetches_ahead(compiled) <= 40
 
 
 #: what a traced run shows of a step: every instruction outside the fused

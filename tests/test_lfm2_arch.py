@@ -559,6 +559,43 @@ def test_the_short_convolution_step_is_the_causal_convolution():
         prev = np.concatenate([u[pos][None, None], prev[:, :k - 2]], axis=1)
 
 
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_the_segment_convolution_is_the_steps_bit_for_bit(dtype):
+    """A packed pass's convolution (``segment_conv``) over segments of 1,
+    2, 3 and 6 positions of four slots, each over a state its slot's
+    predecessor left, some from position 0 and some past it, against
+    ``short_conv`` stepped over the same ``u`` with the state shifted as
+    the step shifts it: the same values bit for bit (the reaching taps
+    read ``u`` as the state stores it), and each segment's last row's
+    window is the state the step leaves."""
+    rng = np.random.default_rng(4)
+    e, k = 5, 3
+    w = jnp.asarray(rng.standard_normal((e, k)).astype(np.float32))
+    segments = [(2, 0, 3), (0, 4, 1), (3, 7, 6), (1, 0, 2)]   # slot, pos0, n
+    state = rng.standard_normal((4, k - 1, e)).astype(np.float32)
+    state = jnp.asarray(state).astype(dtype)
+    rows = sum(n for _, _, n in segments)
+    u = jnp.asarray(rng.standard_normal((rows, e)).astype(np.float32))
+    slot = np.concatenate([[s] * n for s, _, n in segments]).astype(np.int32)
+    pos = np.concatenate([np.arange(p, p + n)
+                          for _, p, n in segments]).astype(np.int32)
+    got, window = lfm2.segment_conv(u, state, w, jnp.asarray(slot),
+                                    jnp.asarray(pos))
+    at = 0
+    for s, p0, n in segments:
+        prev = state[s][None]
+        for r in range(at, at + n):
+            want = lfm2.short_conv(u[r][None], prev, w, jnp.asarray([pos[r]]))
+            np.testing.assert_array_equal(np.asarray(got[r]),
+                                          np.asarray(want[0]))
+            prev = jnp.concatenate([u[r][None, None].astype(dtype),
+                                    prev[:, :k - 2]], axis=1)
+        np.testing.assert_array_equal(
+            np.asarray(window[at + n - 1].astype(dtype).astype(np.float32)),
+            np.asarray(prev[0].astype(np.float32)))
+        at += n
+
+
 def test_the_references_dense_half_is_transformers_lfm2(ref):
     """The published code for the conv operator, the attention and the
     layer (``modeling_lfm2.py``; it has no expert layer): every layer
