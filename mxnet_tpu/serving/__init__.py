@@ -18,7 +18,9 @@ Three layers over the standalone :class:`~mxnet_tpu.predictor.Predictor`:
   layers and a two-row conv state over the others, :class:`MellumArch` a
   ring of K and V rows over its sliding-window layers beside K and V rows a
   position over its full ones, :class:`LagunaArch` the same state under
-  gated heads of two counts, partial rotary positions and a shared expert)
+  gated heads of two counts, partial rotary positions and a shared expert,
+  :class:`GraniteHybridArch` K and V rows over its attention layers beside
+  a conv state and a float32 matrix state a head over its Mamba-2 layers)
   is donated device state
   stepped by one compiled decode
   body; sequences join and leave mid-stream. The
@@ -55,6 +57,7 @@ from .deepseek_v3 import DeepseekV3Arch
 from .lfm2 import Lfm2Arch
 from .mellum import MellumArch
 from .laguna import LagunaArch
+from .granite_hybrid import GraniteHybridArch
 from .fleet import FleetRouter, FleetRequest, CLASSES as FLEET_CLASSES
 from .quantize import (QUANT_MODES, check_quality, quality_report,
                        quantize_tree, tree_bytes)
@@ -62,7 +65,7 @@ from .quantize import (QUANT_MODES, check_quality, quality_report,
 __all__ = [
     "ServingEngine", "Batcher", "DecodeLoop", "GenerateFuture",
     "Architecture", "OptArch", "DeepseekV3Arch", "Lfm2Arch", "MellumArch",
-    "LagunaArch",
+    "LagunaArch", "GraniteHybridArch",
     "FleetRouter", "FleetRequest", "FLEET_CLASSES",
     "ServingHealth", "SERVING_HEALTH", "default_buckets",
     "ServingError", "ServingDeadlineError", "ServingOverloadedError",

@@ -1333,6 +1333,11 @@ class DecodeLoop(object):
     def _shed(self, exc):
         shed = 0
         outcome = "failed" if self.dead is None else "shed"
+        rec, self._inflight = self._inflight, None
+        rows = rec[1] if rec is not None else ()
+        # the step in flight is never read back: count its trash slot-steps
+        # (their request's eos was read a step ago) as its commit would have
+        trash = sum(1 for _, slot, _, _ in rows if slot.fut.done())
         for i, slot in enumerate(self._slots):
             if slot is not None:
                 self._request_done(slot.fut, outcome, i, slot)
@@ -1341,8 +1346,7 @@ class DecodeLoop(object):
                 shed += 1
         # and the requests whose last step is in flight: they left their
         # slots when it was dispatched, and their tokens will not be read
-        rec, self._inflight = self._inflight, None
-        for i, slot, _, last in rec[1] if rec is not None else ():
+        for i, slot, _, last in rows:
             if last and not slot.fut.done():
                 self._request_done(slot.fut, outcome, i, slot)
                 shed += slot.fut.fail(exc)
@@ -1354,6 +1358,8 @@ class DecodeLoop(object):
                 shed += 1
             except queue.Empty:
                 break
+        if trash:
+            self.health.record_tokens(0, trash)
         if shed:
             self.health.record_shed(shed, exc)
 

@@ -56,6 +56,14 @@ held beside a shared expert; 64 slots), compiled as the compiler leaves
 it: both K/V arrays pass through without a copy, each kind attends by its
 own ladder, and the step stays under its count of device instructions.
 
+The Granite 4.0-H step at published widths, its first period (nine
+Mamba-2 layers and one attention layer), 64 slots, 1024 rows: the float32
+matrix state (2 MiB a slot and layer), the conv state and K and V pass
+through without an array-sized copy and are written in place; the program
+fits the chip at full depth; and the step's count of device instructions
+is held, with what the compiler makes of the state's update and read-out
+(two passes over the state a layer, PERF.md).
+
 The topology is described inside a fixture (only one process may load the
 TPU's library; a worker that cannot skips), and this is the one file that
 does so."""
@@ -70,8 +78,8 @@ import jax
 import jax.numpy as jnp
 
 import chip_smoke
-from mxnet_tpu.serving import (blocks, decode, deepseek_v3, laguna, lfm2,
-                               mellum)
+from mxnet_tpu.serving import (blocks, decode, deepseek_v3, granite_hybrid,
+                               laguna, lfm2, mellum)
 
 SLOTS, ROWS = 64, 1024
 ITEM = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
@@ -777,3 +785,126 @@ def test_laguna_step_stays_under_its_count_of_device_instructions(
     _, compiled = laguna_step
     comps, entry = fc._parse_computations(compiled.as_text())
     assert 580 < _device_visible(comps, entry) <= 700
+
+
+# ---------------------------------------------------------------------------
+# a float32 matrix state a head beside K/V rows (the Granite 4.0-H block)
+# ---------------------------------------------------------------------------
+
+def _granite_config(layers=None):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        cfg = json.load(f)
+    if layers is not None:
+        cfg = dict(cfg, num_hidden_layers=layers,
+                   layer_types=cfg["layer_types"][:layers])
+    return cfg
+
+
+def _granite_compile(one_chip, layers):
+    arch = granite_hybrid.GraniteHybridArch(_granite_config(layers))
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {k: s(v, jnp.bfloat16) for k, v in arch.param_shapes().items()}
+    state = {k: s((a.layers, SLOTS, a.depth(ROWS), a.width), a.dtype)
+             for k, a in arch.slot_state(None, "bf16").items()}
+    state.update(seed=s((SLOTS,), np.uint32), tok=s((SLOTS,), np.int32))
+    feed = [s((SLOTS,), d) for d in (np.int32, np.int32, np.float32,
+                                     np.int32, np.float32, np.uint32,
+                                     np.bool_)]
+    fn = jax.jit(decode._build_decode_fn(arch), donate_argnums=(0,))
+    return state, fn.lower(state, params, *feed).compile(
+        compiler_options=arch.compiler_options("tpu") or None)
+
+
+@pytest.fixture(scope="module")
+def granite_step(one_chip):
+    return _granite_compile(one_chip, 10)
+
+
+def test_granite_every_kind_of_state_passes_through_in_place(granite_step):
+    state, compiled = granite_step
+    assert state["ssm"].shape == (9, SLOTS, 128, 4096)
+    assert state["ssm"].dtype == np.float32
+    assert state["conv"].shape == (9, SLOTS, 3, 4352)
+    assert state["k"].shape == (1, SLOTS, ROWS, 512)
+    layer = SLOTS * 4096 * 128 * 4                    # 128 MiB of state
+    # no copy of a layer's conv state or K (bf16), none of a quarter of a
+    # layer's matrix state (the sampler's f32 copies over slots x
+    # vocabulary, 25.7 MB, stand in its conditional's branch)
+    assert [i for i in _top_level(compiled) if i[3] in ("copy", "transpose")
+            and ((i[1] == "bf16" and i[2] >= SLOTS * 3 * 4352 * 2)
+                 or (i[1] == "f32" and i[2] >= layer // 4))] == []
+    # the state written in place: one update of a layer's 128 MiB into the
+    # donated array, never a copy of the array's 1.2 GB
+    writes = [i for i in _top_level(compiled)
+              if i[1] == "f32" and i[2] == 9 * layer]
+    assert writes and all(i[3] in ("fusion", "parameter",
+                                   "get-tuple-element", "bitcast")
+                          for i in writes)
+    mem = compiled.memory_analysis()
+    donated = sum(int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize
+                  for k, v in state.items() if k in ("ssm", "conv", "k", "v"))
+    assert mem.alias_size_in_bytes >= donated
+    assert mem.temp_size_in_bytes < 0.1e9
+
+
+def test_granite_fits_the_chip_at_full_depth(granite_step):
+    """The period compiled here holds 3.26 GB of arguments; the 40 layers
+    hold 11.81 GB (weights 6.38, the matrix state 4.83, K and V 0.54, the
+    conv state 0.06), which with the period's temporaries fits the chip's
+    15.75 GB (a compile of all 40 layers reads 11.81 GB of arguments and
+    0.09 GB of temporaries for a described v5e, PERF.md)."""
+    _, compiled = granite_step
+    arch = granite_hybrid.GraniteHybridArch(_granite_config())
+    weights = 2 * sum(int(np.prod(v)) for v in arch.param_shapes().values())
+    state = sum(a.layers * SLOTS * a.depth(ROWS) * a.width
+                * np.dtype(a.dtype).itemsize
+                for a in arch.slot_state(None, "bf16").values())
+    assert abs(weights + state - 11.81e9) < 0.01e9
+    mem = compiled.memory_analysis()
+    assert abs(mem.argument_size_in_bytes - 3.261e9) < 0.01e9
+    assert weights + state + 4 * mem.temp_size_in_bytes < 15.5e9
+
+
+def test_granite_step_stays_under_its_count_of_device_instructions(
+        granite_step):
+    """A traced run's cost is gaps x spans (PERF.md §7 j). As the compiler
+    leaves the step, one period of ten layers shows 963 device-visible
+    instructions, most of them the compiler's fetches of weight slices into
+    fast memory ahead of their products, and the 40 layers 2,855; the
+    state's update and its read-out are two fusions a layer that each read
+    the layer's state (the read-out recomputes the update)."""
+    from mxnet_tpu import flopcheck as fc
+    _, compiled = granite_step
+    comps, entry = fc._parse_computations(compiled.as_text())
+    assert 900 < _device_visible(comps, entry) <= 1000
+    # the state's update: one in-place fusion a mamba layer whose result
+    # is the whole donated array
+    updates = [i for i in _top_level(compiled) if i[3] == "fusion"
+               and i[1] == "f32" and i[2] == 9 * SLOTS * 4096 * 128 * 4]
+    assert len(updates) == 9
+
+
+def test_granite_full_step_rematerialises_nothing_and_updates_in_place(
+        one_chip):
+    """At 40 layers and 64 slots, left to itself, the compiler
+    rematerialises the first layer's in-place update of the matrix state:
+    a second copy of it reads the donated array after the first has
+    overwritten it, so that layer's state advances twice a step (every
+    served token wrong on the chip, PERF.md). Under the block's compiler
+    options nothing is rematerialised: each of the 36 layers' updates is
+    one fusion into the donated array, and the step fits."""
+    state, compiled = _granite_compile(one_chip, None)
+    assert state["ssm"].shape == (36, SLOTS, 128, 4096)
+    assert ".remat" not in compiled.as_text()
+    updates = [i for i in _top_level(compiled) if i[3] == "fusion"
+               and i[1] == "f32" and i[2] == 36 * SLOTS * 4096 * 128 * 4]
+    assert len(updates) == 36
+    mem = compiled.memory_analysis()
+    assert abs(mem.argument_size_in_bytes - 11.81e9) < 0.01e9
+    assert mem.alias_size_in_bytes >= 5.42e9
+    assert mem.temp_size_in_bytes < 0.1e9
