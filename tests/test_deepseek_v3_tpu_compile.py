@@ -60,9 +60,11 @@ The Granite 4.0-H step at published widths, its first period (nine
 Mamba-2 layers and one attention layer), 64 slots, 1024 rows: the float32
 matrix state (2 MiB a slot and layer), the conv state and K and V pass
 through without an array-sized copy and are written in place; the program
-fits the chip at full depth; and the step's count of device instructions
-is held, with what the compiler makes of the state's update and read-out
-(two passes over the state a layer, PERF.md).
+fits the chip at full depth; the state is stepped and read out by ONE
+Pallas kernel a layer (``ops/pallas_ssm.py``), aliased into the donated
+array and scoped ``layer/ssm``, and nothing else reads it (as XLA
+compiles the jnp form, the read-out is a second pass over the state,
+PERF.md); and the step's count of device instructions is held.
 
 The topology is described inside a fixture (only one process may load the
 TPU's library; a worker that cannot skips), and this is the one file that
@@ -825,6 +827,50 @@ def granite_step(one_chip):
     return _granite_compile(one_chip, 10)
 
 
+def _scope(op_path):
+    """The scope path a device trace's reader gets for an instruction: its
+    metadata's ``op_name`` less the jitted functions and the op itself
+    (``DecodeLoop.program_scopes``)."""
+    return "/".join(c for c in (op_path or "").split("/")[:-1]
+                    if c and "(" not in c)
+
+
+def _state_readers(compiled, layers):
+    """``(opcode, scope, aliasing)`` of every top-level instruction that
+    takes the float32 matrix state ``(layers, SLOTS, 128, 4096)`` as an
+    operand, its views (tuple elements, bitcasts) and the result tuple seen
+    through."""
+    from mxnet_tpu import flopcheck as fc
+    comps, entry = fc._parse_computations(compiled.as_text())
+    shape = "f32[%d,%d,128,4096]" % (layers, SLOTS)
+    arrays = {i["instr"] for i in comps[entry] if i["type"].startswith(shape)}
+    out = []
+    for i in comps[entry]:
+        operands = set(re.findall(r"%([\w.\-]+)", i["rest"].split(")")[0]))
+        if operands & arrays and i["opcode"] not in (
+                "get-tuple-element", "bitcast", "tuple"):
+            alias = re.search(r"output_to_operand_aliasing=\{\{0\}: \((\d+)",
+                              i["rest"])
+            out.append((i["opcode"], _scope(i["op_path"]),
+                        alias and int(alias.group(1))))
+    return out
+
+
+def _assert_one_kernel_a_layer(compiled, layers):
+    """Each mamba layer's state is stepped and read out by one Pallas call
+    that writes its result into the state operand (the sixth, after the
+    layer index and the four row operands), scoped ``layer/ssm``; no other
+    instruction reads the state (as XLA compiled the jnp form, a fusion a
+    layer read it a second time for the read-out)."""
+    readers = _state_readers(compiled, layers)
+    assert len(readers) == layers, readers
+    for opcode, scope, alias in readers:
+        assert (opcode, alias) == ("custom-call", 5), readers
+        assert scope.startswith("layer/ssm"), readers
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == layers
+
+
 def test_granite_every_kind_of_state_passes_through_in_place(granite_step):
     state, compiled = granite_step
     assert state["ssm"].shape == (9, SLOTS, 128, 4096)
@@ -838,13 +884,14 @@ def test_granite_every_kind_of_state_passes_through_in_place(granite_step):
     assert [i for i in _top_level(compiled) if i[3] in ("copy", "transpose")
             and ((i[1] == "bf16" and i[2] >= SLOTS * 3 * 4352 * 2)
                  or (i[1] == "f32" and i[2] >= layer // 4))] == []
-    # the state written in place: one update of a layer's 128 MiB into the
-    # donated array, never a copy of the array's 1.2 GB
+    # the state written in place: a kernel a layer steps its 128 MiB in
+    # the donated array, never a copy of the array's 1.2 GB
     writes = [i for i in _top_level(compiled)
               if i[1] == "f32" and i[2] == 9 * layer]
-    assert writes and all(i[3] in ("fusion", "parameter",
-                                   "get-tuple-element", "bitcast")
+    assert writes and all(i[3] in ("parameter", "get-tuple-element",
+                                   "bitcast")
                           for i in writes)
+    _assert_one_kernel_a_layer(compiled, 9)
     mem = compiled.memory_analysis()
     donated = sum(int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize
                   for k, v in state.items() if k in ("ssm", "conv", "k", "v"))
@@ -857,7 +904,7 @@ def test_granite_fits_the_chip_at_full_depth(granite_step):
     hold 11.81 GB (weights 6.38, the matrix state 4.83, K and V 0.54, the
     conv state 0.06), which with the period's temporaries fits the chip's
     15.75 GB (a compile of all 40 layers reads 11.81 GB of arguments and
-    0.09 GB of temporaries for a described v5e, PERF.md)."""
+    0.07 GB of temporaries for a described v5e, PERF.md)."""
     _, compiled = granite_step
     arch = granite_hybrid.GraniteHybridArch(_granite_config())
     weights = 2 * sum(int(np.prod(v)) for v in arch.param_shapes().values())
@@ -873,20 +920,17 @@ def test_granite_fits_the_chip_at_full_depth(granite_step):
 def test_granite_step_stays_under_its_count_of_device_instructions(
         granite_step):
     """A traced run's cost is gaps x spans (PERF.md §7 j). As the compiler
-    leaves the step, one period of ten layers shows 963 device-visible
+    leaves the step, one period of ten layers shows 785 device-visible
     instructions, most of them the compiler's fetches of weight slices into
-    fast memory ahead of their products, and the 40 layers 2,855; the
-    state's update and its read-out are two fusions a layer that each read
-    the layer's state (the read-out recomputes the update)."""
+    fast memory ahead of their products, and the 40 layers 2,357; the
+    state's step and read-out are one kernel a layer. (As XLA compiled the
+    jnp form they were two fusions a layer that each read the layer's
+    state: 963 and 2,855.)"""
     from mxnet_tpu import flopcheck as fc
     _, compiled = granite_step
     comps, entry = fc._parse_computations(compiled.as_text())
-    assert 900 < _device_visible(comps, entry) <= 1000
-    # the state's update: one in-place fusion a mamba layer whose result
-    # is the whole donated array
-    updates = [i for i in _top_level(compiled) if i[3] == "fusion"
-               and i[1] == "f32" and i[2] == 9 * SLOTS * 4096 * 128 * 4]
-    assert len(updates) == 9
+    assert 740 < _device_visible(comps, entry) <= 820
+    _assert_one_kernel_a_layer(compiled, 9)
 
 
 def test_granite_full_step_rematerialises_nothing_and_updates_in_place(
@@ -896,14 +940,13 @@ def test_granite_full_step_rematerialises_nothing_and_updates_in_place(
     a second copy of it reads the donated array after the first has
     overwritten it, so that layer's state advances twice a step (every
     served token wrong on the chip, PERF.md). Under the block's compiler
-    options nothing is rematerialised: each of the 36 layers' updates is
-    one fusion into the donated array, and the step fits."""
+    options nothing is rematerialised: each of the 36 layers' steps is one
+    kernel into the donated array, nothing else reads the state, and the
+    step fits."""
     state, compiled = _granite_compile(one_chip, None)
     assert state["ssm"].shape == (36, SLOTS, 128, 4096)
     assert ".remat" not in compiled.as_text()
-    updates = [i for i in _top_level(compiled) if i[3] == "fusion"
-               and i[1] == "f32" and i[2] == 36 * SLOTS * 4096 * 128 * 4]
-    assert len(updates) == 36
+    _assert_one_kernel_a_layer(compiled, 36)
     mem = compiled.memory_analysis()
     assert abs(mem.argument_size_in_bytes - 11.81e9) < 0.01e9
     assert mem.alias_size_in_bytes >= 5.42e9
