@@ -48,6 +48,15 @@ recurrence with the state's write, the gated norm), ``cache_write/ssm``
 (the conv state's shift), ``layer/attn`` and ``cache_write/kv``,
 ``layer/mlp``.
 
+**Prompts by a packed prefill pass** (:attr:`packed_prefill`,
+:meth:`~GraniteHybridArch.build_prefill_pass`): rows of several slots behind
+one read of the weights. A mamba layer carries each slot's conv state over
+its rows with :func:`.lfm2.segment_conv` and its matrix state in the chunked
+(SSD) form (:func:`segment_ssd`), the state written once a slot; on the chip
+one Pallas kernel a layer reads and writes only the carried slots' state
+(``ops/pallas_ssd.py::ssm_segments``), everywhere else its jnp form
+(``_segments_step``) runs.
+
 **A reused slot starts from a zero state with no reset program**: the
 decay is multiplied by ``pos > 0`` and the conv taps that reach back ``j``
 by ``pos - j >= 0``, so a request's first position sees none of what its
@@ -73,7 +82,7 @@ from ..base import MXNetError
 from .arch import PER_POSITION, Architecture, SlotArray
 from .blocks import (gqa_attention, linear, over_filled_rows, rms_norm,
                      swiglu, validate_share)
-from .lfm2 import short_conv
+from .lfm2 import segment_conv, short_conv
 
 _KEYS = ("hidden_size", "num_attention_heads", "num_key_value_heads",
          "num_hidden_layers", "vocab_size", "shared_intermediate_size",
@@ -82,12 +91,17 @@ _KEYS = ("hidden_size", "num_attention_heads", "num_key_value_heads",
 _SCALES = ("embedding_multiplier", "residual_multiplier",
            "attention_multiplier", "logits_scaling", "rms_norm_eps")
 LAYER_TYPES = ("mamba", "attention")
+#: a mamba layer's parameters up to its matrix state: all the LAST layer
+#: of a prefill pass reads
+MIXER_IN = ("op_norm_gamma", "ssm_in_weight", "ssm_conv_weight",
+            "ssm_conv_bias", "ssm_dt_bias", "ssm_A_log")
 
 
 class GraniteHybridArch(Architecture):
     """The Granite 4.0-H block from its ``config.json`` keys."""
 
     name = "granite_hybrid"
+    packed_prefill = True
 
     def __init__(self, config):
         missing = [k for k in _KEYS + _SCALES + ("layer_types",)
@@ -231,6 +245,58 @@ class GraniteHybridArch(Architecture):
     def load(self, params):
         return params      # as stored: no float32 copy (int8 was refused)
 
+    # -- what the token pass and the prefill pass are both made of -------------
+    def _layer_parts(self):
+        """``(project, activate, gate_out, qkv, attn_out, mlp)``: a layer's
+        mathematics over any number of rows, one position each, ``p(name)``
+        the layer's parameter. The token pass runs them over a row a slot,
+        the prefill pass over the rows of several slots' prompts: ONE text
+        for both, so they cannot drift."""
+        import jax
+        import jax.numpy as jnp
+        f32 = jnp.float32
+        eps, res = self.rms_norm_eps, f32(self.residual_multiplier)
+        hd, inner = self.mamba_d_head, self.inner
+
+        def project(x, p):
+            """The mixer's ``(z, xBC, dt)`` before the convolution."""
+            a = rms_norm(x, p("op_norm_gamma"), eps)
+            return jnp.split(linear(a, p("ssm_in_weight")),
+                             [inner, inner + self.conv_width], axis=-1)
+
+        def activate(mix, dt, p):
+            """``(x, B, C, dt)`` from the convolution and the raw dt."""
+            xs, b, c = jnp.split(
+                jax.nn.silu(mix + p("ssm_conv_bias").astype(f32)),
+                [inner, inner + self.mamba_d_state], axis=-1)
+            return xs, b, c, jax.nn.softplus(dt + p("ssm_dt_bias").astype(f32))
+
+        def gate_out(x, y, xs, z, p):
+            """The mixer's ``D`` term, gated norm and out-projection into
+            the residual stream, from the state's read-out ``y``."""
+            y = y + xs * jnp.repeat(p("ssm_D").astype(f32), hd)[None]
+            g = rms_norm(y * jax.nn.silu(z), p("ssm_norm_gamma"), eps)
+            return x + res * linear(g, p("ssm_out_weight"))
+
+        def qkv(x, p):
+            a = rms_norm(x, p("op_norm_gamma"), eps)
+            q = linear(a, p("attn_q_weight")).reshape(
+                x.shape[0], self.num_heads, self.head_dim)
+            return q, linear(a, p("attn_k_weight")), \
+                linear(a, p("attn_v_weight"))
+
+        def attn_out(x, o, p):
+            return x + res * linear(o, p("attn_out_weight"))
+
+        def mlp(x, p):
+            with jax.named_scope("layer/mlp"):
+                f = rms_norm(x, p("ffn_norm_gamma"), eps)
+                return x + res * swiglu(f, p("ffn_gate_weight"),
+                                        p("ffn_up_weight"),
+                                        p("ffn_down_weight"))
+
+        return project, activate, gate_out, qkv, attn_out, mlp
+
     # -- one position per slot through every layer -----------------------------
     def build_token_pass(self, mesh=None):
         import jax
@@ -238,11 +304,12 @@ class GraniteHybridArch(Architecture):
         if mesh is not None:
             self.slot_partition()
         f32 = jnp.float32
-        eps, res = self.rms_norm_eps, f32(self.residual_multiplier)
+        eps = self.rms_norm_eps
         heads, hd, inner = self.mamba_n_heads, self.mamba_d_head, self.inner
         taps = self.mamba_d_conv - 1
         own = dict([(i, n) for n, i in enumerate(self.mamba_layers)]
                    + [(i, n) for n, i in enumerate(self.attn_layers)])
+        project, activate, gate_out, qkv, attn_out, mlp = self._layer_parts()
 
         def token_pass(state, params, tokens, pos):
             ck, cv = state.get("k"), state.get("v")
@@ -265,16 +332,10 @@ class GraniteHybridArch(Architecture):
                 n = own[i]
                 if kind == "mamba":
                     with jax.named_scope("layer/ssm"):
-                        a = rms_norm(x, p("op_norm_gamma"), eps)
-                        z, xbc, dt = jnp.split(
-                            linear(a, p("ssm_in_weight")),
-                            [inner, inner + self.conv_width], axis=-1)
-                        xs, b, c = jnp.split(jax.nn.silu(
+                        z, xbc, dt = project(x, p)
+                        xs, b, c, dt = activate(
                             short_conv(xbc, conv[n], p("ssm_conv_weight"),
-                                       pos)
-                            + p("ssm_conv_bias").astype(f32)),
-                            [inner, inner + self.mamba_d_state], axis=-1)
-                        dt = jax.nn.softplus(dt + p("ssm_dt_bias").astype(f32))
+                                       pos), dt, p)
                         # times pos > 0: a request's first position meets a
                         # zero state, whatever the slot held
                         decay = jnp.exp(
@@ -291,18 +352,10 @@ class GraniteHybridArch(Architecture):
                             [xbc[:, None].astype(conv.dtype),
                              conv[n][:, :taps - 1]], axis=1))
                     with jax.named_scope("layer/ssm"):
-                        y = y + xs \
-                            * jnp.repeat(p("ssm_D").astype(f32), hd)[None]
-                        g = rms_norm(y * jax.nn.silu(z), p("ssm_norm_gamma"),
-                                     eps)
-                        x = x + res * linear(g, p("ssm_out_weight"))
+                        x = gate_out(x, y, xs, z, p)
                 else:
                     with jax.named_scope("layer/attn"):
-                        a = rms_norm(x, p("op_norm_gamma"), eps)
-                        q = linear(a, p("attn_q_weight")).reshape(
-                            nslots, self.num_heads, self.head_dim)
-                        k = linear(a, p("attn_k_weight"))
-                        v = linear(a, p("attn_v_weight"))
+                        q, k, v = qkv(x, p)
                     with jax.named_scope("cache_write/kv"):
                         ck = ck.at[n, sidx, wpos].set(k.astype(ck.dtype))
                         cv = cv.at[n, sidx, wpos].set(v.astype(cv.dtype))
@@ -311,12 +364,8 @@ class GraniteHybridArch(Architecture):
                                  lambda mask, kr, vr: gqa_attention(
                                      q, kr, vr, mask,
                                      self.attention_multiplier))
-                        x = x + res * linear(o, p("attn_out_weight"))
-                with jax.named_scope("layer/mlp"):
-                    f = rms_norm(x, p("ffn_norm_gamma"), eps)
-                    x = x + res * swiglu(f, p("ffn_gate_weight"),
-                                         p("ffn_up_weight"),
-                                         p("ffn_down_weight"))
+                        x = attn_out(x, o, p)
+                x = mlp(x, p)
             with jax.named_scope("head"):
                 logits = linear(
                     rms_norm(x, params["final_norm_gamma"], eps),
@@ -328,6 +377,138 @@ class GraniteHybridArch(Architecture):
 
         return token_pass
 
+    # -- the prompts of several slots behind one read of the weights -----------
+    def build_prefill_pass(self, mesh=None):
+        """The packed pass (:attr:`packed_prefill`): the token pass's
+        mathematics over ``R`` rows, each a position of a slot it NAMES,
+        the rows of one slot consecutive and ascending (a SEGMENT).
+
+        A mamba layer runs the convolution over the rows with
+        :func:`.lfm2.segment_conv` (each segment reads its own earlier rows
+        and, before them, the slot's stored ones; the conv state is written
+        ONCE a slot, from the segment's last row) and the matrix state in
+        the chunked (SSD) form, :func:`segment_ssd`: each segment's rows
+        read the state their slot held before it (a zero state where the
+        segment starts a request) and the state at its last row is written
+        ONCE a segment, in place. On the chip one Pallas kernel a layer
+        reads and writes the carried slots' state and no other
+        (``ops/pallas_ssd.py::ssm_segments``); everywhere else the jnp
+        form of the same lines runs (``_segments_step``). An attention
+        layer scatters each live row's K and V to ``(layer, slot[r],
+        pos[r])``, then each row attends ITS slot's rows ``<= pos[r]``
+        through :func:`.blocks.over_filled_rows`. Padding rows and the rows
+        that end no segment write out of bounds and are DROPPED. The LAST
+        layer stops at its states: no head, no sampler, nothing to read
+        back."""
+        import jax
+        import jax.numpy as jnp
+        if mesh is not None:    # a sharded loop is fed one position a step
+            return None
+        f32 = jnp.float32
+        own = dict([(i, n) for n, i in enumerate(self.mamba_layers)]
+                   + [(i, n) for n, i in enumerate(self.attn_layers)])
+        project, activate, gate_out, qkv, out, mlp = self._layer_parts()
+
+        def weights(params, i, names=None):
+            pre = "layer%d_" % i
+            return {k[len(pre):]: v for k, v in params.items()
+                    if k.startswith(pre)
+                    and (names is None or k[len(pre):] in names)}
+
+        # a layer's parts are traced once a KIND of layer, its weights
+        # arguments (the compiler inlines the calls): the pass's tracing and
+        # lowering are set-up time
+        @jax.jit
+        def mixer_in(x, w, stored, slot, pos, same, causal, keep):
+            p = w.__getitem__
+            with jax.named_scope("layer/ssm"):
+                z, xbc, dt = project(x, p)
+                mix, window = segment_conv(xbc, stored, p("ssm_conv_weight"),
+                                           slot, pos)
+                xs, b, c, dt = activate(mix, dt, p)
+                y, e, u, d = segment_ssd(
+                    xs, b, c, dt, -dt * jnp.exp(p("ssm_A_log").astype(f32)),
+                    same, causal, keep, self.mamba_n_heads)
+            return z, xs, b, c, y, e, u, d, window
+
+        @jax.jit
+        def mixer_out(x, w, z, xs, y):
+            with jax.named_scope("layer/ssm"):
+                x = gate_out(x, y, xs, z, w.__getitem__)
+            return mlp(x, w.__getitem__)
+
+        @jax.jit
+        def attn_in(x, w):
+            with jax.named_scope("layer/attn"):
+                return qkv(x, w.__getitem__)
+
+        @jax.jit
+        def attn_rows(q, mask, kr, vr):
+            return gqa_attention(q, kr, vr, mask, self.attention_multiplier)
+
+        @jax.jit
+        def attn_out(x, o, w):
+            with jax.named_scope("layer/attn"):
+                x = out(x, o, w.__getitem__)
+            return mlp(x, w.__getitem__)
+
+        def prefill_pass(state, params, tokens, slot, pos, n):
+            ck, cv = state.get("k"), state.get("v")
+            conv, ssm = state.get("conv"), state.get("ssm")
+            nrows = tokens.shape[0]
+            at = jnp.arange(nrows, dtype=jnp.int32)
+            live = at < n
+            # a padding row reads slot 0 at position 0 and writes nowhere
+            # (each its own way out of bounds: the indices stay unique)
+            slot = jnp.where(live, slot, 0)
+            pos = jnp.where(live, pos, 0)
+            with jax.named_scope("embed"):
+                x = params["tok_embed_weight"][tokens].astype(f32) \
+                    * f32(self.embedding_multiplier)
+            if ck is not None:
+                rows = ck.shape[2]
+                wpos = jnp.where(live, pos, jnp.int32(rows) + at)
+                over = over_filled_rows(pos, rows, slot)
+            if ssm is not None:
+                same, causal, keep, wslot, sslot, lo, hi, nseg = \
+                    segment_table(slot, pos, live, n, ssm.shape[1])
+            for i, kind in enumerate(self.layer_types):
+                w, m = weights(params, i), own[i]
+                final = i + 1 == self.num_layers
+                if kind == "mamba":
+                    z, xs, b, c, y, e, u, d, window = mixer_in(
+                        x, weights(params, i, MIXER_IN), conv[m], slot, pos,
+                        same, causal, keep)
+                    with jax.named_scope("cache_write/ssm"):
+                        conv = conv.at[m, wslot].set(
+                            window.astype(conv.dtype), mode="drop",
+                            unique_indices=True)
+                    with jax.named_scope("layer/ssm"):
+                        ssm, y0 = jax.lax.platform_dependent(
+                            jnp.int32(m), ssm, sslot, lo, hi, nseg, d[lo], c,
+                            e, b, u, tpu=_segments_kernel,
+                            default=_segments_step)
+                    if final:
+                        break
+                    x = mixer_out(x, w, z, xs, y0 + y)
+                    continue
+                q, k, v = attn_in(x, w)
+                with jax.named_scope("cache_write/kv"):
+                    ck = ck.at[m, slot, wpos].set(
+                        k.astype(ck.dtype), mode="drop", unique_indices=True)
+                    cv = cv.at[m, slot, wpos].set(
+                        v.astype(cv.dtype), mode="drop", unique_indices=True)
+                if final:
+                    break
+                with jax.named_scope("layer/attn"):
+                    o = over((ck, cv), m, functools.partial(attn_rows, q))
+                x = attn_out(x, o, w)
+            return {name: arr for name, arr in (("k", ck), ("v", cv),
+                                               ("conv", conv), ("ssm", ssm))
+                    if arr is not None}
+
+        return prefill_pass
+
 
 def _state_step(n, ssm, decay, u, b, c):
     """Layer ``n``'s matrix state stepped by one position and read out:
@@ -335,6 +516,113 @@ def _state_step(n, ssm, decay, u, b, c):
     import jax.numpy as jnp
     s = decay[:, None, :] * ssm[n] + b[:, :, None] * u[:, None, :]
     return ssm.at[n].set(s), jnp.sum(s * c[:, :, None], axis=1)
+
+
+def segment_table(slot, pos, live, n, nslots):
+    """What every mamba layer of a packed pass is told of its SEGMENTS (a
+    slot's rows, consecutive and ascending, each slot once), from the rows'
+    ``slot``, ``pos`` and ``live`` (the first ``n``): the rows' relations
+    ``same`` (R, R) and ``causal`` (``same`` at or before the row), ``keep``
+    (the row's segment starts past position 0), the conv state's write slot
+    a row (out of bounds but at a segment's last row, of ``nslots``), and a
+    table of at most ``min(R, nslots)`` segments: slot, first row, the row
+    past the last, and how many there are."""
+    import jax.numpy as jnp
+    nrows = slot.shape[0]
+    at = jnp.arange(nrows, dtype=jnp.int32)
+    first = live & ((at == 0) | (jnp.roll(slot, 1) != slot))
+    last = live & ((at + 1 >= n) | (jnp.roll(slot, -1) != slot))
+    same = live[:, None] & live[None, :] & (slot[:, None] == slot[None, :])
+    causal = same & (at[None, :] <= at[:, None])
+    keep = pos - jnp.sum(causal, axis=1) + 1 > 0
+    wslot = jnp.where(last, slot, jnp.int32(nslots) + at)
+    nj = min(nrows, nslots)
+    seg = jnp.cumsum(first.astype(jnp.int32)) - 1
+
+    def table(where, value):
+        return jnp.zeros(nj, jnp.int32).at[jnp.where(where, seg, nj)].set(
+            value, mode="drop")
+
+    return (same, causal, keep, wslot, table(first, slot), table(first, at),
+            table(last, at + 1), jnp.sum(first.astype(jnp.int32)))
+
+
+def segment_ssd(xs, b, c, dt, a, same, causal, keep, heads):
+    """The chunked (SSD) form of the matrix state over the rows of a packed
+    prefill pass, all of it that does not read the stored state. Row ``r``
+    is a position of a slot; ``same`` (R, R) says which rows share a
+    SEGMENT (one slot's rows, consecutive and ascending), ``causal`` which
+    of those lie at or before a row, ``keep`` (R,) whether the row's
+    segment starts past position 0. With ``a = -dt exp(A_log)`` summed
+    inside the segment as ``cum`` (``cum_last`` at its last row), per head:
+
+    * ``y`` (R, W): ``sum_{u <= t} exp(cum_t - cum_u) (C_t . B_u) dt_u
+      x_u``, the segment's own rows' part of the read-out;
+    * ``e`` (R, W): ``exp(cum_t) keep``, what the stored state's read-out
+      ``C_t S0`` is weighed by;
+    * ``w`` (R, W): ``exp(cum_last - cum_u) dt_u x_u``, what row ``u`` adds
+      to the segment's end state times ``B_u`` (0 on a row no segment
+      holds);
+    * ``d`` (R, W): ``exp(cum_last) keep``, the decay of the stored state
+      to the segment's end.
+
+    So ``S_end = d S0 + sum_u B_u (x) w_u`` and row ``t``'s read-out is
+    ``e_t (C_t S0) + y_t``: the step's recurrence over the segment, a
+    request's first position meeting a zero state. Float32, the products at ``HIGHEST``
+    (a float32 product at the chip's default precision is a bfloat16 one);
+    the sums of ``a`` run inside the segment, as the published model's
+    chunks of 256 do."""
+    import jax
+    import jax.numpy as jnp
+    f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+    nrows, width = xs.shape
+    hd = width // heads
+    cum = jnp.dot(causal.astype(f32), a, precision=hi)
+    last = jnp.dot(same.astype(f32), a, precision=hi)
+    # exp(cum_t - cum_u) where u <= t in t's segment, 0 elsewhere (masked
+    # before the exp: past a segment the difference would overflow)
+    gap = jnp.where(causal[:, :, None], cum[:, None, :] - cum[None, :, :],
+                    -jnp.inf)
+    mix = jnp.exp(gap) * jnp.dot(c, b.T, precision=hi)[:, :, None] \
+        * dt[None, :, :]
+    x3 = xs.reshape(nrows, heads, hd)
+    y = jnp.einsum("tuh,uhp->thp", mix, x3, precision=hi).reshape(nrows,
+                                                                  width)
+    live = jnp.any(same, axis=1).astype(f32)[:, None]
+    lanes = functools.partial(jnp.repeat, repeats=hd, axis=1)
+    e = lanes(jnp.exp(cum) * keep[:, None])
+    w = (lanes(jnp.exp(last - cum) * dt) * xs) * live
+    d = lanes(jnp.exp(last) * keep[:, None])
+    return y, e, w, d
+
+
+def _segments_step(n, ssm, slot, lo, hi, nseg, dec, c, e, b, w):
+    """:func:`..ops.pallas_ssd.ssm_segments` as jnp, off the chip: layer
+    ``n``'s state of each segment's slot carried over its rows and written
+    once, and the rows' read-out of the state their segment started from."""
+    import jax
+    import jax.numpy as jnp
+    hp = jax.lax.Precision.HIGHEST
+    nj, nrows = slot.shape[0], c.shape[0]
+    seg = jnp.arange(nj) < nseg
+    at = jnp.arange(nrows)
+    mine = (seg[:, None] & (at[None, :] >= lo[:, None])
+            & (at[None, :] < hi[:, None])).astype(jnp.float32)
+    s0 = ssm[n][slot]
+    y = e * jnp.einsum("jr,rn,jnw->rw", mine, c, s0, precision=hp)
+    s = dec[:, None, :] * s0 + jnp.einsum("jr,rn,rw->jnw", mine, b, w,
+                                         precision=hp)
+    # an entry past the last segment writes out of bounds: dropped
+    wslot = jnp.where(seg, slot, ssm.shape[1] + jnp.arange(nj))
+    return ssm.at[n, wslot].set(s, mode="drop", unique_indices=True), y
+
+
+def _segments_kernel(n, ssm, slot, lo, hi, nseg, dec, c, e, b, w):
+    """:func:`_segments_step` as one Pallas kernel that reads and writes
+    only the carried slots' state (``ops/pallas_ssd.py``): the packed
+    pass's lowering for the chip."""
+    from ..ops.pallas_ssd import ssm_segments
+    return ssm_segments(ssm, n, slot, lo, hi, nseg, dec, c, e, b, w)
 
 
 def _state_kernel(n, ssm, decay, u, b, c):

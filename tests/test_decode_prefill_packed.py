@@ -6,7 +6,12 @@ Held here, on the tiny Kimi-K2 configuration of
 ``tests/test_deepseek_v3_decode.py`` and on the tiny LFM2 one
 of ``tests/test_lfm2_arch.py`` (``Lfm2Arch``'s pass: a chunk-wide short
 convolution beside grouped-query attention; its cases are named
-``lfm2_..``), in float32 and in bfloat16:
+``lfm2_..``) and on the tiny Granite 4.0-H one of
+``tests/test_granite_hybrid_arch.py`` cut to its first nine layers, so
+that, as in the published model, an attention layer stands between mamba
+layers and the last is a mamba layer (``GraniteHybridArch``'s pass: the
+float32 matrix state in the chunked (SSD) form over each slot's segment;
+its cases are named ``granite_..``), in float32 and in bfloat16:
 
 * the PROGRAM against the token pass fed one position a step: a pass over
   1, 2 and 3 slots at mixed starts (0, over rows that were there, a second
@@ -16,6 +21,10 @@ convolution beside grouped-query attention; its cases are named
   3 and more positions, over a state a predecessor left (the tap mask
   hides it) and behind positions that rode steps or an earlier pass;
   padding moves nothing, also where ``pos + R`` passes the cache's depth;
+  Granite's conv state and float32 matrix state of each named slot are
+  the states one-token feeding leaves, for the same segments, a segment
+  that starts a request in a slot a predecessor used (the reset), one
+  that starts where steps left off, a prompt split across two passes;
   the routing counters count the pass's rows as the steps counted them, in
   every expert layer the pass runs (the LAST layer stops at its state, so
   its experts serve no row of a pass and count none);
@@ -42,27 +51,37 @@ from mxnet_tpu.serving import decode
 from mxnet_tpu.serving.blocks import rows_ladder
 from mxnet_tpu.serving.health import ServingHealth
 
+import test_granite_hybrid_arch
 import test_lfm2_arch
 from test_deepseek_v3_decode import MAX_LEN, SLOTS, TINY
 
 R = 32          # rows of a pass in the program tests
 NEVER = 10 ** 9
 DTYPES = ["float32", "bfloat16"]
-#: the loop tests' cases: Kimi's by its dtype alone, LFM2's beside them
-VARIANTS = DTYPES + ["lfm2_" + d for d in DTYPES]
+#: the loop tests' cases: Kimi's by its dtype alone, LFM2's and Granite's
+#: beside them
+VARIANTS = DTYPES + ["lfm2_" + d for d in DTYPES] \
+    + ["granite_" + d for d in DTYPES]
 #: those whose packing loop emits one-token feeding's tokens to the token.
 #: LFM2's bfloat16 pass rounds rows a bfloat16 unit apart from the step's
 #: where XLA:CPU sums a product of 24 rows in another order than one of 3
 #: (its float32 rows differ in the sixth figure), and its tiny model's
 #: large weights carry that to a greedy near-tie once in 40 tokens: judged
 #: against the reference instead
-TOKEN_EXACT = DTYPES + ["lfm2_float32"]
+TOKEN_EXACT = DTYPES + ["lfm2_float32", "granite_float32"]
+#: the tiny Granite block's first nine layers: four mamba layers, the
+#: attention layer, four mamba layers (the last layer a mamba layer, as the
+#: published model's is)
+GRANITE = dict(test_granite_hybrid_arch.TINY, num_hidden_layers=9,
+               layer_types=test_granite_hybrid_arch.TINY["layer_types"][:9])
 
 
 def _variant(name):
-    """``(model, the rest)`` of a case or variant named ``[lfm2_]..``."""
-    if name.startswith("lfm2_"):
-        return "lfm2", name[len("lfm2_"):]
+    """``(model, the rest)`` of a case or variant named
+    ``[lfm2_|granite_]..``."""
+    for model in ("lfm2", "granite"):
+        if name.startswith(model + "_"):
+            return model, name[len(model) + 1:]
     return "kimi", name
 
 
@@ -72,6 +91,9 @@ def _model(dtype, model="kimi"):
     if model == "lfm2":
         cfg = dict(test_lfm2_arch.TINY, dtype=dtype)
         make, arch = "lfm2-24b-a2b-ep8", serving.Lfm2Arch(cfg)
+    elif model == "granite":
+        cfg = dict(GRANITE, dtype=dtype)
+        make, arch = "granite-4.0-h-micro", serving.GraniteHybridArch(cfg)
     else:
         cfg = dict(TINY, dtype=dtype)
         make, arch = "kimi-k2-ep32", serving.DeepseekV3Arch(cfg)
@@ -84,7 +106,8 @@ def _model(dtype, model="kimi"):
 # ---------------------------------------------------------------------------
 
 #: name -> (rows of the cache, [pass, ...]), a pass ``[(slot, pos0, n)]`` in
-#: the order its rows lie in; an ``lfm2_`` case runs the LFM2 model
+#: the order its rows lie in; an ``lfm2_`` case runs the LFM2 model, a
+#: ``granite_`` case the Granite one
 CASES = {
     "one_slot_from_0": (MAX_LEN, [[(1, 0, 20)]]),
     "two_slots_one_over_rows_that_were_there":
@@ -110,6 +133,21 @@ CASES = {
     "lfm2_all_padding": (MAX_LEN, [[]]),
     "lfm2_across_a_rung_of_the_ladder":
         (400, [[(1, 200, 16), (0, 3, 9)]]),
+    # every slot holds a predecessor's states: a segment from 0 meets a
+    # zero state; one from past 0 carries what the steps left
+    "granite_segments_of_1_2_3_and_more_then_short_ones":
+        (MAX_LEN, [[(0, 0, 1), (1, 0, 2), (2, 0, 3)],
+                   [(2, 3, 1), (0, 1, 2), (1, 2, 1)],
+                   [(1, 3, 7), (2, 4, 2)]]),
+    "granite_two_slots_one_over_rows_that_rode_steps":
+        (MAX_LEN, [[(0, 6, 11), (2, 0, 15)]]),
+    "granite_a_prompt_split_across_two_passes":
+        (MAX_LEN, [[(0, 0, 10), (1, 0, 12), (2, 0, 10)],
+                   [(1, 12, 9), (0, 10, 5)]]),
+    "granite_pos_plus_R_passes_the_depth": (MAX_LEN, [[(2, 40, 8)]]),
+    "granite_all_padding": (MAX_LEN, [[]]),
+    "granite_across_a_rung_of_the_ladder":
+        (400, [[(1, 200, 16), (0, 3, 9)]]),
 }
 
 
@@ -117,11 +155,12 @@ def _base_state(arch, rows, dtype):
     """Slots whose every row holds SOMETHING (what an earlier occupant, a
     prefix hit or the steps left there), the surplus lanes zero."""
     rs = np.random.RandomState(11)
-    if isinstance(arch, serving.Lfm2Arch):
+    if not isinstance(arch, serving.DeepseekV3Arch):
         quant = "bf16" if dtype == "bfloat16" else "none"
+        # each array in its own dtype: Granite's matrix state is float32
         state = {k: jnp.asarray(rs.randn(a.layers, SLOTS, a.depth(rows),
                                          a.width).astype(np.float32) * 0.5)
-                 .astype(dtype)
+                 .astype(a.dtype)
                  for k, a in sorted(arch.slot_state(None, quant).items())}
     else:
         lat = np.zeros((arch.num_layers, SLOTS, rows, arch.latent_width),
@@ -148,8 +187,9 @@ def _pass_args(group, seed):
 
 
 #: state arrays a slot keeps a fixed number of rows of, stepped once a
-#: position whatever the position (LFM2's conv state)
-STEPPED = ("conv",)
+#: position whatever the position (LFM2's and Granite's conv state,
+#: Granite's matrix state)
+STEPPED = ("conv", "ssm")
 
 
 def _one_position_a_step(arch, params, state, group, tokens, rows):
@@ -174,7 +214,7 @@ def _one_position_a_step(arch, params, state, group, tokens, rows):
         for s, (pos0, toks) in todo.items():
             if t < len(toks):
                 tok[s], pos[s], live[s] = toks[t], pos0 + t, True
-        state, _ = step(state, params, tok, pos, live)
+        state, _ = step(state, params, tok, pos, *[live] * arch.wants_live)
         for k in kept:
             for s, (_, toks) in todo.items():
                 if t == len(toks) - 1:
@@ -246,9 +286,9 @@ def test_a_packed_pass_writes_the_rows_of_one_token_feeding(dtype, case):
         for s, pos0, n in group:
             pos[s] = min(pos0 + n, rows - 1)
         tok = np.arange(3, 3 + SLOTS, dtype=np.int32)
-        live = np.ones(SLOTS, bool)
-        _, got = step(decode._model_state(new), params, tok, pos, live)
-        _, want = step(ref, params, tok, pos, live)
+        live = [np.ones(SLOTS, bool)] * arch.wants_live
+        _, got = step(decode._model_state(new), params, tok, pos, *live)
+        _, want = step(ref, params, tok, pos, *live)
         _close(got, want, dtype)
 
 
@@ -274,7 +314,8 @@ def test_a_packed_pass_moves_nothing_but_the_rows_it_names(dtype, case):
                                   .astype(np.float32)).any()
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("case", sorted(c for c in CASES
+                                         if _variant(c)[0] != "granite"))
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_a_packed_pass_counts_its_rows_as_the_steps_counted_them(dtype,
                                                                  case):
@@ -374,6 +415,36 @@ def test_the_last_layer_of_lfm2_stops_at_its_conv_state():
                            "tok", "v"]
 
 
+def test_the_last_layer_of_granite_stops_at_its_states():
+    """Granite's last layer is a mamba layer: the pass writes its conv
+    state and its matrix state, and reads neither its gated norm, its
+    output projection nor its feed-forward, nor the head's norm."""
+    arch, params = _model("float32", "granite")
+    assert arch.layer_types[-1] == "mamba"
+    assert arch.layer_types.count("attention") == 1
+    state = dict(_base_state(arch, MAX_LEN, "float32"),
+                 seed=jnp.zeros(SLOTS, np.uint32),
+                 tok=jnp.zeros(SLOTS, np.int32))
+    fn = decode._build_prefill_fn(arch)
+    args = (jnp.zeros(R, np.int32),) * 3 + (jnp.int32(4),)
+    jaxpr = jax.make_jaxpr(fn)(state, params, *args)
+    used = {str(v) for eqn in jaxpr.eqns for v in eqn.invars}
+    flat, _ = jax.tree_util.tree_flatten_with_path((state, params))
+    unused = {jax.tree_util.keystr(path) for (path, _), var in
+              zip(flat, jaxpr.jaxpr.invars) if str(var) not in used}
+    last = "layer%d_" % (arch.num_layers - 1)
+    assert {"[1]['final_norm_gamma']", "[1]['%sssm_norm_gamma']" % last,
+            "[1]['%sssm_out_weight']" % last, "[1]['%sssm_D']" % last,
+            "[1]['%sffn_norm_gamma']" % last,
+            "[1]['%sffn_down_weight']" % last} <= unused
+    assert not {"[1]['%sssm_in_weight']" % last,
+                "[1]['%sssm_conv_weight']" % last,
+                "[1]['%sssm_A_log']" % last} & unused
+    out = jax.eval_shape(fn, state, params, *args)
+    assert sorted(out) == ["conv", "k", "seed", "ssm", "tok", "v"]
+    assert out["ssm"].dtype == np.float32
+
+
 def test_which_architectures_pack_and_what_the_constants_are():
     import test_laguna_arch
     import test_mellum_arch
@@ -382,9 +453,11 @@ def test_which_architectures_pack_and_what_the_constants_are():
     assert not decode.OptArch.packed_prefill
     assert serving.DeepseekV3Arch.packed_prefill
     assert serving.Lfm2Arch.packed_prefill
+    assert serving.GraniteHybridArch.packed_prefill
     assert not serving.MellumArch.packed_prefill
     assert not serving.LagunaArch.packed_prefill
-    for arch in (_model("float32")[0], _model("float32", "lfm2")[0]):
+    for arch in (_model("float32")[0], _model("float32", "lfm2")[0],
+                 _model("float32", "granite")[0]):
         assert arch.build_prefill_pass() is not None
         # a sharded loop keeps one-token feeding: no sharded pass
         assert arch.build_prefill_pass(mesh=object()) is None
@@ -642,7 +715,8 @@ def test_a_packing_loop_emits_one_token_feedings_tokens(variant, sampled):
 def test_greedy_tokens_are_the_references_full_forward():
     for variant, make, cfg in (
             ("float32", "kimi-k2-ep32", TINY),
-            ("lfm2_float32", "lfm2-24b-a2b-ep8", test_lfm2_arch.TINY)):
+            ("lfm2_float32", "lfm2-24b-a2b-ep8", test_lfm2_arch.TINY),
+            ("granite_float32", "granite-4.0-h-micro", GRANITE)):
         ref = test_lfm2_arch._load(make)
         params = ref.make_params(dict(cfg, dtype="float32"), 7)
         for (prompt, _), out in zip(REQUESTS, _served(
@@ -670,6 +744,27 @@ def test_a_packing_lfm2_loop_in_bfloat16_serves_within_rounding():
         gaps.append(logits.max(-1) - logits[np.arange(new), out])
     gap = np.concatenate(gaps)
     assert gap.max() < 0.15 and np.mean(gap * gap) < 1e-3
+
+
+def test_a_packing_granite_loop_in_bfloat16_serves_within_rounding():
+    """Granite's bfloat16 loop, judged as LFM2's is: every served token's
+    logit within bfloat16 rounding of the float32 reference's best
+    (``tests/test_granite_hybrid_arch.py``'s measure), the passes having
+    carried prompts."""
+    ref = test_lfm2_arch._load("granite-4.0-h-micro")
+    cfg = dict(GRANITE, dtype="bfloat16")
+    params = ref.make_params(cfg, 7)
+    outs, _, health, _, _ = _served("granite_bfloat16", decode.MIN_PREFILL)
+    assert health["prefill_passes"] > 0
+    gaps = []
+    for (prompt, new), out in zip(REQUESTS, outs):
+        assert len(out) == new
+        seq = jnp.asarray((prompt + out)[:-1], jnp.int32)
+        logits = np.asarray(ref.forward(params, seq, cfg,
+                                        "float32"))[len(prompt) - 1:]
+        gaps.append(logits.max(-1) - logits[np.arange(new), out])
+    gap = np.concatenate(gaps)
+    assert gap.max() < 0.1 and np.mean(gap * gap) < 1e-3
 
 
 @pytest.mark.parametrize("variant", VARIANTS)

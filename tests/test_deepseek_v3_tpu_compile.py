@@ -64,7 +64,10 @@ fits the chip at full depth; the state is stepped and read out by ONE
 Pallas kernel a layer (``ops/pallas_ssm.py``), aliased into the donated
 array and scoped ``layer/ssm``, and nothing else reads it (as XLA
 compiles the jnp form, the read-out is a second pass over the state,
-PERF.md); and the step's count of device instructions is held.
+PERF.md); and the step's count of device instructions is held. From PR 45
+its PACKED prefill program at full depth (256 rows): nothing
+rematerialised, the matrix state read and written only by one Pallas call
+a mamba layer aliased into the donated array, under 1 GB of temporaries.
 
 The topology is described inside a fixture (only one process may load the
 TPU's library; a worker that cannot skips), and this is the one file that
@@ -804,7 +807,9 @@ def _granite_config(layers=None):
     return cfg
 
 
-def _granite_compile(one_chip, layers):
+def _granite_compile(one_chip, layers, prefill=False):
+    """The step (or, with ``prefill``, the packed prefill program of
+    ``decode.PACKED_ROWS`` rows) as the loop compiles it."""
     arch = granite_hybrid.GraniteHybridArch(_granite_config(layers))
 
     def s(shape, dtype):
@@ -814,10 +819,15 @@ def _granite_compile(one_chip, layers):
     state = {k: s((a.layers, SLOTS, a.depth(ROWS), a.width), a.dtype)
              for k, a in arch.slot_state(None, "bf16").items()}
     state.update(seed=s((SLOTS,), np.uint32), tok=s((SLOTS,), np.int32))
-    feed = [s((SLOTS,), d) for d in (np.int32, np.int32, np.float32,
-                                     np.int32, np.float32, np.uint32,
-                                     np.bool_)]
-    fn = jax.jit(decode._build_decode_fn(arch), donate_argnums=(0,))
+    if prefill:
+        rows = s((decode.PACKED_ROWS,), np.int32)
+        feed = [rows, rows, rows, s((), np.int32)]
+        fn = jax.jit(decode._build_prefill_fn(arch), donate_argnums=(0,))
+    else:
+        feed = [s((SLOTS,), d) for d in (np.int32, np.int32, np.float32,
+                                         np.int32, np.float32, np.uint32,
+                                         np.bool_)]
+        fn = jax.jit(decode._build_decode_fn(arch), donate_argnums=(0,))
     return state, fn.lower(state, params, *feed).compile(
         compiler_options=arch.compiler_options("tpu") or None)
 
@@ -951,3 +961,37 @@ def test_granite_full_step_rematerialises_nothing_and_updates_in_place(
     assert abs(mem.argument_size_in_bytes - 11.81e9) < 0.01e9
     assert mem.alias_size_in_bytes >= 5.42e9
     assert mem.temp_size_in_bytes < 0.1e9
+
+
+def test_granite_full_prefill_pass_carries_the_state_in_place(one_chip):
+    """The claimed cell's PACKED prefill program (PR 45) at full depth: 256
+    rows over 64 slots, bfloat16 weights, the float32 matrix state of 36
+    layers. Compiled with the block's options, as the loop compiles it,
+    nothing is rematerialised; each mamba layer's state is read and
+    written by ONE Pallas call (``ops/pallas_ssd.py::ssm_segments``) that
+    writes into the donated array (its eleventh operand, after the layer,
+    the segment table and the rows' operands), scoped ``layer/ssm``, and
+    nothing else reads the 4.83 GB; every state array is donated in place;
+    the temporaries (most of them each row's gathered K/V rows at the
+    deepest rung) stay under 1 GB; the last layer stops at its states."""
+    state, compiled = _granite_compile(one_chip, None, prefill=True)
+    assert state["ssm"].shape == (36, SLOTS, 128, 4096)
+    text = compiled.as_text()
+    assert ".remat" not in text
+    readers = _state_readers(compiled, 36)
+    assert len(readers) == 36, readers
+    for opcode, scope, alias in readers:
+        assert (opcode, alias) == ("custom-call", 10), readers
+        assert scope.startswith("layer/ssm"), readers
+    assert text.count('custom_call_target="tpu_custom_call"') == 36
+    mem = compiled.memory_analysis()
+    donated = sum(int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize
+                  for k, v in state.items() if k in ("ssm", "conv", "k", "v"))
+    assert abs(donated - 5.43e9) < 0.01e9
+    assert mem.alias_size_in_bytes >= donated
+    assert mem.temp_size_in_bytes < 1e9
+    entry = text[text.index("ENTRY"):]
+    for name in ("final_norm_gamma", "layer39_ssm_out_weight",
+                 "layer39_ffn_down_weight"):
+        assert "params__%s__" % name not in entry, name
+    assert "params__layer39_ssm_in_weight__" in entry

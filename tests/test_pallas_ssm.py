@@ -1,4 +1,5 @@
-"""The Mamba-2 state kernel (``ops/pallas_ssm.py``) in Pallas interpret
+"""The Mamba-2 state kernels (``ops/pallas_ssm.py``, and from PR 45 the
+packed prefill pass's, ``ops/pallas_ssd.py``) in Pallas interpret
 mode on the CPU, against the jnp form the Granite 4.0-H token pass runs
 everywhere but the chip (``serving/granite_hybrid.py::_state_step``), in the
 state's own layout ``(layers, slots, d_state, heads * d_head)`` at small
@@ -10,12 +11,19 @@ widths: three layers, three slots, four heads of 8 lanes, ``d_state`` 16.
 * every other layer of the array is left bit for bit as it was;
 * a slot at ``pos == 0`` (its decay 0) starts from a zero state, whatever
   it held;
-* the kernel is the token pass's on the chip only: importing
+* the packed prefill pass's kernel (``pallas_ssd.ssm_segments``: a grid
+  step a segment, the carried slots' state read and written in place, each
+  segment's rows' read-out of the state it started from) agrees with its
+  jnp form (``_segments_step``) to float32 rounding, for a segment that
+  starts a request (its decay 0), segments of one row, as many segments
+  as slots, and a pass that carries none; every slot and layer it does
+  not carry is left bit for bit;
+* the kernels are the passes' on the chip only: importing
   ``mxnet_tpu.serving`` imports no Pallas, and the CPU's lowering of the
-  step holds no kernel call.
+  step and of the pass holds no kernel call.
 
-The kernel's lowering for the chip, at published widths inside the step,
-is held by ``tests/test_deepseek_v3_tpu_compile.py``."""
+The kernels' lowering for the chip, at published widths inside the step and
+the pass, is held by ``tests/test_deepseek_v3_tpu_compile.py``."""
 import subprocess
 import sys
 
@@ -25,8 +33,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from mxnet_tpu.ops import pallas_ssm
-from mxnet_tpu.serving.granite_hybrid import _state_step
+from mxnet_tpu.ops import pallas_ssd, pallas_ssm
+from mxnet_tpu.serving.granite_hybrid import _segments_step, _state_step
 
 LAYERS, SLOTS, ROWS, HEADS, HEAD = 3, 3, 16, 4, 8
 WIDTH = HEADS * HEAD
@@ -97,6 +105,7 @@ def test_serving_imports_no_pallas():
 
 
 def test_the_cpu_lowering_of_the_step_runs_the_jnp_form():
+    """Of the step and of the packed prefill pass."""
     from mxnet_tpu.serving import decode
     from mxnet_tpu.serving.granite_hybrid import GraniteHybridArch
     arch = GraniteHybridArch(dict(
@@ -121,3 +130,58 @@ def test_the_cpu_lowering_of_the_step_runs_the_jnp_form():
     text = jax.jit(decode._build_decode_fn(arch)).lower(
         state, params, *feed).as_text()
     assert "tpu_custom_call" not in text
+    rows = jax.ShapeDtypeStruct((8,), np.int32)
+    text = jax.jit(decode._build_prefill_fn(arch)).lower(
+        state, params, rows, rows, rows,
+        jax.ShapeDtypeStruct((), np.int32)).as_text()
+    assert "tpu_custom_call" not in text
+
+
+#: rows of a packed pass in the segment kernel's tests, and its segments:
+#: name -> [(slot, first row, row past the last)]; a slot whose decay is
+#: 0 starts a request
+PASS_ROWS = 24
+PASSES = {
+    "three_one_starting_a_request": [(2, 0, 7), (0, 7, 10), (1, 10, 19)],
+    "one_row_segments": [(1, 0, 1), (0, 1, 2)],
+    "every_slot": [(1, 0, 3), (2, 3, 4), (0, 4, 24)],
+    "none": []}
+
+
+def _pass(seed, segments):
+    rng = np.random.RandomState(seed)
+    state = rng.randn(LAYERS, SLOTS, ROWS, WIDTH).astype(np.float32) * 50
+    nj = SLOTS
+    slot, lo, hi = (np.zeros(nj, np.int32) for _ in range(3))
+    for j, (s, a, b) in enumerate(segments):
+        slot[j], lo[j], hi[j] = s, a, b
+    dec = rng.uniform(0.2, 1.0, (nj, WIDTH)).astype(np.float32)
+    dec[0] *= seed % 2             # the first segment may start a request
+    c, b = (rng.randn(PASS_ROWS, ROWS).astype(np.float32) for _ in range(2))
+    e, w = (rng.randn(PASS_ROWS, WIDTH).astype(np.float32) for _ in range(2))
+    return state, (slot, lo, hi, np.int32(len(segments)), dec, c, e, b, w)
+
+
+@pytest.mark.parametrize("lanes", [8, 12, 32])
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("case", sorted(PASSES))
+def test_the_segment_kernel_carries_each_slot_as_the_jnp_form(case, layer,
+                                                             lanes):
+    state, args = _pass(layer + len(case), PASSES[case])
+    want_state, want_y = _segments_step(layer, jnp.asarray(state), *args)
+    got_state, got_y = pallas_ssd.ssm_segments(
+        jnp.asarray(state), jnp.int32(layer), *args, lanes=lanes,
+        interpret=True)
+    assert got_state.shape == state.shape and got_y.shape == want_y.shape
+    np.testing.assert_allclose(np.asarray(got_state), np.asarray(want_state),
+                               rtol=2e-6, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                               rtol=2e-5, atol=1e-2)
+    carried = {s for s, _, _ in PASSES[case]}
+    for other in range(LAYERS):
+        for s in range(SLOTS):
+            if other != layer or s not in carried:
+                np.testing.assert_array_equal(np.asarray(got_state[other, s]),
+                                              state[other, s])
+    if not carried:
+        assert not np.asarray(got_y).any()
